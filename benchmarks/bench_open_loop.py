@@ -46,6 +46,7 @@ from repro.serve.loadgen import (
     merged_class_summary,
 )
 from repro.serve.paxos import BatchedMachine
+from repro.runtime import use_compile_cache
 
 try:
     from benchmarks.bench_vector import _run_metadata
@@ -153,6 +154,7 @@ def main(argv=None):
                     help="append one open_loop_smoke row to this tracked "
                          "JSONL history; pass '' to disable")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.sweep:
         return sweep()

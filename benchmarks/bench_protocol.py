@@ -26,6 +26,7 @@ except ImportError:                  # python benchmarks/bench_protocol.py
 from repro.core import checkers
 from repro.core.node import ProtocolConfig
 from repro.core.sim import Cluster, NetConfig, workload
+from repro.runtime import use_compile_cache
 
 
 def run(all_aboard: bool, *, n_ops=600, keys=256, rmw_frac=1.0,
@@ -215,6 +216,7 @@ def bench_host_path(n_items=20_000, reps=5):
 
 
 def main():
+    use_compile_cache()
     out = {
         "rmw_modes": bench_rmw_modes(),
         "op_classes": bench_op_classes(),

@@ -53,6 +53,7 @@ from repro.core import proposer_vector, vector
 from repro.core.proposer import AbdPhase, Phase
 from repro.core.types import TS, Msg, MsgKind, RmwId, View
 from repro.kernels.paxos_apply import ops
+from repro.runtime import kernel_interpret, use_compile_cache
 
 N_GSESS = 40
 
@@ -91,7 +92,7 @@ def random_tables(n, seed=0, kinds=None):
     return kv, msg, registered
 
 
-def _time_step(kv, msg, reg, iters, use_kernel, interpret, repeats=3):
+def _time_step(kv, msg, reg, iters, use_kernel, repeats=3):
     """Seconds per replica_step call, steady-state (post-compile).
 
     Best-of-``repeats`` timing: interpret-mode batches at smoke shapes run
@@ -99,7 +100,7 @@ def _time_step(kv, msg, reg, iters, use_kernel, interpret, repeats=3):
     otherwise dominate the measurement and scramble op-class ordering.
     """
     step = lambda kv, msg, reg: ops.replica_step(
-        kv, msg, reg, use_kernel=use_kernel, interpret=interpret)
+        kv, msg, reg, use_kernel=use_kernel)
     out = step(kv, msg, reg)
     jax.block_until_ready(out)
     best = float("inf")
@@ -113,10 +114,9 @@ def _time_step(kv, msg, reg, iters, use_kernel, interpret, repeats=3):
     return best
 
 
-def bench(n_keys: int, iters: int = 30, use_kernel: bool = False,
-          interpret: bool = True):
+def bench(n_keys: int, iters: int = 30, use_kernel: bool = False):
     kv, msg, reg = random_tables(n_keys)
-    dt = _time_step(kv, msg, reg, iters, use_kernel, interpret)
+    dt = _time_step(kv, msg, reg, iters, use_kernel)
     return {"n_keys": n_keys, "impl": "pallas" if use_kernel else "jnp",
             "msgs_per_s": round(n_keys / dt), "us_per_batch": round(dt * 1e6)}
 
@@ -139,15 +139,14 @@ def _wire_bytes_per_op():
 
 
 def bench_op_classes(n_keys: int, iters: int = 20, use_kernel: bool = False,
-                     interpret: bool = True, seed: int = 0):
+                     seed: int = 0):
     """Mixed read/write/RMW lane benchmark: per-op-class ops/s at the SIMD
     layer, measured per message kind (single-kind full batches) and summed
     over each op class's receiver rounds."""
     per_kind_s = {}
     for kind in ALL_KINDS:
         kv, msg, reg = random_tables(n_keys, seed=seed + kind, kinds=[kind])
-        per_kind_s[kind] = _time_step(kv, msg, reg, iters, use_kernel,
-                                      interpret)
+        per_kind_s[kind] = _time_step(kv, msg, reg, iters, use_kernel)
     bytes_per_op = _wire_bytes_per_op()
     rows = []
     for cls, rounds in OP_ROUNDS.items():
@@ -182,15 +181,13 @@ def check_op_class_ordering(rows):
 
 
 def bench_op_classes_checked(n_keys: int, iters: int = 20,
-                             use_kernel: bool = False,
-                             interpret: bool = True, attempts: int = 3):
+                             use_kernel: bool = False, attempts: int = 3):
     """Measure op classes, re-measuring with more iterations if timing
     noise inverted the structural ordering; every measurement (including
     the last) is checked before giving up."""
     for attempt in range(attempts):
         rows = bench_op_classes(n_keys, iters=iters * (attempt + 1),
-                                use_kernel=use_kernel, interpret=interpret,
-                                seed=attempt)
+                                use_kernel=use_kernel, seed=attempt)
         if check_op_class_ordering(rows):
             return rows
     raise SystemExit(f"op-class ordering inverted even after "
@@ -511,10 +508,9 @@ def _run_metadata() -> dict:
 
 
 def check_kernel_matches_oracle(n_keys: int = 256, seed: int = 5):
-    """One mixed full-vocabulary batch: Pallas (interpret) == pure jnp."""
+    """One mixed full-vocabulary batch: Pallas == pure jnp."""
     kv, msg, reg = random_tables(n_keys, seed=seed)
-    k = ops.replica_step(kv, msg, reg, block_rows=1, use_kernel=True,
-                         interpret=True)
+    k = ops.replica_step(kv, msg, reg, block_rows=1, use_kernel=True)
     j = ops.replica_step(kv, msg, reg, block_rows=1, use_kernel=False)
     for name, a, b in zip(("kv", "rep", "reg"), k, j):
         for f, x, y in zip(getattr(type(a), "_fields", (name,)),
@@ -548,6 +544,7 @@ def main(argv=None):
                              "device_count=N to spread the shard rows "
                              "over N devices")
     args = parser.parse_args(argv)
+    use_compile_cache()
 
     if args.smoke:
         check_kernel_matches_oracle()
@@ -556,7 +553,7 @@ def main(argv=None):
             "schema": 1,
             "mode": "smoke",
             "impl": "pallas",
-            "interpret": True,
+            "interpret": kernel_interpret(),
             "jax": jax.__version__,
             "backend": jax.default_backend(),
             "shapes": {"n_keys": n, "n_issuer_lanes": n, "block_rows": 32},
@@ -584,7 +581,7 @@ def main(argv=None):
               f"({out} written)")
         return rows
 
-    rows = {"schema": 1, "mode": "full", "interpret": True,
+    rows = {"schema": 1, "mode": "full", "interpret": kernel_interpret(),
             "jax": jax.__version__, "backend": jax.default_backend(),
             "throughput": [bench(n) for n in (4096, 65_536, 1_048_576)]}
     rows["throughput"].append(bench(65_536, iters=3, use_kernel=True))

@@ -38,12 +38,13 @@ from repro.core.node import Machine, ProtocolConfig
 from repro.core.sim import Cluster, NetConfig, completion_tuples, workload
 from repro.obs import FlightRecorder, flight_guard
 from repro.serve.paxos import BatchedMachine
+from repro.runtime import use_compile_cache
 
 SEEDS = range(20)
 ABOARD_SEEDS = frozenset((1, 3, 7, 11, 15, 19))
 CRASH_SEEDS = frozenset((2, 5, 9, 13, 17))
 # a third of the storm drives the fused engine through the Pallas kernels
-# (receiver + issuer paths, interpret mode) instead of the jnp oracle —
+# (receiver + issuer paths, interpreted off a TPU) instead of the jnp oracle —
 # both use_kernel settings must stay completion-identical to scalar
 KERNEL_SEEDS = frozenset((0, 3, 5, 8, 12, 16, 19))
 
@@ -127,6 +128,7 @@ def main(argv=None) -> int:
                          "first seed: demonstrates the checker-failure "
                          "-> dump -> trace_report postmortem path")
     args = ap.parse_args(argv)
+    use_compile_cache()
     t0 = time.time()
     total_ops = 0
     for seed in SEEDS:

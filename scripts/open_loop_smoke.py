@@ -41,6 +41,7 @@ from repro.serve.loadgen import (
     ArrivalPhase, FaultPlan, MIXES, OpenLoopHarness, OpenLoopSpec,
 )
 from repro.serve.paxos import BatchedMachine
+from repro.runtime import use_compile_cache
 
 KIND_TO_PATHS = {"RMW": ("all_aboard_fast", "cp_slow"),
                  "READ": ("abd_read",), "WRITE": ("abd_write",)}
@@ -102,6 +103,7 @@ def main(argv=None) -> int:
                     help="also dump the first seed's recorder on success "
                          "(CI runs trace_report.py against it)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     t0 = time.time()
     total = fault_total = 0
     for seed in SEEDS:

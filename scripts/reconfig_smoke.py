@@ -29,11 +29,12 @@ from repro.core import checkers
 from repro.core.node import Machine, ProtocolConfig
 from repro.core.sim import Cluster, NetConfig, completion_tuples, workload
 from repro.serve.paxos import BatchedMachine
+from repro.runtime import use_compile_cache
 
 SEEDS = range(20)
 ABOARD_SEEDS = frozenset((3, 9, 15))
 # these storms run the fused engine through the Pallas kernels (receiver
-# + issuer, interpret mode): view changes, crash/restart and catch-up
+# + issuer, interpreted off a TPU): view changes, crash/restart and catch-up
 # must be completion-identical under both use_kernel settings
 KERNEL_SEEDS = frozenset((2, 9, 14, 18))
 
@@ -98,6 +99,7 @@ def main(argv=None) -> int:
                          "(>1 drives view installs / snapshot catch-up "
                          "through per-shard plane rows)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     t0 = time.time()
     total_ops = 0
     for seed in SEEDS:

@@ -1,17 +1,18 @@
 """JAX version-compatibility layer — the ONLY place version-sensitive
 JAX API usage is allowed.
 
-The repo targets a range of JAX releases whose mesh-introspection and
-Pallas ref-indexing surfaces differ:
+The repo targets the JAX 0.9 series.  Its version-sensitive surfaces:
 
-* mesh introspection: ``jax.sharding.get_abstract_mesh()`` (newer) vs the
-  legacy ``jax._src.mesh.thread_resources.env.physical_mesh`` (set by
-  ``with mesh:``); see :mod:`repro.compat.meshes`,
-* mesh activation: ``jax.sharding.use_mesh`` (newer) vs the legacy
-  ``Mesh.__enter__`` context,
-* Pallas indexing: raw Python ints inside ``pl.load``/``pl.store`` index
-  tuples stopped working (the discharge rule requires every non-slice
-  index to carry ``.shape``); see :mod:`repro.compat.pallas`.
+* mesh construction: ``jax.make_mesh`` defaults to ``Explicit`` axes,
+  which ``with_sharding_constraint`` rejects; :func:`make_mesh` builds
+  ``Auto`` axes,
+* mesh activation and introspection: ``jax.set_mesh`` and
+  ``jax.sharding.get_abstract_mesh``; see :mod:`repro.compat.meshes`,
+* AOT cost analysis, whose return shape changed across releases; see
+  :mod:`repro.compat.aot`.
+
+Pallas kernels index refs directly (``ref[...]``, ``ref[0, pl.ds(i, n)]``),
+the one spelling the installed JAX supports.
 
 Everything outside this package imports the stable names below; the
 pinned-API canary in ``tests/test_compat.py`` fails in one obvious place
@@ -20,13 +21,11 @@ when a JAX bump shifts the surface again.
 
 from repro.compat.aot import flatten_cost_analysis
 from repro.compat.meshes import (
-    abstract_mesh,
     current_mesh,
-    physical_mesh,
+    make_mesh,
     sharding_constraint,
     use_mesh,
 )
-from repro.compat.pallas import dslice, load_block, store_block
 from repro.compat.version import (
     JAX_VERSION,
     SUPPORTED_MAX,
@@ -40,16 +39,12 @@ __all__ = [
     "JAX_VERSION",
     "SUPPORTED_MAX",
     "SUPPORTED_MIN",
-    "abstract_mesh",
     "api_report",
     "check_pinned_api",
     "current_mesh",
-    "dslice",
     "flatten_cost_analysis",
-    "load_block",
-    "physical_mesh",
+    "make_mesh",
     "sharding_constraint",
-    "store_block",
     "supported",
     "use_mesh",
 ]

@@ -1,9 +1,8 @@
-"""AOT (lower/compile) result normalization across JAX versions.
+"""AOT (lower/compile) result normalization.
 
-``Compiled.cost_analysis()`` returned a one-element list of dicts
-(per-device) through 0.4.x and a plain dict in newer releases;
-``flatten_cost_analysis`` accepts either and always hands back a dict,
-so roofline/dryrun code never branches on the JAX version.
+``Compiled.cost_analysis()`` returns a dict, or None where the backend
+has no cost model; ``flatten_cost_analysis`` always hands back a dict,
+so roofline/dryrun code never branches on the backend.
 """
 
 from __future__ import annotations
@@ -11,6 +10,4 @@ from __future__ import annotations
 
 def flatten_cost_analysis(cost) -> dict:
     """Normalize Compiled.cost_analysis() output to a flat dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return dict(cost) if cost else {}

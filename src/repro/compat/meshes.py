@@ -1,85 +1,49 @@
-"""Mesh introspection and activation across JAX versions.
+"""Mesh construction, activation and introspection.
 
-Introspection chain (first hit wins):
-  1. ``thread_resources.env.physical_mesh`` — set by the legacy
-     ``with mesh:`` context; a concrete Mesh with devices, preferred
-     because downstream code may need ``mesh.devices``.
-  2. ``jax.sharding.get_abstract_mesh()`` — newer JAX; set by
-     ``jax.sharding.use_mesh`` / ``jax.set_mesh``.
-
-Activation: ``use_mesh(mesh)`` picks ``jax.sharding.use_mesh`` when it
-exists and falls back to the legacy ``Mesh.__enter__`` context, so call
-sites are written once and survive the deprecation in either direction.
+* ``make_mesh`` builds every mesh with ``Auto`` axes.  ``jax.make_mesh``
+  defaults to ``Explicit`` axes, and ``with_sharding_constraint`` rejects
+  a spec that names an Explicit axis, so a mesh from the bare call breaks
+  every constraint in :mod:`repro.parallel.sharding`.
+* ``use_mesh`` activates a mesh with ``jax.set_mesh``.
+* ``current_mesh`` reads it back through ``jax.sharding.get_abstract_mesh``.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Optional
+from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType
 
 _GET_ABSTRACT_MESH = getattr(jax.sharding, "get_abstract_mesh", None)
-_USE_MESH = getattr(jax.sharding, "use_mesh", None)
+_SET_MESH = getattr(jax, "set_mesh", None)
+
+INTROSPECTION_BRANCH = ("get_abstract_mesh" if _GET_ABSTRACT_MESH is not None
+                        else None)
+ACTIVATION_BRANCH = "set_mesh" if _SET_MESH is not None else None
 
 
-def _thread_resources():
-    try:
-        from jax._src import mesh as mesh_lib
-        return mesh_lib.thread_resources
-    except Exception:
-        return None
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """A mesh of ``shape`` over ``axes`` whose axes are all ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
-INTROSPECTION_BRANCH = (
-    "get_abstract_mesh" if _GET_ABSTRACT_MESH is not None
-    else "thread_resources" if _thread_resources() is not None
-    else None)
-ACTIVATION_BRANCH = "use_mesh" if _USE_MESH is not None else "mesh_context"
-
-
-def abstract_mesh():
-    """The ambient abstract mesh, or None (also None pre-0.5 JAX)."""
-    if _GET_ABSTRACT_MESH is None:
-        return None
+def current_mesh():
+    """The active (abstract) mesh, or None when no mesh is active."""
     mesh = _GET_ABSTRACT_MESH()
     if mesh is None or mesh.empty:
         return None
     return mesh
 
 
-def physical_mesh() -> Optional[Mesh]:
-    """The legacy thread-resources physical mesh, or None."""
-    tr = _thread_resources()
-    if tr is None:
-        return None
-    try:
-        phys = tr.env.physical_mesh
-    except Exception:
-        return None
-    if phys is None or phys.empty:
-        return None
-    return phys
-
-
-def current_mesh() -> Optional[Mesh]:
-    """The active mesh under either activation style, or None."""
-    phys = physical_mesh()
-    if phys is not None:
-        return phys
-    return abstract_mesh()
-
-
 @contextlib.contextmanager
-def use_mesh(mesh: Mesh):
-    """Activate ``mesh`` for the block, new-style when available."""
-    if _USE_MESH is not None:
-        with _USE_MESH(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+def use_mesh(mesh):
+    """Activate ``mesh`` for the block."""
+    with _SET_MESH(mesh):
+        yield mesh
 
 
 def sharding_constraint(x, sharding):

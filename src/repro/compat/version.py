@@ -13,16 +13,15 @@ from typing import Tuple
 
 import jax
 
-# Inclusive lower bound, exclusive upper bound.  0.4.30 is the oldest
-# release the fallback chains were written against; bump SUPPORTED_MAX
-# only after re-running the full suite (scripts/check.sh) on the new
-# release and extending the chains in meshes.py / pallas.py as needed.
-SUPPORTED_MIN: Tuple[int, int, int] = (0, 4, 30)
-SUPPORTED_MAX: Tuple[int, int, int] = (0, 8, 0)
+# Inclusive lower bound, exclusive upper bound: the 0.9 series, the one
+# release the suite and the chip runs use.  Widen the range only after
+# re-running the full suite (scripts/check.sh) on the new release.
+SUPPORTED_MIN: Tuple[int, int, int] = (0, 9, 0)
+SUPPORTED_MAX: Tuple[int, int, int] = (0, 10, 0)
 
 
 def _parse(version: str) -> Tuple[int, int, int]:
-    """'0.4.37' / '0.5.0.dev20250101' -> (0, 4, 37) / (0, 5, 0)."""
+    """'0.9.0' / '0.10.0.dev20260101' -> (0, 9, 0) / (0, 10, 0)."""
     parts = []
     for tok in version.split(".")[:3]:
         digits = ""
@@ -41,9 +40,8 @@ JAX_VERSION: Tuple[int, int, int] = _parse(jax.__version__)
 # Every fallback chain and the branch names it may resolve to.  A None
 # branch means no candidate API exists in the installed JAX at all.
 KNOWN_BRANCHES = {
-    "mesh_introspection": {"get_abstract_mesh", "thread_resources"},
-    "mesh_activation": {"use_mesh", "mesh_context"},
-    "pallas_indexing": {"dslice"},
+    "mesh_introspection": {"get_abstract_mesh"},
+    "mesh_activation": {"set_mesh"},
 }
 
 
@@ -53,14 +51,13 @@ def supported() -> bool:
 
 def api_report() -> dict:
     """Which branch each version-sensitive chain resolved to."""
-    from repro.compat import meshes, pallas
+    from repro.compat import meshes
 
     return {
         "jax": jax.__version__,
         "supported": supported(),
         "mesh_introspection": meshes.INTROSPECTION_BRANCH,
         "mesh_activation": meshes.ACTIVATION_BRANCH,
-        "pallas_indexing": pallas.INDEXING_BRANCH,
     }
 
 

@@ -7,8 +7,8 @@ machine can tap BOTH halves of what it processed:
 * the **receiver** message stream (``Machine.msg_trace``, enabled by
   ``Cluster.enable_msg_trace``), replayed here through the scalar handlers
   (:func:`repro.core.handlers.apply_msg`) AND the SIMD engine
-  (:func:`repro.kernels.paxos_apply.ops.replica_step`, Pallas kernel in
-  interpret mode by default or the pure-jnp oracle), asserting reply- and
+  (:func:`repro.kernels.paxos_apply.ops.replica_step`, Pallas kernel by
+  default or the pure-jnp oracle), asserting reply- and
   plane-for-plane state equality after every conflict-free batch;
 * the **issuer** event stream (``Machine.issuer_trace``, enabled by
   ``Cluster.enable_issuer_trace``): round starts, steered replies,
@@ -127,7 +127,7 @@ def _expected_reply_lanes(rep) -> Dict[str, int]:
 # ---------------------------------------------------------------------------
 
 def replay_trace(trace: Sequence[Msg], *, n_keys: int, num_gsess: int,
-                 use_kernel: bool = True, interpret: bool = True,
+                 use_kernel: bool = True,
                  block_rows: int = 1) -> Dict[str, int]:
     """Replay one machine's message trace through both implementations.
 
@@ -154,7 +154,7 @@ def replay_trace(trace: Sequence[Msg], *, n_keys: int, num_gsess: int,
         msgb = batch_to_msgbatch(batch, n_keys)
         table, replies, registered = ops.replica_step(
             table, msgb, registered, block_rows=block_rows,
-            interpret=interpret, use_kernel=use_kernel)
+            use_kernel=use_kernel)
         rep_np = {f: np.asarray(p) for f, p in
                   zip(vector.ReplyBatch._fields, replies)}
         for msg, rep in zip(batch, scalar_reps):
@@ -189,7 +189,7 @@ def replay_trace(trace: Sequence[Msg], *, n_keys: int, num_gsess: int,
 
 
 def replay_cluster(cluster: Cluster, *, n_keys: int,
-                   use_kernel: bool = True, interpret: bool = True,
+                   use_kernel: bool = True,
                    block_rows: int = 1,
                    machines: Optional[Sequence[int]] = None
                    ) -> Dict[str, int]:
@@ -204,7 +204,7 @@ def replay_cluster(cluster: Cluster, *, n_keys: int,
                 f"cluster.enable_msg_trace() before running the workload")
         stats = replay_trace(trace, n_keys=n_keys,
                              num_gsess=cluster.cfg.num_gsess,
-                             use_kernel=use_kernel, interpret=interpret,
+                             use_kernel=use_kernel,
                              block_rows=block_rows)
         total["machines"] += 1
         for k, v in stats.items():
@@ -217,13 +217,13 @@ def run_and_replay(seed: int, *, n_ops: int = 24, keys: int = 3,
                    net: Optional[NetConfig] = None,
                    rmw_frac: float = 0.45, write_frac: float = 0.3,
                    all_aboard: bool = False,
-                   use_kernel: bool = True, interpret: bool = True,
+                   use_kernel: bool = True,
                    block_rows: int = 1) -> Dict[str, int]:
     """End-to-end harness: seeded faulty sim run -> differential replay.
 
     Defaults exercise the full vocabulary (mixed RMW/write/read) under an
     adversarial network (drops, dups, heavy tails) and replay **every**
-    machine's trace through the Pallas kernel in interpret mode.
+    machine's trace through the Pallas kernel.
     ``all_aboard=True`` deploys the §9 fast path, putting the all-aboard
     epoch-conflict lane into the replayed schedules.
     """
@@ -242,7 +242,7 @@ def run_and_replay(seed: int, *, n_ops: int = 24, keys: int = 3,
     if not cluster.run_until_quiet(max_ticks=120_000):
         raise RuntimeError(f"sim (seed {seed}) did not quiesce")
     stats = replay_cluster(cluster, n_keys=keys, use_kernel=use_kernel,
-                           interpret=interpret, block_rows=block_rows)
+                           block_rows=block_rows)
     stats["history"] = len(cluster.history)
     return stats
 
@@ -275,10 +275,10 @@ _FUSED_NOOP["has_value"] = 1                    # matches MsgBatch.noop
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("use_kernel", "interpret", "block_rows",
+                   static_argnames=("use_kernel", "block_rows",
                                     "shard_lanes"))
 def _fused_wave_step(kv_stack, msg_stack, is_reg, *, use_kernel,
-                     interpret, block_rows, shard_lanes=None):
+                     block_rows, shard_lanes=None):
     """One fused receiver wave: (18,M,K),(11,M,K),(M,K) ->
     (18,M,K),(11,M,K),(M,K) — the ClusterEngine flattening convention
     (machine axis folded into the lane axis, kernel path padded to the
@@ -306,7 +306,7 @@ def _fused_wave_step(kv_stack, msg_stack, is_reg, *, use_kernel,
         new_kv, replies, mask = ops.paxos_apply(
             kv_p, msg_p,
             ops.pad_segments(reg.astype(jnp.int32), seg, seg_pad),
-            block_rows=block_rows, interpret=interpret)
+            block_rows=block_rows)
         new_kv = vector.KVTable(
             *[ops.unpad_segments(a, seg, seg_pad) for a in new_kv])
         replies = type(replies)(
@@ -320,7 +320,7 @@ def _fused_wave_step(kv_stack, msg_stack, is_reg, *, use_kernel,
 
 
 def replay_cluster_fused(cluster: Cluster, *, n_keys: int,
-                         use_kernel: bool = True, interpret: bool = True,
+                         use_kernel: bool = True,
                          block_rows: int = 1,
                          machines: Optional[Sequence[int]] = None
                          ) -> Dict[str, int]:
@@ -383,7 +383,7 @@ def replay_cluster_fused(cluster: Cluster, *, n_keys: int,
                 staged.append((row, msg))
         kv_stack, rep_stack, reg_mask = _fused_wave_step(
             kv_stack, jnp.asarray(msg_host), jnp.asarray(reg_host),
-            use_kernel=use_kernel, interpret=interpret,
+            use_kernel=use_kernel,
             block_rows=block_rows)
         rep_np = np.asarray(rep_stack)
         mask_np = np.asarray(reg_mask)
@@ -439,7 +439,7 @@ def run_and_replay_fused(seed: int, *, n_ops: int = 24, keys: int = 3,
                          cfg: Optional[ProtocolConfig] = None,
                          net: Optional[NetConfig] = None,
                          rmw_frac: float = 0.45, write_frac: float = 0.3,
-                         use_kernel: bool = True, interpret: bool = True,
+                         use_kernel: bool = True,
                          block_rows: int = 1) -> Dict[str, int]:
     """End-to-end fused harness: seeded faulty sim -> stacked replay."""
     cfg = cfg or ProtocolConfig(n_machines=5, sessions_per_machine=2)
@@ -452,14 +452,14 @@ def run_and_replay_fused(seed: int, *, n_ops: int = 24, keys: int = 3,
     if not cluster.run_until_quiet(max_ticks=120_000):
         raise RuntimeError(f"sim (seed {seed}) did not quiesce")
     stats = replay_cluster_fused(cluster, n_keys=keys,
-                                 use_kernel=use_kernel, interpret=interpret,
+                                 use_kernel=use_kernel,
                                  block_rows=block_rows)
     stats["history"] = len(cluster.history)
     return stats
 
 
 def replay_sharded(cluster: Cluster, *, n_keys: int, shards: int = 2,
-                   use_kernel: bool = True, interpret: bool = True,
+                   use_kernel: bool = True,
                    block_rows: int = 1,
                    machines: Optional[Sequence[int]] = None
                    ) -> Dict[str, int]:
@@ -538,7 +538,7 @@ def replay_sharded(cluster: Cluster, *, n_keys: int, shards: int = 2,
                 staged.append((row, msg))
         kv_stack, rep_stack, reg_mask = _fused_wave_step(
             kv_stack, jnp.asarray(msg_host), jnp.asarray(reg_host),
-            use_kernel=use_kernel, interpret=interpret,
+            use_kernel=use_kernel,
             block_rows=block_rows,
             shard_lanes=lps if shards > 1 else None)
         rep_np = np.asarray(rep_stack)
@@ -617,7 +617,7 @@ def run_and_replay_sharded(seed: int, *, shards: int = 2, n_ops: int = 24,
                            cfg: Optional[ProtocolConfig] = None,
                            net: Optional[NetConfig] = None,
                            rmw_frac: float = 0.45, write_frac: float = 0.3,
-                           use_kernel: bool = True, interpret: bool = True,
+                           use_kernel: bool = True,
                            block_rows: int = 1) -> Dict[str, int]:
     """End-to-end sharded harness: seeded faulty sim -> sharded replay."""
     cfg = cfg or ProtocolConfig(n_machines=5, sessions_per_machine=2)
@@ -630,7 +630,7 @@ def run_and_replay_sharded(seed: int, *, shards: int = 2, n_ops: int = 24,
     if not cluster.run_until_quiet(max_ticks=120_000):
         raise RuntimeError(f"sim (seed {seed}) did not quiesce")
     stats = replay_sharded(cluster, n_keys=keys, shards=shards,
-                           use_kernel=use_kernel, interpret=interpret,
+                           use_kernel=use_kernel,
                            block_rows=block_rows)
     stats["history"] = len(cluster.history)
     return stats

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.compat import dslice, load_block, store_block
-
 NEG_INF = -1e30
 
 
@@ -35,7 +33,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
                window: Optional[int], bq: int, bk: int, sk: int,
                q_offset: int):
     qi = pl.program_id(1)
-    q = load_block(q_ref, (0,)).astype(jnp.float32) * scale     # [BQ, D]
+    q = q_ref[0].astype(jnp.float32) * scale                    # [BQ, D]
     d = q.shape[-1]
 
     q_lo = qi * bq + q_offset                           # first query position
@@ -51,10 +49,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
     def body(j, carry):
         acc, m, l = carry
-        k = load_block(k_ref, (0, dslice(j * bk, bk))
-                       ).astype(jnp.float32)            # [BK, D]
-        v = load_block(v_ref, (0, dslice(j * bk, bk))
-                       ).astype(jnp.float32)
+        k = k_ref[0, pl.ds(j * bk, bk)].astype(jnp.float32)   # [BK, D]
+        v = v_ref[0, pl.ds(j * bk, bk)].astype(jnp.float32)
         logits = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)         # [BQ, BK]
@@ -80,7 +76,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
     m0 = jnp.full((bq,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
-    store_block(o_ref, (0,), (acc / l[:, None]).astype(o_ref.dtype))
+    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(

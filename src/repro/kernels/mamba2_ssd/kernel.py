@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import load_block, store_block
-
 NEG_INF = -1e30
 
 
@@ -41,11 +39,11 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, s_ref, *,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    x = load_block(x_ref, (0,)).astype(jnp.float32)      # [L, P]
-    dt = load_block(dt_ref, (0,)).astype(jnp.float32)    # [L]
-    A = load_block(a_ref, (0, 0))                        # scalar (this head)
-    Bm = load_block(b_ref, (0,)).astype(jnp.float32)     # [L, N]
-    Cm = load_block(c_ref, (0,)).astype(jnp.float32)     # [L, N]
+    x = x_ref[0].astype(jnp.float32)                 # [L, P]
+    dt = dt_ref[0].astype(jnp.float32)               # [L]
+    A = a_ref[0, 0]                                  # scalar (this head)
+    Bm = b_ref[0].astype(jnp.float32)                # [L, N]
+    Cm = c_ref[0].astype(jnp.float32)                # [L, N]
     S = s_ref[...]                                   # [N, P]
 
     xdt = x * dt[:, None]                            # [L, P]
@@ -64,7 +62,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, s_ref, *,
     y_inter = jnp.exp(la)[:, None] * jax.lax.dot_general(
         Cm, S, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    store_block(o_ref, (0,), (y_intra + y_inter).astype(o_ref.dtype))
+    o_ref[0] = (y_intra + y_inter).astype(o_ref.dtype)
 
     # state update: S' = exp(la_L) S + sum_s exp(la_L - la_s) B_s xdt_s^T
     total = la[chunk - 1]

@@ -20,13 +20,14 @@ state-bound: §8.6 "we are bottlenecked by the CPU and not the network").
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.compat import load_block, store_block
 from repro.core.vector import KVTable, MsgBatch, ReplyBatch, apply_batch
+from repro.runtime import kernel_interpret
 
 N_KV = len(KVTable._fields)          # 18 state planes
 N_MSG = len(MsgBatch._fields)        # 11 message planes
@@ -45,28 +46,32 @@ def _paxos_apply_kernel(*refs):
     out_rep_refs = out[N_KV:N_KV + N_REP]
     out_mask_ref = out[N_KV + N_REP]
 
-    kv = KVTable(*[load_block(r) for r in kv_refs])
-    msg = MsgBatch(*[load_block(r) for r in msg_refs])
-    is_reg = load_block(reg_ref) != 0
+    kv = KVTable(*[r[...] for r in kv_refs])
+    msg = MsgBatch(*[r[...] for r in msg_refs])
+    is_reg = reg_ref[...] != 0
 
     new_kv, replies, reg_mask = apply_batch(kv, msg, is_reg)
 
     for r, v in zip(out_kv_refs, new_kv):
-        store_block(r, None, v)
+        r[...] = v
     for r, v in zip(out_rep_refs, replies):
-        store_block(r, None, v)
-    store_block(out_mask_ref, None, reg_mask.astype(jnp.int32))
+        r[...] = v
+    out_mask_ref[...] = reg_mask.astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "interpret"))
 def paxos_apply(kv: KVTable, msg: MsgBatch, is_registered: jnp.ndarray,
-                *, block_rows: int = 32, interpret: bool = True):
+                *, block_rows: int = 32, interpret: Optional[bool] = None):
     """Apply a conflict-free message batch on TPU via Pallas.
 
     All lane arrays must be 1-D of equal length; the wrapper in ``ops.py``
     handles padding to a multiple of ``block_rows * 128`` and un-padding.
+    ``interpret=None`` runs compiled on a TPU and interpreted elsewhere
+    (:func:`repro.runtime.kernel_interpret`).
     """
+    if interpret is None:
+        interpret = kernel_interpret()
     n = kv.state.shape[0]
     if n % (block_rows * LANE) != 0:
         raise ValueError(

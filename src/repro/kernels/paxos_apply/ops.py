@@ -113,7 +113,8 @@ def validate_batch(kv: KVTable, msg: MsgBatch, registered: jnp.ndarray,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret",
                                              "use_kernel", "shard_lanes"))
 def _replica_step(kv: KVTable, msg: MsgBatch, registered: jnp.ndarray,
-                  *, block_rows: int, interpret: bool, use_kernel: bool,
+                  *, block_rows: int, interpret: Optional[bool],
+                  use_kernel: bool,
                   shard_lanes: Optional[int] = None):
     n = kv.state.shape[0]
     tile = block_rows * LANE
@@ -142,7 +143,7 @@ def _replica_step(kv: KVTable, msg: MsgBatch, registered: jnp.ndarray,
 
 
 def replica_step(kv: KVTable, msg: MsgBatch, registered: jnp.ndarray,
-                 *, block_rows: int = 32, interpret: bool = True,
+                 *, block_rows: int = 32, interpret: Optional[bool] = None,
                  use_kernel: bool = True,
                  shard_lanes: Optional[int] = None):
     """One receiver step of a replica over a conflict-free message batch.

@@ -24,15 +24,16 @@ kernel-vs-oracle over shape sweeps in interpret mode.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.compat import load_block, store_block
 from repro.core.proposer_vector import (
     ActionBatch, IssuerReplyBatch, ProposerTable, proposer_core,
 )
+from repro.runtime import kernel_interpret
 
 N_TAB = len(ProposerTable._fields)       # 65 session-state planes
 N_REP = len(IssuerReplyBatch._fields)    # 13 steered-reply planes
@@ -51,33 +52,37 @@ def _paxos_propose_kernel(*refs):
     out_tab_refs = out[:N_TAB]
     out_act_refs = out[N_TAB:N_TAB + N_ACT]
 
-    t = ProposerTable(*[load_block(r) for r in tab_refs])
-    rep = IssuerReplyBatch(*[load_block(r) for r in rep_refs])
-    n_machines, majority, commit_need, lth = (load_block(r)
-                                              for r in par_refs)
+    t = ProposerTable(*[r[...] for r in tab_refs])
+    rep = IssuerReplyBatch(*[r[...] for r in rep_refs])
+    n_machines, majority, commit_need, lth = (r[...] for r in par_refs)
 
     new_t, actions = proposer_core(t, rep, n_machines, majority,
                                    commit_need, lth)
 
     for r, v in zip(out_tab_refs, new_t):
-        store_block(r, None, v)
+        r[...] = v
     for r, v in zip(out_act_refs, actions):
-        store_block(r, None, v)
+        r[...] = v
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_rows", "interpret"))
 def paxos_propose(t: ProposerTable, rep: IssuerReplyBatch,
-                  params: jnp.ndarray, *, block_rows: int = 1,
-                  interpret: bool = True):
+                  params: jnp.ndarray, *, block_rows: int = 32,
+                  interpret: Optional[bool] = None):
     """One issuer step over session lanes on TPU via Pallas.
 
     All lane arrays must be 1-D of one equal length; ``params`` is the
     ``(4, n)`` int32 per-lane quorum-parameter stack.  The wrapper in
     ``ops.py`` handles padding to a multiple of ``block_rows * 128`` and
     un-padding (padded lanes carry ``rep.kind = -1`` — idle — so they
-    neither fold nor decide).
+    neither fold nor decide).  A TPU tile is ``(8k, 128)``, so
+    ``block_rows`` must be a multiple of 8 unless one block covers every
+    row.  ``interpret=None`` runs compiled on a TPU and interpreted
+    elsewhere (:func:`repro.runtime.kernel_interpret`).
     """
+    if interpret is None:
+        interpret = kernel_interpret()
     n = t.phase.shape[0]
     if n % (block_rows * LANE) != 0:
         raise ValueError(

@@ -69,8 +69,9 @@ def validate_lanes(t: ProposerTable, rep: IssuerReplyBatch,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret",
                                              "use_kernel", "shard_lanes"))
 def _issuer_step(t: ProposerTable, rep: IssuerReplyBatch,
-                 params: jnp.ndarray, *, block_rows: int, interpret: bool,
-                 use_kernel: bool, shard_lanes: Optional[int] = None):
+                 params: jnp.ndarray, *, block_rows: int,
+                 interpret: Optional[bool], use_kernel: bool,
+                 shard_lanes: Optional[int] = None):
     n = t.phase.shape[0]
     if use_kernel:
         tile = block_rows * LANE
@@ -99,7 +100,7 @@ def _issuer_step(t: ProposerTable, rep: IssuerReplyBatch,
 
 def issuer_step(t: ProposerTable, rep: IssuerReplyBatch, *,
                 n_machines, majority, commit_need, log_too_high_threshold,
-                block_rows: int = 1, interpret: bool = True,
+                block_rows: int = 32, interpret: Optional[bool] = None,
                 use_kernel: bool = True, shard_lanes: Optional[int] = None):
     """One issuer step of a replica over steered-reply session lanes.
 

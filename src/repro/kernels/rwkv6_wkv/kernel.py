@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import load_block, store_block
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
                  chunk: int):
@@ -36,16 +34,16 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    u = load_block(u_ref, (0,)).astype(jnp.float32)     # [K]
+    u = u_ref[0].astype(jnp.float32)                    # [K]
 
     def step(t, S):
-        r_t = load_block(r_ref, (0, t)).astype(jnp.float32)     # [K]
-        k_t = load_block(k_ref, (0, t)).astype(jnp.float32)
-        v_t = load_block(v_ref, (0, t)).astype(jnp.float32)     # [V]
-        w_t = load_block(w_ref, (0, t)).astype(jnp.float32)
+        r_t = r_ref[0, t].astype(jnp.float32)           # [K]
+        k_t = k_ref[0, t].astype(jnp.float32)
+        v_t = v_ref[0, t].astype(jnp.float32)           # [V]
+        w_t = w_ref[0, t].astype(jnp.float32)
         kv = k_t[:, None] * v_t[None, :]                # [K, V]
         y = ((S + u[:, None] * kv) * r_t[:, None]).sum(0)   # [V]
-        store_block(o_ref, (0, t), y.astype(o_ref.dtype))
+        o_ref[0, t] = y.astype(o_ref.dtype)
         return w_t[:, None] * S + kv
 
     s_ref[...] = jax.lax.fori_loop(0, chunk, step, s_ref[...])

@@ -288,7 +288,6 @@ def apply_moe_shardmap(cfg: ModelConfig, p, x, mesh):
       over the sliced ff dim, combined by the same psum.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, s, d = x.shape
     e = cfg.n_experts
@@ -319,11 +318,11 @@ def apply_moe_shardmap(cfg: ModelConfig, p, x, mesh):
     w_specs = ((P(None, None, "model"), P(None, None, "model"),
                 P(None, "model", None)) if tp
                else (P("model"), P("model"), P("model")))
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(),) + w_specs + (P(), P(batch_axes or None)),
         out_specs=(P(batch_axes or None), P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = fn(p["router"], p["w_gate"], p["w_up"], p["w_down"],
                 p["norm"]["scale"], x)
     return y, aux

@@ -274,6 +274,11 @@ class OpenLoopHarness:
         spec = self.spec
         cluster = Cluster(spec.protocol_config(), spec.net_config(),
                           machine_cls=self.machine_cls)
+        if cluster.engine is not None:
+            # the fused engine holds the whole key universe on the device
+            # before the first op, as a deployment would; growing the
+            # shared KV plane mid-run would recompile every fused step
+            cluster.machines[0].kvs.ensure(spec.key_base + spec.n_keys - 1)
         if self.obs is not None:
             cluster.attach_obs(self.obs)
         recorder = LatencyRecorder(self.faults.windows,

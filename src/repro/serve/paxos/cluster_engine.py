@@ -76,7 +76,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import proposer_vector, vector
 from repro.core.lanes import (
@@ -172,9 +172,13 @@ class PlaneStack:
         self.dev: Optional[jnp.ndarray] = None
         self.shard_dirty = np.ones(self.n_shards, dtype=bool)
         self.dev_fresh = False
-        # coherence telemetry: device uploads taken (dirty-plane syncs)
-        # and row evict/reloads — surfaced via ClusterEngine.telemetry()
+        # coherence telemetry: device uploads taken (dirty-plane syncs),
+        # host mirror refreshes (pulls), the bytes both moved, and row
+        # evict/reloads — surfaced via ClusterEngine.telemetry()
         self.syncs = 0
+        self.pulls = 0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
         self.reloads = 0
         self._mesh: Optional[Mesh] = None
         self._sharding: Optional[NamedSharding] = None
@@ -275,6 +279,8 @@ class PlaneStack:
         if self.dev_fresh:
             np.copyto(self.host, np.asarray(self.dev))
             self.dev_fresh = False
+            self.pulls += 1
+            self.d2h_bytes += self.host.nbytes
 
     def read_views(self, mi: int) -> Dict[str, np.ndarray]:
         """Field -> row-``mi`` lane views, for host reads."""
@@ -326,6 +332,7 @@ class PlaneStack:
                 self.dev = jnp.asarray(self.host)
             self.host_dirty = False
             self.syncs += 1
+            self.h2d_bytes += self.host.nbytes
         return self.dev
 
     def absorb(self, dev_out: jnp.ndarray) -> None:
@@ -340,24 +347,8 @@ class PlaneStack:
 # fused step functions (module-level: one jit cache across engines)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("use_kernel", "interpret", "block_rows",
-                                    "shard_lanes", "out_sharding"))
-def _fused_receiver_step(kv_stack, msgreg_stack, *, use_kernel,
-                         interpret, block_rows, shard_lanes=None,
-                         out_sharding=None):
-    """One receiver step for every machine: (18,M,K),(12,M,K) ->
-    (18,M,K),(11,M,K),(M,K).  Flattens the machine axis into the lane axis
-    — apply_batch is elementwise, so rows stay isolated by construction.
-    The 12th input plane is the host-gathered is_registered bit, packed
-    with the message planes so one transfer stages the whole wave.
-
-    ``shard_lanes`` (static) declares the lane axis as shard-aligned
-    segments of that length: each machine row is n_shards contiguous
-    blocks, so the flattened axis is M·n_shards segments, each padded
-    independently to the kernel tile — compiled blocks never straddle a
-    shard boundary.  One segment (``None``) is whole-axis padding; either
-    way the step is elementwise, so the outputs are bit-identical."""
+def _receiver_core(kv_stack, msgreg_stack, use_kernel, block_rows,
+                   shard_lanes, interpret):
     msg_stack = msgreg_stack[:N_MSG]
     is_reg = msgreg_stack[N_MSG]
     m, k = is_reg.shape
@@ -385,29 +376,13 @@ def _fused_receiver_step(kv_stack, msgreg_stack, *, use_kernel,
         mask = unpad_segments(mask, seg, seg_pad) != 0
     else:
         new_kv, replies, mask = vector.apply_batch(kv, msg, reg)
-    new_stack = jnp.stack([a.reshape(m, k) for a in new_kv])
-    if out_sharding is not None:
-        # the (M,K)->(M·K,) flatten defeats sharding propagation (a lane
-        # block per row is not a contiguous block of the merged axis);
-        # re-pin the resident output to its lane-partitioned layout so
-        # residency keeps the planes distributed across waves
-        new_stack = jax.lax.with_sharding_constraint(new_stack, out_sharding)
-    return (new_stack,
+    return (jnp.stack([a.reshape(m, k) for a in new_kv]),
             jnp.stack([a.reshape(m, k) for a in replies]),
             mask.reshape(m, k))
 
 
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("use_kernel", "interpret", "block_rows",
-                                    "shard_lanes", "out_sharding"))
-def _fused_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
-                       interpret, block_rows, shard_lanes=None,
-                       out_sharding=None):
-    """One issuer step for every machine: (65,M,S),(13,M,S),(4,M,1) ->
-    (65,M,S),(14,M,S).  Quorum parameters broadcast per machine row —
-    each machine's active view pins its own quorum sizes (§8.7).
-    ``shard_lanes`` as in :func:`_fused_receiver_step` (session-lane
-    segments)."""
+def _issuer_core(tab_stack, rep_stack, params, use_kernel, block_rows,
+                 shard_lanes, interpret):
     m, s = rep_stack.shape[1], rep_stack.shape[2]
     if use_kernel:
         n = m * s
@@ -419,20 +394,79 @@ def _fused_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
         new_t, act = propose_ops._issuer_step(
             t, rep, par, block_rows=block_rows, interpret=interpret,
             use_kernel=True, shard_lanes=shard_lanes)
-        new_stack = jnp.stack([a.reshape(m, s) for a in new_t])
-        if out_sharding is not None:
-            new_stack = jax.lax.with_sharding_constraint(
-                new_stack, out_sharding)
-        return new_stack, jnp.stack([a.reshape(m, s) for a in act])
+        return (jnp.stack([a.reshape(m, s) for a in new_t]),
+                jnp.stack([a.reshape(m, s) for a in act]))
     t = proposer_vector.ProposerTable(*[tab_stack[i] for i in range(N_TAB)])
     rep = proposer_vector.IssuerReplyBatch(
         *[rep_stack[i] for i in range(N_IREP)])
     new_t, act = proposer_vector.proposer_core(
         t, rep, params[0], params[1], params[2], params[3])
-    new_stack = jnp.stack(new_t)
-    if out_sharding is not None:
-        new_stack = jax.lax.with_sharding_constraint(new_stack, out_sharding)
-    return new_stack, jnp.stack(act)
+    return jnp.stack(new_t), jnp.stack(act)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("use_kernel", "interpret", "block_rows",
+                                    "shard_lanes", "out_sharding"))
+def _fused_receiver_step(kv_stack, msgreg_stack, *, use_kernel,
+                         block_rows, shard_lanes=None, out_sharding=None,
+                         interpret=None):
+    """One receiver step for every machine: (18,M,K),(12,M,K) ->
+    (18,M,K),(11,M,K),(M,K).  Flattens the machine axis into the lane axis
+    — apply_batch is elementwise, so rows stay isolated by construction.
+    The 12th input plane is the host-gathered is_registered bit, packed
+    with the message planes so one transfer stages the whole wave.
+
+    ``shard_lanes`` (static) declares the lane axis as shard-aligned
+    segments of that length: each machine row is n_shards contiguous
+    blocks, so the flattened axis is M·n_shards segments, each padded
+    independently to the kernel tile — compiled blocks never straddle a
+    shard boundary.  One segment (``None``) is whole-axis padding; either
+    way the step is elementwise, so the outputs are bit-identical.
+
+    ``out_sharding`` (static) is the stack's placement on a device mesh.
+    The step then runs under ``shard_map``: each device steps its own lane
+    block (a Mosaic kernel cannot be partitioned automatically), and the
+    outputs keep the stack's placement across waves.  ``check_vma`` is off
+    because ``pallas_call`` outputs carry no varying-axes annotation; a
+    stack too narrow to split is replicated, and every device then
+    computes the same step.  ``interpret``
+    (static) is left to the kernel, which derives it from the platform;
+    only a compile for a described chip passes False."""
+    if out_sharding is None:
+        return _receiver_core(kv_stack, msgreg_stack, use_kernel, block_rows,
+                              shard_lanes, interpret)
+    spec = out_sharding.spec
+    local = functools.partial(_receiver_core, use_kernel=use_kernel,
+                              block_rows=block_rows, shard_lanes=None,
+                              interpret=interpret)
+    return jax.shard_map(local, mesh=out_sharding.mesh,
+                         in_specs=(spec, spec),
+                         out_specs=(spec, spec, P(*tuple(spec)[1:])),
+                         check_vma=False)(kv_stack, msgreg_stack)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("use_kernel", "interpret", "block_rows",
+                                    "shard_lanes", "out_sharding"))
+def _fused_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
+                       block_rows, shard_lanes=None, out_sharding=None,
+                       interpret=None):
+    """One issuer step for every machine: (65,M,S),(13,M,S),(4,M,1) ->
+    (65,M,S),(14,M,S).  Quorum parameters broadcast per machine row —
+    each machine's active view pins its own quorum sizes (§8.7).
+    ``shard_lanes``, ``out_sharding`` and ``interpret`` as in
+    :func:`_fused_receiver_step` (session-lane segments)."""
+    if out_sharding is None:
+        return _issuer_core(tab_stack, rep_stack, params, use_kernel,
+                            block_rows, shard_lanes, interpret)
+    spec = out_sharding.spec
+    local = functools.partial(_issuer_core, use_kernel=use_kernel,
+                              block_rows=block_rows, shard_lanes=None,
+                              interpret=interpret)
+    return jax.shard_map(local, mesh=out_sharding.mesh,
+                         in_specs=(spec, spec, P()),
+                         out_specs=(spec, spec),
+                         check_vma=False)(tab_stack, rep_stack, params)
 
 
 # ---------------------------------------------------------------------------
@@ -442,15 +476,20 @@ def _fused_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
 def _shard_mesh(shards: int) -> Optional[Mesh]:
     """A 1-D ``"shard"`` mesh over the first ``shards`` devices.
 
-    ``None`` when sharding is off or the backend exposes fewer devices —
-    the shard *layout* (aligned lane blocks, steering, per-shard batches)
-    applies host-side either way; only the physical placement needs the
-    devices (CI forces them on CPU via
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``)."""
+    ``None`` when sharding is off.  On a TPU, fewer devices than shards
+    raises: a run that asked for sharded state must not silently put every
+    shard on one chip.  On CPU the shard *layout* (aligned lane blocks,
+    steering, per-shard batches) still applies host-side with no mesh —
+    the tests of that layout rely on it; CI forces the devices with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``."""
     if shards <= 1:
         return None
     devices = jax.devices()
     if len(devices) < shards:
+        if devices[0].platform == "tpu":
+            raise ValueError(
+                f"shards={shards} needs {shards} devices; this host has "
+                f"{len(devices)} {devices[0].device_kind}")
         return None
     return Mesh(np.array(devices[:shards]), ("shard",))
 
@@ -475,11 +514,10 @@ class ClusterEngine:
     """
 
     def __init__(self, cfg, n_machines: int = 1, *,
-                 use_kernel: bool = False, interpret: bool = True,
-                 block_rows: int = 32, n_keys: int = 8, shards: int = 1):
+                 use_kernel: bool = False, block_rows: int = 32,
+                 n_keys: int = 8, shards: int = 1):
         self.cfg = cfg
         self.use_kernel = use_kernel
-        self.interpret = interpret
         self.block_rows = block_rows
         self.shards = max(1, int(shards))
         # session lanes shard only when the axis divides evenly; the KV
@@ -504,6 +542,7 @@ class ClusterEngine:
         self._params_key = None
         self._params_dev: Optional[jnp.ndarray] = None
         self.stats = {"ticks": 0, "shards": self.shards,
+                      "staging_h2d_bytes": 0, "staging_d2h_bytes": 0,
                       "fused_receiver_calls": 0, "fused_receiver_lanes": 0,
                       "fused_issuer_calls": 0, "fused_issuer_lanes": 0,
                       "receiver_shard_lanes": [0] * self.shards,
@@ -515,13 +554,21 @@ class ClusterEngine:
     def telemetry(self) -> Dict[str, object]:
         """``stats`` plus the plane-coherence counters that live on the
         stacks themselves: dirty-plane re-uploads (``plane_syncs``, split
-        per stack) and row evict/reloads (crash/restart + view installs).
-        The flight recorder pulls this at snapshot time."""
+        per stack), host-mirror refreshes (``plane_pulls``), row
+        evict/reloads (crash/restart + view installs), and every byte moved
+        between host and device (``h2d_bytes``/``d2h_bytes``: plane syncs
+        and pulls plus the per-wave staging and reply transfers).  The
+        flight recorder pulls this at snapshot time."""
         t = dict(self.stats)
         t["kv_plane_syncs"] = self.kv.syncs
         t["tab_plane_syncs"] = self.tab.syncs
         t["plane_syncs"] = self.kv.syncs + self.tab.syncs
+        t["plane_pulls"] = self.kv.pulls + self.tab.pulls
         t["row_reloads"] = self.kv.reloads + self.tab.reloads
+        t["h2d_bytes"] = (self.stats["staging_h2d_bytes"]
+                          + self.kv.h2d_bytes + self.tab.h2d_bytes)
+        t["d2h_bytes"] = (self.stats["staging_d2h_bytes"]
+                          + self.kv.d2h_bytes + self.tab.d2h_bytes)
         return t
 
     # -- shard steering ------------------------------------------------------
@@ -578,6 +625,7 @@ class ClusterEngine:
                 p[2, mi, 0] = mach._commit_need
             self._params_dev = jnp.asarray(p)
             self._params_key = key
+            self.stats["staging_h2d_bytes"] += p.nbytes
         return self._params_dev
 
     # -- staging buffers (persistent, reset lane-by-lane) --------------------
@@ -632,8 +680,7 @@ class ClusterEngine:
         msg_host[:, s_mi, s_key] = np.array(cols, I32).T
         out_kv, out_rep, out_mask = _fused_receiver_step(
             self.kv.push(), jnp.asarray(msg_host),
-            use_kernel=self.use_kernel, interpret=self.interpret,
-            block_rows=self.block_rows,
+            use_kernel=self.use_kernel, block_rows=self.block_rows,
             shard_lanes=lps if self.shards > 1 else None,
             out_sharding=self.kv.device_sharding())
         self.kv.absorb(out_kv)
@@ -641,6 +688,8 @@ class ClusterEngine:
             br.drop_views()              # stale against the new stack
         rep_np = np.asarray(out_rep)
         mask_np = np.asarray(out_mask)
+        self.stats["staging_h2d_bytes"] += msg_host.nbytes
+        self.stats["staging_d2h_bytes"] += rep_np.nbytes + mask_np.nbytes
         results: Dict[int, Dict[str, np.ndarray]] = {}
         self.stats["fused_receiver_calls"] += 1
         reg_stat = self.stats["shard_registrations"]
@@ -689,12 +738,13 @@ class ClusterEngine:
         rep_host[:, s_mi, s_lane] = np.array(cols, I32).T
         out_tab, out_act = _fused_issuer_step(
             self.tab.push(), jnp.asarray(rep_host), self._params(),
-            use_kernel=self.use_kernel, interpret=self.interpret,
-            block_rows=self.block_rows,
+            use_kernel=self.use_kernel, block_rows=self.block_rows,
             shard_lanes=lps if self.tab_shards > 1 else None,
             out_sharding=self.tab.device_sharding())
         self.tab.absorb(out_tab)
         act_np = np.asarray(out_act)
+        self.stats["staging_h2d_bytes"] += rep_host.nbytes
+        self.stats["staging_d2h_bytes"] += act_np.nbytes
         results: Dict[int, Dict[str, np.ndarray]] = {}
         self.stats["fused_issuer_calls"] += 1
         for mach, batch in requests:

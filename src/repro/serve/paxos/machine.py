@@ -70,12 +70,11 @@ class BatchedMachine(Machine):
 
     def __init__(self, mid: int, cfg: ProtocolConfig, send, now,
                  incarnation: int = 0, view: Optional[View] = None, *,
-                 use_kernel: bool = False, interpret: bool = True,
-                 block_rows: int = 32, batch_target: Optional[int] = None,
+                 use_kernel: bool = False, block_rows: int = 32,
+                 batch_target: Optional[int] = None,
                  engine: Optional[ClusterEngine] = None, shards: int = 1):
         super().__init__(mid, cfg, send, now, incarnation, view=view)
         self.use_kernel = use_kernel
-        self.interpret = interpret
         self.block_rows = block_rows
         self.shards = max(1, int(shards))
         self.batch_target = (DEFAULT_BATCH_TARGET if batch_target is None
@@ -86,7 +85,6 @@ class BatchedMachine(Machine):
         # stacks without touching this machine's code.
         if engine is None:
             engine = ClusterEngine(cfg, mid + 1, use_kernel=use_kernel,
-                                   interpret=interpret,
                                    block_rows=block_rows,
                                    shards=self.shards)
         self._engine = engine
@@ -124,7 +122,6 @@ class BatchedMachine(Machine):
         first = machines[0]
         eng = ClusterEngine(first.cfg, len(machines),
                             use_kernel=first.use_kernel,
-                            interpret=first.interpret,
                             block_rows=first.block_rows,
                             shards=first.shards)
         for m in machines:

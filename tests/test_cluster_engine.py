@@ -254,3 +254,25 @@ def test_steering_table_shard_of():
     assert table.shard_of((1 << 16) | 3) == 1
     assert table.shard_of((1 << 16) | 9) is None     # unroutable lane
     assert SteeringTable(4).shard_of(2) is None      # unsharded
+
+
+class _FakeDevice:
+    def __init__(self, platform):
+        self.platform = platform
+        self.device_kind = f"fake {platform}"
+
+
+@pytest.mark.parametrize("platform", ("tpu", "cpu"))
+def test_shard_mesh_needs_devices_on_tpu(monkeypatch, platform):
+    """More shards than devices: a TPU run raises rather than put every
+    shard on one chip; CPU keeps the layout-only mode the tests above
+    use."""
+    from repro.serve.paxos import cluster_engine
+    monkeypatch.setattr(cluster_engine.jax, "devices",
+                        lambda: [_FakeDevice(platform)])
+    assert cluster_engine._shard_mesh(1) is None
+    if platform == "tpu":
+        with pytest.raises(ValueError, match="needs 4 devices"):
+            cluster_engine._shard_mesh(4)
+    else:
+        assert cluster_engine._shard_mesh(4) is None

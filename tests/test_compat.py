@@ -1,7 +1,6 @@
-"""The repro.compat contract: pinned-API canary, Pallas index
-normalization (interpret + compiled), mesh fallback-chain equivalence
-under both activation styles, and the no-raw-version-sensitive-calls
-source invariant."""
+"""The repro.compat contract: pinned-API canary, Pallas ref indexing
+(interpret + compiled), Auto-axis meshes under ``jax.set_mesh``, and the
+no-raw-version-sensitive-calls source invariant."""
 
 import pathlib
 
@@ -10,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental import pallas as pl
+from jax.sharding import AxisType
 
 from repro import compat
 from repro.compat.version import KNOWN_BRANCHES
@@ -20,7 +20,7 @@ SRC = pathlib.Path(__file__).resolve().parent.parent / "src" / "repro"
 
 # ---------------------------------------------------------------------------
 # Pinned-API canary: a JAX bump must fail HERE, not as scattered
-# AttributeErrors across 59 tests.
+# AttributeErrors across the suite.
 # ---------------------------------------------------------------------------
 
 def test_pinned_api_canary():
@@ -32,28 +32,25 @@ def test_pinned_api_canary():
 
 def test_flatten_cost_analysis_accepts_both_shapes():
     assert compat.flatten_cost_analysis({"flops": 2.0}) == {"flops": 2.0}
-    assert compat.flatten_cost_analysis([{"flops": 2.0}]) == {"flops": 2.0}
-    assert compat.flatten_cost_analysis([]) == {}
+    assert compat.flatten_cost_analysis({}) == {}
     assert compat.flatten_cost_analysis(None) == {}
 
 
 def test_version_parse_is_tolerant():
     from repro.compat.version import _parse
-    assert _parse("0.4.37") == (0, 4, 37)
-    assert _parse("0.5.0.dev20260101") == (0, 5, 0)
-    assert _parse("0.6") == (0, 6, 0)
+    assert _parse("0.9.0") == (0, 9, 0)
+    assert _parse("0.10.0.dev20260101") == (0, 10, 0)
+    assert _parse("0.9") == (0, 9, 0)
 
 
 def test_no_version_sensitive_calls_outside_compat():
-    """The acceptance grep, enforced from inside the suite: raw
-    get_abstract_mesh / pl.load / pl.store usage lives only in compat."""
+    """The acceptance grep, enforced from inside the suite: mesh
+    construction, activation and introspection live only in compat, and
+    nothing calls the Pallas load/store functions JAX removed."""
     import re
     needles = [re.escape(n) for n in (
-        "get_abstract_mesh", "pl.load(", "pl.store(", "pl.ds(",
-        "thread_resources", "jax.set_mesh", "jax.sharding.use_mesh")]
-    # raw int-indexed ref subscripts (`x_ref[0]`, `o_ref[0, t]`) — the
-    # spelling this compat layer exists to normalize away
-    needles += [r"_ref\[\s*-?\d", r"_ref\[[^\]\n]*,\s*-?\d"]
+        "get_abstract_mesh", "pl.load(", "pl.store(", "thread_resources",
+        "jax.set_mesh", "jax.make_mesh(", "jax.sharding.use_mesh")]
     offenders = []
     for path in SRC.rglob("*.py"):
         if "compat" in path.parts:
@@ -65,12 +62,12 @@ def test_no_version_sensitive_calls_outside_compat():
 
 
 # ---------------------------------------------------------------------------
-# Pallas index normalization
+# Pallas ref indexing: the spelling every kernel uses
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("interpret", [True, False])
 def test_load_store_block_roundtrip(interpret):
-    """int + dynamic-slice + full-slice mixed indices, through a real
+    """int + dynamic-slice + full-slice mixed ref indices, through a real
     pallas_call, on both execution paths."""
     if not interpret and jax.default_backend() != "tpu":
         pytest.skip("compiled Pallas TPU path needs a TPU backend")
@@ -78,21 +75,19 @@ def test_load_store_block_roundtrip(interpret):
     x = jnp.arange(2 * 8 * 128, dtype=jnp.float32).reshape(2, 8, 128)
 
     def kernel(x_ref, o_ref):
-        # static int row, dslice window, full minor — historical shapes
-        row = compat.load_block(x_ref, (1, compat.dslice(2, 4)))   # [4, 128]
+        row = x_ref[1, pl.ds(2, 4)]                                # [4, 128]
         assert row.shape == (4, 128)
-        head = compat.load_block(x_ref, (0,))                      # [8, 128]
+        head = x_ref[0]                                            # [8, 128]
         assert head.shape == (8, 128)
 
         def body(t, acc):
-            # traced scalar index must normalize like a raw int
-            r = compat.load_block(x_ref, (0, t))                   # [128]
-            compat.store_block(o_ref, (1, t), r * 2.0)
+            r = x_ref[0, t]                                        # [128]
+            o_ref[1, t] = r * 2.0
             return acc + r.sum()
 
         total = jax.lax.fori_loop(0, 8, body, jnp.float32(0))
-        compat.store_block(o_ref, (0,), head + total * 0.0)
-        compat.store_block(o_ref, (0, compat.dslice(0, 4)), row)
+        o_ref[0] = head + total * 0.0
+        o_ref[0, pl.ds(0, 4)] = row
 
     got = pl.pallas_call(
         kernel,
@@ -107,64 +102,28 @@ def test_load_store_block_roundtrip(interpret):
     np.testing.assert_allclose(np.asarray(got), want)
 
 
-def test_normalize_rejects_overlong_index():
-    class FakeRef:
-        shape = (4, 4)
-    from repro.compat.pallas import _normalize
-    with pytest.raises(ValueError):
-        _normalize(FakeRef(), (0, 0, 0))
-
-
-def test_normalize_branch_shapes():
-    from repro.compat.pallas import _normalize
-
-    class FakeRef:
-        shape = (2, 8, 128)
-
-    norm, squeeze = _normalize(FakeRef(), (0, pl.dslice(2, 4)))
-    assert squeeze == (0,)
-    assert isinstance(norm[0], type(pl.dslice(0, 1)))
-    assert norm[2] == slice(None)                 # padded to full rank
-    norm, squeeze = _normalize(FakeRef(), None)
-    assert squeeze == () and norm == (slice(None),) * 3
-
-
 # ---------------------------------------------------------------------------
-# Mesh fallback chain: identical resolution under the new-style
-# compat.use_mesh activation and the legacy `with mesh:` context.
+# Meshes: Auto axes, activated with jax.set_mesh
 # ---------------------------------------------------------------------------
 
-def _activations(mesh):
-    import contextlib
-
-    @contextlib.contextmanager
-    def legacy():
-        with mesh:
-            yield mesh
-
-    @contextlib.contextmanager
-    def shimmed():
-        with compat.use_mesh(mesh):
-            yield mesh
-
-    return {"legacy_with_mesh": legacy, "compat_use_mesh": shimmed}
+def test_make_mesh_axes_are_auto():
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    assert tuple(mesh.axis_types) == (AxisType.Auto, AxisType.Auto)
 
 
 def test_mesh_fallback_chain_resolves_identically():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    """The active mesh read back through compat resolves logical axes
+    exactly as the concrete mesh it was activated with."""
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     assert compat.current_mesh() is None          # nothing active
-
-    seen = {}
-    for name, ctx in _activations(mesh).items():
-        with ctx():
-            got = compat.current_mesh()
-            assert got is not None and not got.empty, name
-            assert tuple(got.axis_names) == ("data", "model"), name
-            spec = sharding.resolve(("batch", None, "embed"), got,
-                                    shape=(4, 8, 16))
-            seen[name] = (tuple(got.axis_names), spec)
-        assert compat.current_mesh() is None      # cleanly deactivated
-    assert seen["legacy_with_mesh"] == seen["compat_use_mesh"], seen
+    with compat.use_mesh(mesh):
+        got = compat.current_mesh()
+        assert got is not None and not got.empty
+        assert tuple(got.axis_names) == ("data", "model")
+        logical, shape = ("batch", None, "embed"), (4, 8, 16)
+        assert (sharding.resolve(logical, got, shape=shape)
+                == sharding.resolve(logical, mesh, shape=shape))
+    assert compat.current_mesh() is None          # cleanly deactivated
 
 
 def test_shard_is_noop_without_mesh_and_constrains_with():
@@ -172,16 +131,7 @@ def test_shard_is_noop_without_mesh_and_constrains_with():
     y = sharding.shard(x, ("batch", None))        # no mesh: identity
     assert y is x
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = compat.make_mesh((1,), ("data",))
     with compat.use_mesh(mesh):
         out = jax.jit(lambda a: sharding.shard(a, ("batch", None)))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))
-
-
-def test_physical_vs_abstract_precedence():
-    """With only legacy activation available the chain must pick the
-    physical mesh; when both exist the physical (concrete) one wins."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with mesh:
-        assert compat.physical_mesh() is not None
-        assert compat.current_mesh() is compat.physical_mesh()

@@ -11,7 +11,7 @@ import dataclasses
 import jax
 import numpy as np
 
-from repro.compat import use_mesh
+from repro.compat import make_mesh, use_mesh
 from repro.models import blocks
 from repro.models.common import Init
 from repro.models.config import ModelConfig
@@ -31,7 +31,7 @@ def test_shardmap_matches_spmd():
     cfg, params, x = setup()
     y_spmd, aux_spmd = blocks.apply_moe_spmd(cfg, params, x)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with use_mesh(mesh):
         y_sm, aux_sm = blocks.apply_moe_shardmap(cfg, params, x, mesh)
     np.testing.assert_allclose(np.asarray(y_sm), np.asarray(y_spmd),
@@ -65,7 +65,7 @@ def test_grads_flow_both_paths():
         return blocks.apply_moe_spmd(cfg, p, x)[0].sum()
 
     g1 = jax.grad(loss_spmd)(params)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def loss_sm(p):
         return blocks.apply_moe_shardmap(cfg, p, x, mesh)[0].sum()

@@ -32,7 +32,7 @@ ABOARD_SEEDS = (0, 3, 7, 11, 15)
 @pytest.mark.parametrize("seed", SEEDS)
 def test_differential_replay_kernel(seed):
     stats = replay.run_and_replay(seed, n_ops=24, keys=3,
-                                  use_kernel=True, interpret=True)
+                                  use_kernel=True)
     assert stats["machines"] == 5
     assert stats["messages"] > 0
     assert stats["history"] == 24
@@ -41,7 +41,7 @@ def test_differential_replay_kernel(seed):
 @pytest.mark.parametrize("seed", ABOARD_SEEDS)
 def test_differential_replay_kernel_all_aboard(seed):
     stats = replay.run_and_replay(seed, n_ops=24, keys=3, all_aboard=True,
-                                  use_kernel=True, interpret=True)
+                                  use_kernel=True)
     assert stats["machines"] == 5
     assert stats["history"] == 24
 
@@ -102,7 +102,7 @@ def test_fused_replay_jnp(seed):
 def test_fused_replay_kernel():
     """Same through the Pallas kernel (interpret mode): the machine axis
     folded into the lane axis pads to the block tile and back."""
-    stats = replay.run_and_replay_fused(3, use_kernel=True, interpret=True,
+    stats = replay.run_and_replay_fused(3, use_kernel=True,
                                         block_rows=1)
     assert stats["machines"] == 5
     assert stats["fused_waves"] > 0
@@ -150,7 +150,7 @@ def test_sharded_replay_kernel():
     block pads to its own tile segment, so no compiled block spans a
     shard boundary — and the planes still match the scalar shadows."""
     stats = replay.run_and_replay_sharded(3, shards=4, use_kernel=True,
-                                          interpret=True, block_rows=1)
+                                          block_rows=1)
     assert stats["machines"] == 5
     assert stats["shards"] == 4
     assert stats["fused_waves"] > 0

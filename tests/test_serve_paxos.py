@@ -184,7 +184,7 @@ def test_batched_cluster_kernel_mode():
     """One small seed with the receiver step through the Pallas kernel in
     interpret mode (block_rows=1) instead of the jnp oracle."""
     mcls = functools.partial(BatchedMachine, use_kernel=True,
-                             interpret=True, block_rows=1)
+                             block_rows=1)
     cfg = ProtocolConfig(n_machines=5, sessions_per_machine=2)
     net = NetConfig(seed=6, drop_prob=0.04)
     ref = Cluster(cfg, NetConfig(seed=6, drop_prob=0.04))
