@@ -1,0 +1,16 @@
+"""The fused issuer step's share of the HBM roofline, computed as for
+``recv_step_roofline``."""
+
+import roofline
+
+PROGRAM = "_fused_issuer_step"
+
+
+def read(w):
+    if w.trace is None or PROGRAM not in w.call_bytes:
+        return None
+    n, device_s = w.trace.module(PROGRAM)
+    if not n or device_s <= 0:
+        return None
+    return roofline.share(n, w.call_bytes[PROGRAM], device_s,
+                          w.peaks["hbm_bytes_per_s"])
