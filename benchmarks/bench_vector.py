@@ -353,12 +353,7 @@ def bench_e2e(n_ops: int = 300, keys: int = 32, seed: int = 5,
             agg = {}
             for m in cl.machines:
                 for k, v in m.engine_stats.items():
-                    if isinstance(v, list):
-                        tot = agg.setdefault(k, [0] * len(v))
-                        for i, x in enumerate(v):
-                            tot[i] += x
-                    else:
-                        agg[k] = agg.get(k, 0) + v
+                    agg[k] = agg.get(k, 0) + v
             row["receiver_lanes_per_batch"] = round(
                 agg["receiver_lanes"] / max(agg["receiver_batches"], 1), 2)
             row["issuer_lanes_per_batch"] = round(

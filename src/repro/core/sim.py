@@ -73,6 +73,7 @@ class Network:
         self.stats = {"sent": 0, "dropped": 0, "duplicated": 0,
                       "delivered": 0, "removed_dst": 0, "crashed_dst": 0,
                       "heavy_tail": 0}
+        self.clock = None     # repro.obs.HostClock while a recorder is on
 
     def partition(self, group_a: Sequence[int], group_b: Sequence[int]) -> None:
         for a in group_a:
@@ -116,6 +117,9 @@ class Network:
         separately in ``removed_dst``.  VIEW/SYNC control messages are
         exempt: they are the catch-up plane for exactly those machines.
         """
+        clock = self.clock
+        if clock is not None:
+            clock.begin("net.deliver")
         delivered = 0
         while self.heap and self.heap[0][0] <= until:
             t, _, dst, payload = heapq.heappop(self.heap)
@@ -134,6 +138,8 @@ class Network:
             delivered += 1
         self.stats["delivered"] += delivered
         self.now = until
+        if clock is not None:
+            clock.end()
         return delivered
 
     def pending(self) -> int:
@@ -191,6 +197,7 @@ class Cluster:
         self._inflight: Dict[int, dict] = {}
         self._tag = itertools.count(1)
         self.rounds = 0
+        self.clock = None     # repro.obs.HostClock while a recorder is on
 
     def enable_msg_trace(self) -> None:
         """Record every receiver-side protocol message, per machine and in
@@ -380,8 +387,13 @@ class Cluster:
     # -- driving -------------------------------------------------------------
 
     def step(self, ticks: int = 1) -> None:
+        clock = self.clock
         for _ in range(ticks):
             self.rounds += 1
+            if clock is not None:
+                # the round joins a wall-clock tick to the recorder's
+                # virtual-time op spans
+                clock.begin("tick", round=self.rounds)
             self.network.deliver_due(self.network.now + 1.0, self.machines)
             if self.engine is not None:
                 # fused tick: every machine's generator driven in waves,
@@ -401,6 +413,8 @@ class Cluster:
                 m.completions.clear()
             if self.cfg.reconfig:
                 self._sync_view()
+            if clock is not None:
+                clock.end()
 
     def _complete(self, mid: int, sess: int, comp: Completion) -> None:
         self.completions.append((mid, sess, comp))

@@ -13,6 +13,10 @@ observable:
   path the op actually took (``abd_read`` / ``abd_write`` /
   ``all_aboard_fast`` / ``cp_slow``), plus protocol events (retries,
   steals, helps, quorum-wait ticks, machine crashes);
+* :class:`~repro.obs.clock.HostClock` — the recorder's wall-clock
+  companion: nested host spans inside the served tick (total and self
+  nanoseconds per span name), read through ``ClusterEngine.telemetry()``
+  and kept out of dumps;
 * :mod:`~repro.obs.dump` — deterministic JSONL and Chrome-trace/Perfetto
   exports of the ring, and :func:`~repro.obs.dump.flight_guard` which
   dumps automatically when a checker fails or a smoke script dies;
@@ -28,13 +32,14 @@ the ring is governed by the recorder mode (``off`` / ``sampled`` /
 ``full``).  See ``docs/observability.md``.
 """
 
+from .clock import HostClock
 from .registry import MetricsRegistry
 from .trace import PATHS, FlightRecorder, Span
 from .dump import dump_all, dump_chrome_trace, dump_jsonl, flight_guard
 from .report import load_records, summarize, render_summary
 
 __all__ = [
-    "MetricsRegistry", "FlightRecorder", "Span", "PATHS",
+    "HostClock", "MetricsRegistry", "FlightRecorder", "Span", "PATHS",
     "dump_all", "dump_chrome_trace", "dump_jsonl", "flight_guard",
     "load_records", "summarize", "render_summary",
 ]

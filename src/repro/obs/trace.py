@@ -6,7 +6,9 @@ from the protocol hook sites in :mod:`repro.core.node` (guarded by
 ``msg_trace``/``issuer_trace``, so the default configuration pays nothing).
 All timestamps are **virtual ticks** (``Network.now``), never wall clock:
 a dump is a pure function of (seed, spec, mode), which is what makes the
-byte-identical determinism tests possible.
+byte-identical determinism tests possible.  The recorder's wall-clock
+host spans (:mod:`repro.obs.clock`) are read through
+``ClusterEngine.telemetry()`` and never enter the registry or a dump.
 
 Per-op **path classification** follows the paper's taxonomy:
 
@@ -34,6 +36,7 @@ from __future__ import annotations
 from collections import deque
 from typing import Deque, List, Optional
 
+from .clock import HostClock
 from .registry import MetricsRegistry
 
 # Path taxonomy (keep in sync with docs/observability.md)
@@ -122,6 +125,9 @@ class FlightRecorder:
         self.ring: Deque[dict] = deque(maxlen=capacity)
         self.meta = dict(meta or {})
         self._op_seq = 0
+        # the wall-clock companion: host spans inside the served tick,
+        # reported through ClusterEngine.telemetry(), never dumped
+        self.clock = HostClock()
         self.network = None              # set by attach()
         self.engine = None
         self._machines: List = []
@@ -131,12 +137,16 @@ class FlightRecorder:
     def attach(self, cluster) -> "FlightRecorder":
         """Wire this recorder through a :class:`repro.core.sim.Cluster`:
         every machine's ``obs`` tap, the network stats, the fused engine
-        (when present) and any per-machine ingest scheduler.  Attach
+        (when present) and any per-machine ingest scheduler, each with
+        the recorder's :class:`~repro.obs.clock.HostClock`.  Attach
         *before* submitting work or the path counters cannot reconcile
         with the completion history.  Survives ``restart``/``add_machine``
         (the cluster re-adopts replacement machines)."""
         self.network = cluster.network
         self.engine = getattr(cluster, "engine", None)
+        cluster.clock = self.network.clock = self.clock
+        if self.engine is not None:
+            self.engine.set_clock(self.clock)
         for m in cluster.machines:
             self.adopt(m)
         return self
@@ -150,6 +160,7 @@ class FlightRecorder:
         sched = getattr(machine, "ingest", None)
         if sched is not None and hasattr(sched, "bind_metrics"):
             sched.bind_metrics(self.registry, f"ingest.m{machine.mid}")
+            sched.clock = self.clock
 
     # -- op lifecycle (called from repro.core.node hook sites) ----------------
 
@@ -257,8 +268,11 @@ class FlightRecorder:
 
     def _sync_sources(self) -> None:
         """Pull attached raw stats dicts into the registry as counters
-        (point-in-time: zero hot-path cost, exact at snapshot time)."""
+        (point-in-time: zero hot-path cost, exact at snapshot time).
+        The clock's wall-clock span totals stay out: a dump is a pure
+        function of (seed, spec, mode); its counters are wave counts."""
         reg = self.registry
+        reg.counters.update(self.clock.counters)
         if self.network is not None:
             for k, v in self.network.stats.items():
                 reg.counters["net." + k] = v
@@ -267,6 +281,8 @@ class FlightRecorder:
                      if hasattr(self.engine, "telemetry")
                      else self.engine.stats)
             for k, v in stats.items():
+                if k.startswith("span.") or k in self.clock.counters:
+                    continue
                 if isinstance(v, (int, float)) and not isinstance(v, bool):
                     reg.counters["engine." + k] = v
             calls = stats.get("fused_receiver_calls", 0)

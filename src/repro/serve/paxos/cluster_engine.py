@@ -180,6 +180,7 @@ class PlaneStack:
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.reloads = 0
+        self.clock = None     # repro.obs.HostClock while a recorder is on
         self._mesh: Optional[Mesh] = None
         self._sharding: Optional[NamedSharding] = None
         self._sharding_shape: Optional[Tuple[int, ...]] = None
@@ -277,10 +278,15 @@ class PlaneStack:
     def pull(self) -> None:
         """Sync the host mirror from the latest engine output."""
         if self.dev_fresh:
+            clock = self.clock
+            if clock is not None:
+                clock.begin("plane.pull")
             np.copyto(self.host, np.asarray(self.dev))
             self.dev_fresh = False
             self.pulls += 1
             self.d2h_bytes += self.host.nbytes
+            if clock is not None:
+                clock.end()
 
     def read_views(self, mi: int) -> Dict[str, np.ndarray]:
         """Field -> row-``mi`` lane views, for host reads."""
@@ -325,6 +331,9 @@ class PlaneStack:
         layout (one ``device_put`` distributing the lane blocks).
         """
         if self.host_dirty or self.dev is None:
+            clock = self.clock
+            if clock is not None:
+                clock.begin("plane.push")
             sharding = self.device_sharding()
             if sharding is not None:
                 self.dev = jax.device_put(self.host, sharding)
@@ -333,6 +342,8 @@ class PlaneStack:
             self.host_dirty = False
             self.syncs += 1
             self.h2d_bytes += self.host.nbytes
+            if clock is not None:
+                clock.end()
         return self.dev
 
     def absorb(self, dev_out: jnp.ndarray) -> None:
@@ -469,6 +480,17 @@ def _fused_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
                          check_vma=False)(tab_stack, rep_stack, params)
 
 
+def _wait_for_device(first, *rest) -> None:
+    """Block until a fused step's outputs are computed (the timed
+    ``engine.wait``), without adding a host round trip to the copies that
+    follow.  ``np.asarray`` of a pending array queues its copy behind the
+    computation; starting the first output's copy before waiting keeps
+    that, so the wait ends at the device's finish and the download after
+    it times what the copies add."""
+    first.copy_to_host_async()
+    jax.block_until_ready((first,) + rest)
+
+
 # ---------------------------------------------------------------------------
 # the engine
 # ---------------------------------------------------------------------------
@@ -548,6 +570,12 @@ class ClusterEngine:
                       "receiver_shard_lanes": [0] * self.shards,
                       "issuer_shard_lanes": [0] * self.tab_shards,
                       "shard_registrations": [0] * self.shards}
+        self.clock = None     # repro.obs.HostClock while a recorder is on
+
+    def set_clock(self, clock) -> None:
+        """Time this engine's waves and its stacks' copies on ``clock``
+        (a :class:`repro.obs.HostClock`; ``None`` turns the spans off)."""
+        self.clock = self.kv.clock = self.tab.clock = clock
 
     # -- telemetry -----------------------------------------------------------
 
@@ -557,8 +585,10 @@ class ClusterEngine:
         per stack), host-mirror refreshes (``plane_pulls``), row
         evict/reloads (crash/restart + view installs), and every byte moved
         between host and device (``h2d_bytes``/``d2h_bytes``: plane syncs
-        and pulls plus the per-wave staging and reply transfers).  The
-        flight recorder pulls this at snapshot time."""
+        and pulls plus the per-wave staging and reply transfers).  While a
+        clock is attached it also carries the clock's span totals and
+        counters (:meth:`repro.obs.HostClock.totals`).  The flight
+        recorder pulls this at snapshot time."""
         t = dict(self.stats)
         t["kv_plane_syncs"] = self.kv.syncs
         t["tab_plane_syncs"] = self.tab.syncs
@@ -569,6 +599,8 @@ class ClusterEngine:
                           + self.kv.h2d_bytes + self.tab.h2d_bytes)
         t["d2h_bytes"] = (self.stats["staging_d2h_bytes"]
                           + self.kv.d2h_bytes + self.tab.d2h_bytes)
+        if self.clock is not None:
+            t.update(self.clock.totals())
         return t
 
     # -- shard steering ------------------------------------------------------
@@ -647,7 +679,14 @@ class ClusterEngine:
     # -- fused wave execution ------------------------------------------------
 
     def _run_receiver(self, requests) -> Dict[int, Dict[str, np.ndarray]]:
-        """requests: [(machine, [Msg,...]), ...] — one fused call."""
+        """requests: [(machine, [Msg,...]), ...] — one fused call.
+
+        With a clock attached the call is six sibling spans: ``stage``,
+        ``upload``, ``launch``, ``wait`` (for the device, so the copies
+        after it time the copy alone), ``download`` and ``unstage``."""
+        clock = self.clock
+        if clock is not None:
+            clock.begin("engine.stage")
         # every bridge sharing the stack scatters its checked-out views
         # first: the fused call replaces the *whole* stack
         for br in self._bridges.values():
@@ -678,16 +717,28 @@ class ClusterEngine:
         # one vectorized scatter for the whole wave (per-item fancy writes
         # were the staging hotspot)
         msg_host[:, s_mi, s_key] = np.array(cols, I32).T
+        if clock is not None:
+            clock.switch("engine.upload")
+        kv_dev = self.kv.push()
+        msg_dev = jnp.asarray(msg_host)
+        if clock is not None:
+            clock.switch("engine.launch")
         out_kv, out_rep, out_mask = _fused_receiver_step(
-            self.kv.push(), jnp.asarray(msg_host),
+            kv_dev, msg_dev,
             use_kernel=self.use_kernel, block_rows=self.block_rows,
             shard_lanes=lps if self.shards > 1 else None,
             out_sharding=self.kv.device_sharding())
         self.kv.absorb(out_kv)
         for br in self._bridges.values():
             br.drop_views()              # stale against the new stack
+        if clock is not None:
+            clock.switch("engine.wait")
+            _wait_for_device(out_rep, out_mask)
+            clock.switch("engine.download")
         rep_np = np.asarray(out_rep)
         mask_np = np.asarray(out_mask)
+        if clock is not None:
+            clock.switch("engine.unstage")
         self.stats["staging_h2d_bytes"] += msg_host.nbytes
         self.stats["staging_d2h_bytes"] += rep_np.nbytes + mask_np.nbytes
         results: Dict[int, Dict[str, np.ndarray]] = {}
@@ -716,10 +767,16 @@ class ClusterEngine:
                                  in enumerate(vector.ReplyBatch._fields)}
         # reset to NOOP for the next wave
         msg_host[:, s_mi, s_key] = _NOOP_COL[:, None]
+        if clock is not None:
+            clock.end()
         return results
 
     def _run_issuer(self, requests) -> Dict[int, Dict[str, np.ndarray]]:
-        """requests: [(machine, [(lane, Reply),...]), ...] — one call."""
+        """requests: [(machine, [(lane, Reply),...]), ...] — one call,
+        timed in the same six spans as :meth:`_run_receiver`."""
+        clock = self.clock
+        if clock is not None:
+            clock.begin("engine.stage")
         rep_host = self._rep_buffers()
         fields = proposer_vector.IssuerReplyBatch._fields
         lps = self.tab.n_lanes // self.tab_shards
@@ -736,13 +793,26 @@ class ClusterEngine:
                 s_lane.append(lane)
                 shard_lanes_stat[lane // lps] += 1
         rep_host[:, s_mi, s_lane] = np.array(cols, I32).T
+        if clock is not None:
+            clock.switch("engine.upload")
+        tab_dev = self.tab.push()
+        rep_dev = jnp.asarray(rep_host)
+        params = self._params()
+        if clock is not None:
+            clock.switch("engine.launch")
         out_tab, out_act = _fused_issuer_step(
-            self.tab.push(), jnp.asarray(rep_host), self._params(),
+            tab_dev, rep_dev, params,
             use_kernel=self.use_kernel, block_rows=self.block_rows,
             shard_lanes=lps if self.tab_shards > 1 else None,
             out_sharding=self.tab.device_sharding())
         self.tab.absorb(out_tab)
+        if clock is not None:
+            clock.switch("engine.wait")
+            _wait_for_device(out_act)
+            clock.switch("engine.download")
         act_np = np.asarray(out_act)
+        if clock is not None:
+            clock.switch("engine.unstage")
         self.stats["staging_h2d_bytes"] += rep_host.nbytes
         self.stats["staging_d2h_bytes"] += act_np.nbytes
         results: Dict[int, Dict[str, np.ndarray]] = {}
@@ -754,6 +824,8 @@ class ClusterEngine:
                 in enumerate(proposer_vector.ActionBatch._fields)}
         # reset to idle for the next wave
         rep_host[:, s_mi, s_lane] = _IDLE_COL[:, None]
+        if clock is not None:
+            clock.end()
         return results
 
     def drive(self, pairs: Iterable[Tuple[object, object]]) -> None:
@@ -762,13 +834,20 @@ class ClusterEngine:
         Each wave collects every pending request, executes at most one
         fused receiver call and one fused issuer call, and resumes the
         generators in the order given (mid order — matching the sequential
-        loop's per-machine ordering of host actions)."""
+        loop's per-machine ordering of host actions).  Each resumption is
+        a ``machine`` span: the machine's scalar host decisions."""
+        clock = self.clock
         pending = []
         for mach, gen in pairs:
+            if clock is not None:
+                clock.begin("machine")
             try:
                 req = next(gen)
             except StopIteration:
                 continue
+            finally:
+                if clock is not None:
+                    clock.end()
             pending.append((mach, gen, req))
         while pending:
             recv = [(m, r[1]) for m, _g, r in pending if r[0] == "recv"]
@@ -780,10 +859,15 @@ class ClusterEngine:
                 results.update(self._run_issuer(iss))
             nxt = []
             for mach, gen, _req in pending:
+                if clock is not None:
+                    clock.begin("machine")
                 try:
                     req = gen.send(results[id(mach)])
                 except StopIteration:
                     continue
+                finally:
+                    if clock is not None:
+                        clock.end()
                 nxt.append((mach, gen, req))
             pending = nxt
 
@@ -795,6 +879,9 @@ class ClusterEngine:
         Sends are buffered per machine during the waves and flushed in mid
         order afterwards, reproducing the sequential loop's global send
         sequence exactly (the network draws RNG per send)."""
+        clock = self.clock
+        if clock is not None:
+            clock.begin("engine.step_all")
         self.stats["ticks"] += 1
         for mach in machines:
             if mach._engine is not self:
@@ -812,6 +899,11 @@ class ClusterEngine:
         finally:
             for mach, fn in zip(machines, saved):
                 mach._send = fn
+        if clock is not None:
+            clock.begin("net.send")
         for buf in buffers:
             for src, dst, payload in buf:
                 net_send(src, dst, payload)
+        if clock is not None:
+            clock.end()                  # net.send
+            clock.end()                  # engine.step_all

@@ -109,8 +109,7 @@ class BatchedMachine(Machine):
         # round, so with majority >= 2 they can never decide alone
         self._notes: Deque[Tuple[int, Reply]] = deque()
         self.engine_stats = {"receiver_batches": 0, "receiver_lanes": 0,
-                             "issuer_batches": 0, "issuer_lanes": 0,
-                             "receiver_shard_lanes": [0] * self.shards}
+                             "issuer_batches": 0, "issuer_lanes": 0}
 
     @classmethod
     def attach_engine(cls, machines) -> ClusterEngine:
@@ -269,18 +268,15 @@ class BatchedMachine(Machine):
             self.kvs.ensure(max_key)
         self.ingest.offer_many(run)
         if self.shards > 1:
-            # one emission pass yields the batch AND its per-shard
-            # sub-batches (disjoint plane blocks); the wave still runs as
-            # one fused call spanning shards
-            drained = self.ingest.drain_sharded(self.kvs.shard_map)
+            # emission checks every key against the shard layout; the
+            # wave still runs as one fused call spanning shards, which
+            # counts each shard's lanes (ClusterEngine.stats
+            # ["receiver_shard_lanes"])
+            drained = (batch for batch, _per_shard
+                       in self.ingest.drain_sharded(self.kvs.shard_map))
         else:
-            drained = ((batch, None) for batch in self.ingest.drain())
-        for batch, per_shard in drained:
-            if per_shard is not None:
-                shard_stat = self.engine_stats["receiver_shard_lanes"]
-                for s, sub in enumerate(per_shard):
-                    if sub:
-                        shard_stat[s] += len(sub)
+            drained = self.ingest.drain()
+        for batch in drained:
             # rep_np: field -> this machine's per-key reply row views
             rep_np = yield ("recv", batch)
             for msg in batch:

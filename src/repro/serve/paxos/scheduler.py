@@ -42,12 +42,14 @@ open-loop workload harness (``docs/workloads.md``): :meth:`IngestScheduler.
 gauges` reports ``queue_depth`` (items pending), ``keys_backlogged``
 (distinct keys with a non-empty queue — the fan-out the next emission pass
 faces) and ``oldest_age`` (how many admissions ago the oldest pending item
-arrived — the scheduler-aging signal the fairness mode bounds).  An
-optional :attr:`~IngestScheduler.gauge_hook` fires with that snapshot after
-every emitted batch for in-situ sampling, and
-:meth:`IngestScheduler.bind_metrics` re-homes the same snapshot onto a
-:class:`repro.obs.MetricsRegistry` so the whole stack shares one gauge
-surface (``docs/observability.md``).  :meth:`IngestScheduler.reset`
+arrived — the scheduler-aging signal the fairness mode bounds).
+:meth:`IngestScheduler.bind_metrics` publishes that snapshot after every
+emitted batch onto a :class:`repro.obs.MetricsRegistry`, the stack's one
+gauge surface (``docs/observability.md``).  With a
+:class:`repro.obs.HostClock` attached (:attr:`IngestScheduler.clock`),
+:meth:`~IngestScheduler.drain` counts the waves each message waited
+behind a conflicting head (``ingest.wait_waves`` over
+``ingest.emitted``).  :meth:`IngestScheduler.reset`
 clears all queued state (crash-stop semantics: a machine's staged ingest
 dies with its inbox) while the cumulative ``stats`` counters survive — see
 ``BatchedMachine.crash``.
@@ -116,13 +118,11 @@ class IngestScheduler:
         self._backlogged = 0             # keys with a non-empty queue
         self.stats = {"offered": 0, "emitted": 0, "batches": 0,
                       "conflict_deferrals": 0}
-        # observer called with gauges() after every emitted batch
-        self.gauge_hook: Optional[Callable[[Dict[str, int]], None]] = None
         # the unified gauge surface (repro.obs.MetricsRegistry): when
-        # bound, every emitted batch publishes the same snapshot the
-        # gauge_hook sees — see bind_metrics()
+        # bound, every emitted batch publishes a gauges() snapshot there
         self._metrics = None
         self._metrics_prefix = "ingest"
+        self.clock = None     # repro.obs.HostClock while a recorder is on
 
     # -- ingest ---------------------------------------------------------------
 
@@ -222,9 +222,8 @@ class IngestScheduler:
         """Re-home the gauge surface onto a
         :class:`repro.obs.MetricsRegistry`: every emitted batch publishes
         ``<prefix>.queue_depth`` / ``keys_backlogged`` / ``oldest_age``
-        gauges plus a ``<prefix>.batch_lanes`` occupancy histogram there
-        — the same snapshot any ``gauge_hook`` observer receives, so
-        there is exactly one gauge surface regardless of consumer."""
+        gauges plus a ``<prefix>.batch_lanes`` occupancy histogram there,
+        the one gauge surface of the stack."""
         self._metrics = registry
         self._metrics_prefix = prefix
 
@@ -335,36 +334,46 @@ class IngestScheduler:
         if batch:
             self.stats["batches"] += 1
             self.stats["emitted"] += len(batch)
-            if self._metrics is not None or self.gauge_hook is not None:
+            if self._metrics is not None:
                 g = self.gauges()
-                if self._metrics is not None:
-                    mp = self._metrics_prefix
-                    self._metrics.set_gauge(mp + ".queue_depth",
-                                            g["queue_depth"])
-                    self._metrics.set_gauge(mp + ".keys_backlogged",
-                                            g["keys_backlogged"])
-                    self._metrics.set_gauge(mp + ".oldest_age",
-                                            g["oldest_age"])
-                    self._metrics.observe(mp + ".batch_lanes", len(batch))
-                if self.gauge_hook is not None:
-                    self.gauge_hook(g)
+                mp = self._metrics_prefix
+                self._metrics.set_gauge(mp + ".queue_depth",
+                                        g["queue_depth"])
+                self._metrics.set_gauge(mp + ".keys_backlogged",
+                                        g["keys_backlogged"])
+                self._metrics.set_gauge(mp + ".oldest_age", g["oldest_age"])
+                self._metrics.observe(mp + ".batch_lanes", len(batch))
         return batch, shards
+
+    def _count_wait(self, waves: int, batch: List[object]) -> None:
+        """The ``waves``-th batch of one drain waited that many waves
+        behind a conflicting head, each of its items alike."""
+        self.clock.count("ingest.wait_waves", waves * len(batch))
+        self.clock.count("ingest.emitted", len(batch))
 
     def drain(self) -> Iterator[List[object]]:
         """Emit batches until the queues are empty."""
+        waves = 0
         while self._pending:
             batch = self.emit()
             if not batch:            # defensive: cannot happen (oldest head
                 break                # is always admissible)
+            if self.clock is not None:
+                self._count_wait(waves, batch)
+            waves += 1
             yield batch
 
     def drain_sharded(self, shard_map: ShardMap
                       ) -> Iterator[Tuple[List[object], List[List[object]]]]:
         """:meth:`drain`, yielding ``(batch, per_shard)`` pairs — the
         sharded serve path's emission loop."""
+        waves = 0
         while self._pending:
             batch, shards = self._emit(shard_map)
             if not batch:            # defensive: cannot happen
                 break
+            if self.clock is not None:
+                self._count_wait(waves, batch)
+            waves += 1
             yield batch, shards
 

@@ -192,7 +192,7 @@ if HAVE_HYPOTHESIS:
 
 
 # ---------------------------------------------------------------------------
-# observability: gauges, gauge_hook, reset (crash-stop counter hygiene)
+# observability: gauges, bind_metrics, reset (crash-stop counter hygiene)
 # ---------------------------------------------------------------------------
 
 def test_gauges_track_queue_state():
@@ -225,19 +225,23 @@ def test_gauges_after_partial_emission():
     assert g["oldest_age"] == 3          # head arrived 3 admissions back
 
 
-def test_gauge_hook_fires_once_per_emitted_batch():
+def test_bound_gauges_publish_once_per_emitted_batch():
+    from repro.obs import MetricsRegistry
+
+    reg = MetricsRegistry()
     sched = IngestScheduler(strict_order=True)
-    seen = []
-    sched.gauge_hook = seen.append
+    sched.bind_metrics(reg, "ingest.m0")
     for _ in range(3):
         sched.offer(propose(0))          # conflicts: three batches
     sched.offer(propose(1))
+    depths = []
     for _ in sched.drain():
-        pass
-    assert len(seen) == sched.stats["batches"]
-    # snapshots are live readings taken after each batch drained
-    assert seen[-1]["queue_depth"] == 0
-    assert all(s["queue_depth"] >= 0 for s in seen)
+        # live readings, published as each batch was emitted
+        depths.append(reg.gauge("ingest.m0.queue_depth"))
+        assert depths[-1] == sched.pending()
+    assert depths == [3, 2, 0]
+    hist = reg.snapshot()["histograms"]["ingest.m0.batch_lanes"]
+    assert hist["count"] == sched.stats["batches"] == 3
 
 
 def test_reset_clears_state_keeps_stats():
@@ -390,24 +394,20 @@ def test_gauges_match_oracle_under_key_churn():
 
 def test_bind_metrics_one_gauge_surface():
     """bind_metrics re-homes the gauge surface onto a MetricsRegistry:
-    the registry and any gauge_hook observer see the same snapshot."""
+    after each emitted batch the registry holds what gauges() reads."""
     from repro.obs import MetricsRegistry
 
     reg = MetricsRegistry()
     sched = IngestScheduler(strict_order=True)
     sched.bind_metrics(reg, "ingest.m7")
-    seen = []
-    sched.gauge_hook = seen.append
     for _ in range(3):
         sched.offer(propose(0))                   # conflicts: three batches
     sched.offer(propose(1))
     for _ in sched.drain():
-        pass
-    assert len(seen) == sched.stats["batches"]
-    last = seen[-1]
-    assert reg.gauge("ingest.m7.queue_depth") == last["queue_depth"] == 0
-    assert reg.gauge("ingest.m7.keys_backlogged") == last["keys_backlogged"]
-    assert reg.gauge("ingest.m7.oldest_age") == last["oldest_age"]
+        g = sched.gauges()
+        for name in ("queue_depth", "keys_backlogged", "oldest_age"):
+            assert reg.gauge("ingest.m7." + name) == g[name]
+    assert reg.gauge("ingest.m7.queue_depth") == 0
     hist = reg.snapshot()["histograms"]["ingest.m7.batch_lanes"]
     assert hist["count"] == sched.stats["batches"]
 
