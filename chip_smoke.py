@@ -134,7 +134,9 @@ def run_phase(name: str, spec, *, use_kernel: bool, shards: int = 1,
         "wall_s": wall,
         "fused_calls": tel["fused_receiver_calls"] + tel["fused_issuer_calls"],
         "fused_receiver_calls": tel["fused_receiver_calls"],
-        "plane_syncs": tel["plane_syncs"], "plane_pulls": tel["plane_pulls"],
+        "plane_syncs": tel["plane_syncs"],
+        "plane_wave_ships": tel["plane_wave_ships"],
+        "plane_wave_refreshes": tel["plane_wave_refreshes"],
         "h2d_bytes": tel["h2d_bytes"], "d2h_bytes": tel["d2h_bytes"],
         "paths": {p: paths[p] for p in PATHS},
         "identical_to_scalar": True, "checkers": "green",

@@ -110,8 +110,8 @@ class KVBridge:
 
     @property
     def planes(self) -> Dict[str, np.ndarray]:
-        """Mutable host views of this machine's KV row (pulls device
-        state and marks the stack for re-upload)."""
+        """Mutable host views of this machine's KV row (marks the stack
+        for re-upload)."""
         return self._stack.write_views(self._mi)
 
     @property

@@ -129,19 +129,27 @@ class PlaneStack:
     """Struct-of-arrays planes for the whole cluster, resident on device.
 
     One packed ``(F, M, L)`` int32 array holds field ``f`` of machine ``m``
-    at lane ``l``.  Two coherence flags track the host mirror against the
-    device array:
+    at lane ``l``.  The device array is the authoritative state; the host
+    mirror (:attr:`host`) is what scalar code reads and writes, and every
+    fused wave keeps the two coherent in its own transfers:
 
-    * ``host_dirty`` — host writes not yet uploaded; the next :meth:`push`
-      re-uploads the whole stack (one transfer, however many rows changed).
-    * ``dev_fresh`` — the device array holds engine output the host mirror
-      has not pulled; any host access :meth:`pull`\\ s first.
+    * **down** — a wave that steps this stack downloads the new stack
+      with its other outputs, in the same round trip, and :meth:`absorb`
+      copies it into the mirror.  Host reads find the mirror fresh,
+      with no transfer of their own.
+    * **up** — host writes mark the stack ``host_dirty``; the next wave
+      that steps it ships the mirror together with its staging buffer
+      (:meth:`upload_with`, one batched transfer) instead of a
+      :meth:`push` of its own.
 
-    The donation contract lives here: :meth:`push` hands the device array
-    to a donated jit argument, and :meth:`absorb` immediately replaces
+    :meth:`push` remains the out-of-wave upload, counted apart from the
+    waves' own, for a read-back of the device state.
+
+    The donation contract lives here: the stack handed to a fused step is
+    about to be donated, and :meth:`absorb` immediately replaces
     ``self.dev`` with the engine's *output*.  The donated input reference
     is dropped in the same step, so a donated buffer is never re-read —
-    ``pull`` only ever copies from the freshest output.
+    the mirror is only ever refreshed from the freshest output.
 
     Per-machine field->row view dicts are cached (rebuilt only on growth),
     so host bridges hand out lane views without per-access dict builds.
@@ -171,12 +179,14 @@ class PlaneStack:
         self.host[:] = self._defaults[:, None, None]
         self.dev: Optional[jnp.ndarray] = None
         self.shard_dirty = np.ones(self.n_shards, dtype=bool)
-        self.dev_fresh = False
-        # coherence telemetry: device uploads taken (dirty-plane syncs),
-        # host mirror refreshes (pulls), the bytes both moved, and row
-        # evict/reloads — surfaced via ClusterEngine.telemetry()
+        # coherence telemetry: stacks shipped with a wave's staging
+        # (wave_ships) and mirrors refreshed from a wave's download
+        # (wave_refreshes); out-of-wave uploads (syncs); the bytes all of
+        # them moved, and row evict/reloads — surfaced via
+        # ClusterEngine.telemetry()
+        self.wave_ships = 0
+        self.wave_refreshes = 0
         self.syncs = 0
-        self.pulls = 0
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.reloads = 0
@@ -229,7 +239,6 @@ class PlaneStack:
         self._sharding = None
         self._sharding_shape = None
         if self.dev is not None:
-            self.pull()
             self.dev = None
             self.host_dirty = True
 
@@ -258,7 +267,6 @@ class PlaneStack:
         the callers (bridge key growth, membership joins) keep both
         power-of-two / rare.
         """
-        self.pull()
         new_m = max(self.n_machines, n_machines or 0)
         # lane growth stays shard-aligned: blocks keep their boundaries
         new_l = ShardMap(self.n_shards, self.n_shards).aligned(
@@ -275,27 +283,12 @@ class PlaneStack:
 
     # -- host <-> device coherence -------------------------------------------
 
-    def pull(self) -> None:
-        """Sync the host mirror from the latest engine output."""
-        if self.dev_fresh:
-            clock = self.clock
-            if clock is not None:
-                clock.begin("plane.pull")
-            np.copyto(self.host, np.asarray(self.dev))
-            self.dev_fresh = False
-            self.pulls += 1
-            self.d2h_bytes += self.host.nbytes
-            if clock is not None:
-                clock.end()
-
     def read_views(self, mi: int) -> Dict[str, np.ndarray]:
         """Field -> row-``mi`` lane views, for host reads."""
-        self.pull()
         return self._views[mi]
 
     def write_views(self, mi: int) -> Dict[str, np.ndarray]:
         """Like :meth:`read_views`, but marks the stack for re-upload."""
-        self.pull()
         self.host_dirty = True
         return self._views[mi]
 
@@ -308,8 +301,6 @@ class PlaneStack:
         assert src.fields == self.fields
         if src.n_lanes > self.n_lanes:
             self.grow(n_lanes=src.n_lanes)
-        self.pull()
-        src.pull()
         self.host_dirty = True
         self.reloads += 1
         length = src.n_lanes
@@ -323,10 +314,9 @@ class PlaneStack:
         self.host[:, mi, length:] = self._defaults[:, None]
 
     def push(self) -> jnp.ndarray:
-        """Upload (if stale) and hand the device stack to a fused step.
-
-        The returned array is about to be *donated*: the caller must
-        :meth:`absorb` the step's output before any further host access.
+        """Upload the mirror now if the device array is stale, and return
+        the device stack: the out-of-wave upload (a read-back of device
+        state; a fused wave ships host writes with :meth:`upload_with`).
         A mesh-placed stack uploads straight into its block-partitioned
         layout (one ``device_put`` distributing the lane blocks).
         """
@@ -334,11 +324,8 @@ class PlaneStack:
             clock = self.clock
             if clock is not None:
                 clock.begin("plane.push")
-            sharding = self.device_sharding()
-            if sharding is not None:
-                self.dev = jax.device_put(self.host, sharding)
-            else:
-                self.dev = jnp.asarray(self.host)
+            self.dev = jax.device_put(self.host, self.device_sharding(),
+                                      may_alias=False)
             self.host_dirty = False
             self.syncs += 1
             self.h2d_bytes += self.host.nbytes
@@ -346,12 +333,36 @@ class PlaneStack:
                 clock.end()
         return self.dev
 
-    def absorb(self, dev_out: jnp.ndarray) -> None:
-        """Adopt a fused step's output as the new resident state."""
+    def upload_with(self, staging: np.ndarray
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """A fused wave's one host→device transfer: ``staging``, and the
+        mirror with it when host code wrote to it (or nothing is resident
+        yet), in one batched ``device_put``.  Returns the device stack and
+        staging buffer.  The stack is about to be *donated*: the caller
+        must :meth:`absorb` the step's output before any further host
+        access.  ``may_alias=False``: on the CPU a ``device_put`` of a
+        numpy array would otherwise share its memory, and the donated
+        stack, and with it the step's output, could live in the mirror."""
+        if not self.host_dirty and self.dev is not None:
+            return self.dev, jax.device_put(staging, may_alias=False)
+        self.dev, staging_dev = jax.device_put(
+            (self.host, staging), (self.device_sharding(), None),
+            may_alias=False)
+        self.host_dirty = False
+        self.wave_ships += 1
+        self.h2d_bytes += self.host.nbytes
+        return self.dev, staging_dev
+
+    def absorb(self, dev_out: jnp.ndarray, host_out: np.ndarray) -> None:
+        """Adopt a fused step's output as the new resident state, with
+        ``host_out``, its host copy from the same wave's download, as the
+        new mirror."""
         assert not self.host_dirty, \
-            "host writes raced a fused step; push() must precede absorb()"
+            "host writes raced a fused step; upload_with() ships them"
         self.dev = dev_out
-        self.dev_fresh = True
+        np.copyto(self.host, host_out)
+        self.wave_refreshes += 1
+        self.d2h_bytes += host_out.nbytes
 
 
 # ---------------------------------------------------------------------------
@@ -480,15 +491,19 @@ def _fused_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
                          check_vma=False)(tab_stack, rep_stack, params)
 
 
-def _wait_for_device(first, *rest) -> None:
-    """Block until a fused step's outputs are computed (the timed
-    ``engine.wait``), without adding a host round trip to the copies that
-    follow.  ``np.asarray`` of a pending array queues its copy behind the
-    computation; starting the first output's copy before waiting keeps
-    that, so the wait ends at the device's finish and the download after
-    it times what the copies add."""
-    first.copy_to_host_async()
-    jax.block_until_ready((first,) + rest)
+def _download(outs, clock) -> List[np.ndarray]:
+    """Host copies of a fused step's outputs in one device→host round
+    trip: every copy is started before any is read, so they overlap.
+    With a clock the caller has opened ``engine.wait``: the step's
+    outputs are awaited there (the copies already queued behind the
+    computation, so the wait adds no round trip), and ``engine.download``
+    times what the copies add."""
+    for a in outs:
+        a.copy_to_host_async()
+    if clock is not None:
+        jax.block_until_ready(outs)
+        clock.switch("engine.download")
+    return [np.asarray(a) for a in outs]
 
 
 # ---------------------------------------------------------------------------
@@ -581,19 +596,23 @@ class ClusterEngine:
 
     def telemetry(self) -> Dict[str, object]:
         """``stats`` plus the plane-coherence counters that live on the
-        stacks themselves: dirty-plane re-uploads (``plane_syncs``, split
-        per stack), host-mirror refreshes (``plane_pulls``), row
-        evict/reloads (crash/restart + view installs), and every byte moved
-        between host and device (``h2d_bytes``/``d2h_bytes``: plane syncs
-        and pulls plus the per-wave staging and reply transfers).  While a
+        stacks themselves: stacks shipped inside a wave's upload
+        (``plane_wave_ships``) and mirrors refreshed from a wave's
+        download (``plane_wave_refreshes``), out-of-wave uploads
+        (``plane_syncs``, split per stack), row evict/reloads
+        (crash/restart + view installs), and every byte moved between host and device
+        (``h2d_bytes``/``d2h_bytes``: whole stacks, in a wave or out of
+        one, plus the per-wave staging and reply transfers).  While a
         clock is attached it also carries the clock's span totals and
         counters (:meth:`repro.obs.HostClock.totals`).  The flight
         recorder pulls this at snapshot time."""
         t = dict(self.stats)
+        t["plane_wave_ships"] = self.kv.wave_ships + self.tab.wave_ships
+        t["plane_wave_refreshes"] = (self.kv.wave_refreshes
+                                     + self.tab.wave_refreshes)
         t["kv_plane_syncs"] = self.kv.syncs
         t["tab_plane_syncs"] = self.tab.syncs
         t["plane_syncs"] = self.kv.syncs + self.tab.syncs
-        t["plane_pulls"] = self.kv.pulls + self.tab.pulls
         t["row_reloads"] = self.kv.reloads + self.tab.reloads
         t["h2d_bytes"] = (self.stats["staging_h2d_bytes"]
                           + self.kv.h2d_bytes + self.tab.h2d_bytes)
@@ -719,26 +738,22 @@ class ClusterEngine:
         msg_host[:, s_mi, s_key] = np.array(cols, I32).T
         if clock is not None:
             clock.switch("engine.upload")
-        kv_dev = self.kv.push()
-        msg_dev = jnp.asarray(msg_host)
+        kv_dev, msg_dev = self.kv.upload_with(msg_host)
         if clock is not None:
             clock.switch("engine.launch")
-        out_kv, out_rep, out_mask = _fused_receiver_step(
+        outs = _fused_receiver_step(
             kv_dev, msg_dev,
             use_kernel=self.use_kernel, block_rows=self.block_rows,
             shard_lanes=lps if self.shards > 1 else None,
             out_sharding=self.kv.device_sharding())
-        self.kv.absorb(out_kv)
-        for br in self._bridges.values():
-            br.drop_views()              # stale against the new stack
         if clock is not None:
             clock.switch("engine.wait")
-            _wait_for_device(out_rep, out_mask)
-            clock.switch("engine.download")
-        rep_np = np.asarray(out_rep)
-        mask_np = np.asarray(out_mask)
+        kv_np, rep_np, mask_np = _download(outs, clock)
         if clock is not None:
             clock.switch("engine.unstage")
+        self.kv.absorb(outs[0], kv_np)
+        for br in self._bridges.values():
+            br.drop_views()              # stale against the new stack
         self.stats["staging_h2d_bytes"] += msg_host.nbytes
         self.stats["staging_d2h_bytes"] += rep_np.nbytes + mask_np.nbytes
         results: Dict[int, Dict[str, np.ndarray]] = {}
@@ -795,24 +810,21 @@ class ClusterEngine:
         rep_host[:, s_mi, s_lane] = np.array(cols, I32).T
         if clock is not None:
             clock.switch("engine.upload")
-        tab_dev = self.tab.push()
-        rep_dev = jnp.asarray(rep_host)
+        tab_dev, rep_dev = self.tab.upload_with(rep_host)
         params = self._params()
         if clock is not None:
             clock.switch("engine.launch")
-        out_tab, out_act = _fused_issuer_step(
+        outs = _fused_issuer_step(
             tab_dev, rep_dev, params,
             use_kernel=self.use_kernel, block_rows=self.block_rows,
             shard_lanes=lps if self.tab_shards > 1 else None,
             out_sharding=self.tab.device_sharding())
-        self.tab.absorb(out_tab)
         if clock is not None:
             clock.switch("engine.wait")
-            _wait_for_device(out_act)
-            clock.switch("engine.download")
-        act_np = np.asarray(out_act)
+        tab_np, act_np = _download(outs, clock)
         if clock is not None:
             clock.switch("engine.unstage")
+        self.tab.absorb(outs[0], tab_np)
         self.stats["staging_h2d_bytes"] += rep_host.nbytes
         self.stats["staging_d2h_bytes"] += act_np.nbytes
         results: Dict[int, Dict[str, np.ndarray]] = {}

@@ -33,10 +33,12 @@ def _cluster(seed=11):
 
 
 def _checkout(engine):
-    """Pull both device-resident stacks into their host mirrors and
-    return copies (the 'checked-out snapshot')."""
-    engine.kv.pull()
-    engine.tab.pull()
+    """Copies of both host mirrors (the 'checked-out snapshot'), each
+    checked against its device-resident stack: every wave refreshes the
+    mirror from its own output, so a clean mirror is the device state."""
+    for stack in (engine.kv, engine.tab):
+        if not stack.host_dirty:
+            np.testing.assert_array_equal(np.asarray(stack.dev), stack.host)
     return engine.kv.host.copy(), engine.tab.host.copy()
 
 
@@ -59,10 +61,11 @@ def test_same_tick_twice_from_checked_out_snapshot():
 
 
 def test_checkout_is_stable_across_repeated_pulls():
-    """A checked-out snapshot must not change on re-checkout: pull() may
-    only copy from the freshest output, and pulling twice with no engine
-    step in between has nothing new to copy.  (If pull read the *donated*
-    buffer, XLA would have been free to overwrite it.)"""
+    """A checked-out snapshot must not change on re-checkout: the mirror
+    is only ever refreshed from the freshest output, and checking out
+    twice with no engine step in between has nothing new to read.  (Had
+    the mirror come from the *donated* buffer, XLA would have been free
+    to overwrite it.)"""
     cl = _cluster()
     for _ in range(20):
         cl.step()
@@ -276,3 +279,114 @@ def test_shard_mesh_needs_devices_on_tpu(monkeypatch, platform):
             cluster_engine._shard_mesh(4)
     else:
         assert cluster_engine._shard_mesh(4) is None
+
+
+# ---------------------------------------------------------------------------
+# mirror coherence: each wave refreshes the mirror and ships host writes
+# ---------------------------------------------------------------------------
+
+def _checked_after_every_call(engine, log):
+    """Wrap the engine's fused calls: after each, the stack the call
+    stepped must equal its device array, and so must the other stack
+    unless host code has written to it since its last wave."""
+    for name, stepped in (("_run_receiver", engine.kv),
+                          ("_run_issuer", engine.tab)):
+        def call(requests, _run=getattr(engine, name), _stepped=stepped):
+            out = _run(requests)
+            assert not _stepped.host_dirty
+            for stack in (engine.kv, engine.tab):
+                if not stack.host_dirty:
+                    np.testing.assert_array_equal(
+                        np.asarray(stack.dev), stack.host,
+                        err_msg=f"call {len(log)} {stack.fields[0]}")
+            log.append(name)
+            return out
+        setattr(engine, name, call)
+
+
+@pytest.mark.parametrize("shards", (1, 4))
+@pytest.mark.parametrize("replicas", (3, 5))
+def test_mirror_coherent_after_every_fused_call(replicas, shards):
+    """All-aboard on, one crash and restart: after every fused call the
+    host mirrors equal the device stacks, and the completions are the
+    scalar cluster's."""
+    from repro.core.node import Machine
+
+    cfg = ProtocolConfig(n_machines=replicas, sessions_per_machine=2,
+                         all_aboard=True)
+    mcls = functools.partial(BatchedMachine, shards=shards)
+    pair = [Cluster(cfg, NetConfig(seed=13), machine_cls=cls)
+            for cls in (Machine, mcls)]
+    for cl in pair:
+        workload(cl, n_ops=40, keys=4, seed=13, rmw_frac=0.6,
+                 write_frac=0.2)
+    log = []
+    _checked_after_every_call(pair[1].engine, log)
+    victim = replicas - 1
+    for cl in pair:
+        cl.step(8)
+        cl.crash(victim)
+        cl.step(6)
+        cl.restart(victim)
+        assert cl.run_until_quiet(max_ticks=50_000)
+    assert completion_tuples(pair[1]) == completion_tuples(pair[0])
+    tel = pair[1].engine.telemetry()
+    assert len(log) == tel["plane_wave_refreshes"] > 0
+    assert tel["plane_wave_ships"] > 0 and tel["row_reloads"] > 0
+
+
+def test_one_transfer_each_way_per_fused_call(monkeypatch):
+    """The 3-replica TPC-C cell's shape (3 replicas, 40 sessions each,
+    All-aboard, FAA-heavy on 120 counters), clock on: after warm-up each
+    fused call makes one upload and one download, no whole-stack push runs
+    on its own, and the stacks shipped are exactly the waves
+    that found host writes to ship."""
+    from repro.obs import FlightRecorder
+    from repro.serve.paxos import cluster_engine
+
+    cfg = ProtocolConfig(n_machines=3, sessions_per_machine=40,
+                         all_aboard=True)
+    cl = Cluster(cfg, NetConfig(seed=17), machine_cls=BatchedMachine)
+    cl.attach_obs(FlightRecorder(mode="off"))
+    eng = cl.engine
+    cl.machines[0].kvs.ensure(119)              # 128 lanes, as the cell
+    workload(cl, n_ops=240, keys=120, seed=17, rmw_frac=0.92)
+    cl.step(20)                                  # warm-up
+    before = eng.telemetry()
+
+    puts = []
+    real_put = cluster_engine.jax.device_put
+    monkeypatch.setattr(cluster_engine.jax, "device_put",
+                        lambda *a, **kw: puts.append(1) or real_put(*a, **kw))
+    # a wave carries its stack when the step is handed another array
+    # than the one resident when the wave began
+    resident, carried = [], []
+    for name, stack in (("_run_receiver", eng.kv), ("_run_issuer", eng.tab)):
+        def run(requests, _run=getattr(eng, name), _stack=stack):
+            resident.append(_stack.dev)
+            return _run(requests)
+        setattr(eng, name, run)
+    for name in ("_fused_receiver_step", "_fused_issuer_step"):
+        def step(stack, *a, _step=getattr(cluster_engine, name), **kw):
+            carried.append(stack.nbytes if stack is not resident[-1] else 0)
+            return _step(stack, *a, **kw)
+        monkeypatch.setattr(cluster_engine, name, step)
+    cl.step(60)
+    after = eng.telemetry()
+
+    def delta(key):
+        return after.get(key, 0) - before.get(key, 0)
+    calls = delta("fused_receiver_calls") + delta("fused_issuer_calls")
+    assert calls == len(carried) > 100
+    assert delta("span.engine.upload.n") == calls == len(puts)
+    assert delta("span.engine.download.n") == calls
+    assert delta("span.plane.push.n") == 0
+    assert delta("plane_syncs") == 0
+    assert delta("plane_wave_refreshes") == calls
+    assert delta("plane_wave_ships") == sum(map(bool, carried)) > 0
+    # every stack byte that rode a wave is counted
+    assert delta("h2d_bytes") == delta("staging_h2d_bytes") + sum(carried)
+    assert delta("d2h_bytes") == (
+        delta("staging_d2h_bytes")
+        + delta("fused_receiver_calls") * eng.kv.host.nbytes
+        + delta("fused_issuer_calls") * eng.tab.host.nbytes)

@@ -143,7 +143,10 @@ def test_paths_reconcile_batched_with_engine_telemetry():
     c = snap["counters"]
     # engine wave telemetry flows through the recorder
     assert c["engine.fused_receiver_calls"] > 0
-    assert c["engine.plane_syncs"] > 0
+    # host writes ride the waves' uploads; every wave refreshes a mirror
+    assert c["engine.plane_wave_ships"] > 0
+    assert c["engine.plane_wave_refreshes"] == (
+        c["engine.fused_receiver_calls"] + c["engine.fused_issuer_calls"])
     assert c["engine.row_reloads"] > 0        # crash/restart reloads rows
     assert snap["gauges"]["engine.receiver_lanes_per_call"] > 0
     # every live machine's ingest scheduler reports on the one surface
