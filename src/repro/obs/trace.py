@@ -14,7 +14,10 @@ Per-op **path classification** follows the paper's taxonomy:
 
 * ``abd_read`` / ``abd_write`` — §10–§11 register ops (a read that needed
   the §11 write-back commit round still classifies ``abd_read``; the
-  ``read_write_back`` event on the span records the slow read);
+  ``read_write_back`` event on the span records the slow read, and the
+  served engine counts such reads as ``abd_read_write_backs`` in
+  ``ClusterEngine.telemetry()``, from the machines' ``read_write_backs``
+  stat, which is bumped where that event is recorded);
 * ``all_aboard_fast`` — an RMW that attempted the §9 fast path and was
   never steered onto the classic machinery (no propose round, no retry,
   no helping);

@@ -573,6 +573,7 @@ class ClusterEngine:
             self.kv.set_mesh(self.mesh)
             self.tab.set_mesh(self.mesh)
         self._machines: Dict[int, object] = {}    # mi -> BatchedMachine
+        self._replaced_write_backs = 0    # of incarnations adopt() replaced
         self._bridges: Dict[int, object] = {}     # mi -> its KVBridge
         self._msg_host: Optional[np.ndarray] = None
         self._rep_host: Optional[np.ndarray] = None
@@ -601,8 +602,12 @@ class ClusterEngine:
         download (``plane_wave_refreshes``), out-of-wave uploads
         (``plane_syncs``, split per stack), row evict/reloads
         (crash/restart + view installs), and every byte moved between host and device
-        (``h2d_bytes``/``d2h_bytes``: whole stacks, in a wave or out of
-        one, plus the per-wave staging and reply transfers).  While a
+        (``h2d_bytes``/``d2h_bytes``: the whole stacks, in a wave or out
+        of one, as ``stack_h2d_bytes``/``stack_d2h_bytes``, plus the
+        per-wave staging and reply transfers, ``staging_*``).  It also
+        counts the ABD reads that took the §11 write-back round
+        (``abd_read_write_backs``: the adopted machines'
+        ``read_write_backs``, replaced incarnations included).  While a
         clock is attached it also carries the clock's span totals and
         counters (:meth:`repro.obs.HostClock.totals`).  The flight
         recorder pulls this at snapshot time."""
@@ -614,10 +619,15 @@ class ClusterEngine:
         t["tab_plane_syncs"] = self.tab.syncs
         t["plane_syncs"] = self.kv.syncs + self.tab.syncs
         t["row_reloads"] = self.kv.reloads + self.tab.reloads
+        t["stack_h2d_bytes"] = self.kv.h2d_bytes + self.tab.h2d_bytes
+        t["stack_d2h_bytes"] = self.kv.d2h_bytes + self.tab.d2h_bytes
         t["h2d_bytes"] = (self.stats["staging_h2d_bytes"]
-                          + self.kv.h2d_bytes + self.tab.h2d_bytes)
+                          + t["stack_h2d_bytes"])
         t["d2h_bytes"] = (self.stats["staging_d2h_bytes"]
-                          + self.kv.d2h_bytes + self.tab.d2h_bytes)
+                          + t["stack_d2h_bytes"])
+        t["abd_read_write_backs"] = self._replaced_write_backs + sum(
+            m.stats.get("read_write_backs", 0)
+            for m in self._machines.values())
         if self.clock is not None:
             t.update(self.clock.totals())
         return t
@@ -655,6 +665,9 @@ class ClusterEngine:
             self.tab.load_row(mi, m._engine.tab, m._mi)
             m._engine = self
             m._mi = mi
+        old = self._machines.get(mi)
+        if old is not None and old is not m:
+            self._replaced_write_backs += old.stats.get("read_write_backs", 0)
         self._machines[mi] = m
         self._bridges[mi] = m.kvs
         self._params_key = None

@@ -390,3 +390,42 @@ def test_one_transfer_each_way_per_fused_call(monkeypatch):
         delta("staging_d2h_bytes")
         + delta("fused_receiver_calls") * eng.kv.host.nbytes
         + delta("fused_issuer_calls") * eng.tab.host.nbytes)
+
+
+# ---------------------------------------------------------------------------
+# telemetry: host<->device bytes split into staging and whole stacks
+# ---------------------------------------------------------------------------
+
+def _bytes_split_holds(tel):
+    for way in ("h2d", "d2h"):
+        assert tel[f"{way}_bytes"] == (tel[f"staging_{way}_bytes"]
+                                       + tel[f"stack_{way}_bytes"])
+
+
+def test_bytes_split_into_staging_and_stacks():
+    """After a mixed run (reads, writes, RMWs, a crash and a restart)
+    ``h2d_bytes``/``d2h_bytes`` are the staging plus the whole-stack
+    bytes, and an out-of-wave sync adds one stack to the stack bytes
+    alone."""
+    cfg = ProtocolConfig(n_machines=5, sessions_per_machine=2,
+                         all_aboard=True)
+    cl = Cluster(cfg, NetConfig(seed=21), machine_cls=BatchedMachine)
+    workload(cl, n_ops=40, keys=6, seed=21, rmw_frac=0.4, write_frac=0.3)
+    cl.step(8)
+    cl.crash(4)
+    cl.step(6)
+    cl.restart(4)
+    assert cl.run_until_quiet(max_ticks=50_000)
+    eng = cl.engine
+    before = eng.telemetry()
+    _bytes_split_holds(before)
+    assert before["stack_h2d_bytes"] > 0 and before["stack_d2h_bytes"] > 0
+    assert before["staging_h2d_bytes"] > 0
+    eng.kv.write_views(0)["value"][3] = 9         # a host write
+    eng.kv.push()                                 # synced out of a wave
+    after = eng.telemetry()
+    _bytes_split_holds(after)
+    assert after["stack_h2d_bytes"] == (before["stack_h2d_bytes"]
+                                        + eng.kv.host.nbytes)
+    for key in ("staging_h2d_bytes", "staging_d2h_bytes", "stack_d2h_bytes"):
+        assert after[key] == before[key]

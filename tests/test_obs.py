@@ -320,6 +320,92 @@ def test_abd_read_write_spans_classify_by_kind():
     assert kinds["write"] == "abd_write"
 
 
+# ---------------------------------------------------------------------------
+# the served engine's count of §11 write-backs
+# ---------------------------------------------------------------------------
+
+def batched_abd_cluster(seed, rec):
+    from repro.serve.paxos import BatchedMachine
+
+    cl = Cluster(ProtocolConfig(n_machines=5, sessions_per_machine=2),
+                 NetConfig(seed=seed, min_delay=1, max_delay=3),
+                 machine_cls=BatchedMachine)
+    cl.attach_obs(rec)
+    return cl
+
+
+def write_back_spans(rec):
+    return [r for r in rec.ring if r["type"] == "span"
+            and r["path"] == "abd_read"
+            and any(name == "read_write_back" for _t, name in r["events"])]
+
+
+@pytest.mark.parametrize("seed", [3, 4, 5])
+def test_abd_read_write_backs_reconcile_with_spans(seed):
+    """Reads racing writes on two keys: the engine's count equals the
+    completed ``abd_read`` spans that carry a ``read_write_back`` event,
+    and the recorder's event counter."""
+    rec = FlightRecorder(mode="full", capacity=1 << 14)
+    cl = batched_abd_cluster(seed, rec)
+    workload(cl, n_ops=60, keys=2, seed=seed, rmw_frac=0.0, write_frac=0.4)
+    assert cl.run_until_quiet(max_ticks=50_000)
+    assert_paths_reconcile(rec, cl)
+    n = cl.engine.telemetry()["abd_read_write_backs"]
+    assert n == len(write_back_spans(rec)) > 0
+    assert n == rec.registry.counters["evt.read_write_back"]
+    assert rec.snapshot()["counters"]["engine.abd_read_write_backs"] == n
+
+
+def test_abd_read_write_backs_survive_a_restart():
+    """A restart replaces the machine and its stats: the count keeps
+    the old incarnation's write-backs and matches the event counter."""
+    rec = FlightRecorder(mode="off")
+    cl = batched_abd_cluster(6, rec)
+    workload(cl, n_ops=40, keys=2, seed=6, rmw_frac=0.0, write_frac=0.4)
+    assert cl.run_until_quiet(max_ticks=50_000)
+    before = cl.engine.telemetry()["abd_read_write_backs"]
+    assert before == rec.registry.counters["evt.read_write_back"] > 0
+    for mid in range(5):
+        cl.crash(mid)
+        cl.restart(mid)
+    assert cl.engine.telemetry()["abd_read_write_backs"] == before
+    workload(cl, n_ops=40, keys=2, seed=7, rmw_frac=0.0, write_frac=0.4)
+    assert cl.run_until_quiet(max_ticks=50_000)
+    assert (cl.engine.telemetry()["abd_read_write_backs"]
+            == rec.registry.counters["evt.read_write_back"])
+
+
+def test_read_racing_an_unacknowledged_write_is_counted():
+    """A read issued once the write has entered its phase 2, before any
+    other replica acknowledged it, sees the new value at too few replicas
+    and writes it back (§11): one write-back, counted, and the read
+    returns the written value."""
+    rec = FlightRecorder(mode="full")
+    cl = batched_abd_cluster(3, rec)
+    cl.submit(0, 0, Request(ReqKind.WRITE, 5, value=77))
+    while rec.registry.counters.get("evt.write_phase2", 0) == 0:
+        cl.step()
+    tag = cl.submit(1, 1, Request(ReqKind.READ, 5))
+    assert cl.run_until_quiet()
+    read = next(h for h in cl.history if h["tag"] == tag)
+    assert read["value"] == 77
+    assert [r["tag"] for r in write_back_spans(rec)] == [tag]
+    assert cl.engine.telemetry()["abd_read_write_backs"] == 1
+
+
+def test_read_on_a_quiet_key_counts_no_write_back():
+    rec = FlightRecorder(mode="full")
+    cl = batched_abd_cluster(3, rec)
+    cl.submit(0, 0, Request(ReqKind.WRITE, 5, value=77))
+    assert cl.run_until_quiet()
+    cl.submit(1, 1, Request(ReqKind.READ, 5))
+    cl.submit(2, 1, Request(ReqKind.READ, 6))     # a key never written
+    assert cl.run_until_quiet()
+    assert rec.path_counts()["abd_read"] == 2
+    assert cl.engine.telemetry()["abd_read_write_backs"] == 0
+    assert write_back_spans(rec) == []
+
+
 def test_dump_all_names_are_deterministic(tmp_path):
     rec = FlightRecorder()
     sp = rec.op_begin(0, 0, "read", key=0, tag=0, t=1.0)
