@@ -49,9 +49,8 @@ ZIPF_S = 0.99
 MIX = "update_heavy"
 SEED = 11
 # Offered load: Poisson arrivals at RATE ops per virtual tick for TICKS
-# ticks, ~100 ops per kernel phase.  At 2^20 keys each op costs about a
-# second on one v5e, almost all of it whole-plane host<->device copies
-# (ROADMAP A3), so this keeps the one-chip run to a few minutes.
+# ticks, ~100 ops per kernel phase, which keeps the one-chip run to a few
+# minutes.
 RATE = 0.5
 TICKS = 200
 JNP_TICKS = 70
@@ -137,6 +136,8 @@ def run_phase(name: str, spec, *, use_kernel: bool, shards: int = 1,
         "plane_syncs": tel["plane_syncs"],
         "plane_wave_ships": tel["plane_wave_ships"],
         "plane_wave_refreshes": tel["plane_wave_refreshes"],
+        "compact_receiver_waves": tel["compact_receiver_waves"],
+        "patched_lanes": tel["patched_lanes"],
         "h2d_bytes": tel["h2d_bytes"], "d2h_bytes": tel["d2h_bytes"],
         "paths": {p: paths[p] for p in PATHS},
         "identical_to_scalar": True, "checkers": "green",

@@ -188,10 +188,12 @@ class KVBridge:
     # -- engine boundary ------------------------------------------------------
 
     def flush(self) -> None:
-        """Scatter every checked-out view back into the row's planes."""
+        """Scatter every checked-out view back into the row's planes,
+        recording the lanes written as patches (:meth:`PlaneStack.patch_views
+        <repro.serve.paxos.cluster_engine.PlaneStack.patch_views>`)."""
         if not self._views:
             return
-        planes = self._stack.write_views(self._mi)
+        planes = self._stack.patch_views(self._mi, self._views)
         for key, kv in self._views.items():
             for f, v in kv_to_lanes(kv).items():
                 planes[f][key] = v

@@ -131,25 +131,32 @@ class PlaneStack:
     One packed ``(F, M, L)`` int32 array holds field ``f`` of machine ``m``
     at lane ``l``.  The device array is the authoritative state; the host
     mirror (:attr:`host`) is what scalar code reads and writes, and every
-    fused wave keeps the two coherent in its own transfers:
+    fused wave keeps the two coherent in its own transfers.  A wave takes
+    one of two wires (:meth:`ClusterEngine._run_receiver` picks by shape):
 
-    * **down** — a wave that steps this stack downloads the new stack
-      with its other outputs, in the same round trip, and :meth:`absorb`
-      copies it into the mirror.  Host reads find the mirror fresh,
-      with no transfer of their own.
-    * **up** — host writes mark the stack ``host_dirty``; the next wave
-      that steps it ships the mirror together with its staging buffer
-      (:meth:`upload_with`, one batched transfer) instead of a
-      :meth:`push` of its own.
+    * **dense** — the wave downloads the whole new stack with its other
+      outputs and :meth:`absorb` copies it into the mirror; host writes
+      mark the stack ``host_dirty`` and ride up whole with the wave's
+      staging buffer (:meth:`upload_with`, one batched transfer).
+    * **compact** — the wave downloads only the lanes its step touched
+      and :meth:`absorb_lanes` scatters them into the mirror (every other
+      lane was a NOOP, bit-identical).  Host writes recorded lane by lane
+      (:meth:`patch_views`) ride up as a ``(1 + F, width)`` patch array
+      with the wave's compact staging (:meth:`upload_compact`); a
+      whole-row write (:meth:`write_views`, :meth:`load_row`,
+      :meth:`grow`), or more patch lanes than one patch array holds,
+      ships the whole stack instead.
 
-    :meth:`push` remains the out-of-wave upload, counted apart from the
-    waves' own, for a read-back of the device state.
+    Either way host reads find the mirror fresh, with no transfer of
+    their own.  :meth:`push` remains the out-of-wave upload, counted apart
+    from the waves' own, for a read-back of the device state.
 
     The donation contract lives here: the stack handed to a fused step is
-    about to be donated, and :meth:`absorb` immediately replaces
-    ``self.dev`` with the engine's *output*.  The donated input reference
-    is dropped in the same step, so a donated buffer is never re-read —
-    the mirror is only ever refreshed from the freshest output.
+    about to be donated, and :meth:`absorb`/:meth:`absorb_lanes`
+    immediately replace ``self.dev`` with the engine's *output*.  The
+    donated input reference is dropped in the same step, so a donated
+    buffer is never re-read — the mirror is only ever refreshed from the
+    freshest output.
 
     Per-machine field->row view dicts are cached (rebuilt only on growth),
     so host bridges hand out lane views without per-access dict builds.
@@ -160,7 +167,7 @@ class PlaneStack:
     places the device array on a JAX mesh with a ``"shard"`` axis — the
     lane dimension block-partitions over it (``repro.parallel.sharding``
     rule ``"lanes"``), so a shard's lane block and its device are the same
-    thing.  Host dirtiness is tracked per shard block
+    thing.  Whole-row host dirtiness is tracked per shard block
     (:attr:`shard_dirty`): whole-row host writes mark every block, a
     per-shard flush (:meth:`mark_shard_dirty`) marks one; the upload
     itself ships the stack in one transfer either way (the donated device
@@ -179,12 +186,16 @@ class PlaneStack:
         self.host[:] = self._defaults[:, None, None]
         self.dev: Optional[jnp.ndarray] = None
         self.shard_dirty = np.ones(self.n_shards, dtype=bool)
+        # host writes recorded lane by lane: flat lane indices m * L + l
+        self._patches: set = set()
+        self._no_patches: Optional[jnp.ndarray] = None
         # coherence telemetry: stacks shipped with a wave's staging
-        # (wave_ships) and mirrors refreshed from a wave's download
-        # (wave_refreshes); out-of-wave uploads (syncs); the bytes all of
-        # them moved, and row evict/reloads — surfaced via
-        # ClusterEngine.telemetry()
+        # (wave_ships), lanes shipped as patches instead (patched_lanes)
+        # and mirrors refreshed from a wave's download (wave_refreshes);
+        # out-of-wave uploads (syncs); the bytes all of them moved, and
+        # row evict/reloads — surfaced via ClusterEngine.telemetry()
         self.wave_ships = 0
+        self.patched_lanes = 0
         self.wave_refreshes = 0
         self.syncs = 0
         self.h2d_bytes = 0
@@ -212,15 +223,19 @@ class PlaneStack:
         """The key→shard steering for this stack's current lane axis."""
         return ShardMap(self.n_shards, self.n_lanes)
 
-    # -- host dirtiness (tracked per shard block) ----------------------------
+    # -- host dirtiness (whole rows per shard block, or lane patches) --------
 
     @property
     def host_dirty(self) -> bool:
-        return bool(self.shard_dirty.any())
+        """Whether host code wrote to the mirror since the device array
+        last took it in, whole rows or single lanes."""
+        return bool(self._patches) or bool(self.shard_dirty.any())
 
     @host_dirty.setter
     def host_dirty(self, value: bool) -> None:
         self.shard_dirty[:] = value
+        if not value:
+            self._patches.clear()
 
     def mark_shard_dirty(self, shard: int) -> None:
         """Record host writes confined to one shard's lane block."""
@@ -279,6 +294,8 @@ class PlaneStack:
         self.host = grown
         self.dev = None
         self.host_dirty = True
+        self._patches.clear()            # flat indices of the old shape
+        self._no_patches = None
         self._rebuild_views()
 
     # -- host <-> device coherence -------------------------------------------
@@ -290,6 +307,15 @@ class PlaneStack:
     def write_views(self, mi: int) -> Dict[str, np.ndarray]:
         """Like :meth:`read_views`, but marks the stack for re-upload."""
         self.host_dirty = True
+        return self._views[mi]
+
+    def patch_views(self, mi: int, lanes: Iterable[int]
+                    ) -> Dict[str, np.ndarray]:
+        """Like :meth:`write_views` for a caller that writes only
+        ``lanes`` of row ``mi``: records them as lane patches, which a
+        compact wave ships instead of the whole stack."""
+        base = mi * self.n_lanes
+        self._patches.update(base + lane for lane in lanes)
         return self._views[mi]
 
     def load_row(self, mi: int, src: "PlaneStack", src_mi: int) -> None:
@@ -353,6 +379,42 @@ class PlaneStack:
         self.h2d_bytes += self.host.nbytes
         return self.dev, staging_dev
 
+    def upload_compact(self, staging: np.ndarray, width: int
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+        """A compact wave's one host→device transfer: ``staging`` and the
+        host writes, in one batched ``device_put``.  Recorded lane patches
+        ride as a ``(1 + F, width)`` array: row 0 the flat lane index
+        (padding aims past the stack and is dropped), rows 1.. the lane's
+        mirror values.  With no patch lanes, or a whole-row write, more
+        patch lanes than ``width`` or nothing resident yet,
+        :meth:`upload_with` ships instead, with an all-padding patch
+        array that stays on the device.  Returns the device stack,
+        staging and patches; the same donation rule holds."""
+        if (self.dev is None or self.shard_dirty.any()
+                or not self._patches or len(self._patches) > width):
+            return (*self.upload_with(staging), self._empty_patches(width))
+        idx = np.fromiter(self._patches, np.int64, len(self._patches))
+        patches = np.zeros((1 + len(self.fields), width), I32)
+        patches[0] = self.n_machines * self.n_lanes
+        patches[0, :len(idx)] = idx
+        patches[1:, :len(idx)] = self.host.reshape(len(self.fields), -1)[
+            :, idx]
+        staging_dev, patches_dev = jax.device_put((staging, patches),
+                                                  may_alias=False)
+        self.patched_lanes += len(idx)
+        self.h2d_bytes += patches.nbytes
+        self._patches.clear()
+        return self.dev, staging_dev, patches_dev
+
+    def _empty_patches(self, width: int) -> jnp.ndarray:
+        """An all-padding patch array, uploaded once per shape."""
+        if self._no_patches is None or self._no_patches.shape[1] != width:
+            empty = np.zeros((1 + len(self.fields), width), I32)
+            empty[0] = self.n_machines * self.n_lanes
+            self._no_patches = jax.device_put(empty)
+            self.h2d_bytes += empty.nbytes
+        return self._no_patches
+
     def absorb(self, dev_out: jnp.ndarray, host_out: np.ndarray) -> None:
         """Adopt a fused step's output as the new resident state, with
         ``host_out``, its host copy from the same wave's download, as the
@@ -363,6 +425,20 @@ class PlaneStack:
         np.copyto(self.host, host_out)
         self.wave_refreshes += 1
         self.d2h_bytes += host_out.nbytes
+
+    def absorb_lanes(self, dev_out: jnp.ndarray, mi: np.ndarray,
+                     lanes: np.ndarray, cols: np.ndarray) -> None:
+        """:meth:`absorb` for the compact wire: ``cols``, the same wave's
+        download of the output's columns at its staged entries, holds
+        lane ``lanes[j]`` of row ``mi[j]`` in column ``j`` (the columns
+        past ``len(lanes)`` are padding); every other lane of the output
+        equals the mirror."""
+        assert not self.host_dirty, \
+            "host writes raced a fused step; upload_compact() ships them"
+        self.dev = dev_out
+        self.host[:, mi, lanes] = cols[:, :len(lanes)]
+        self.wave_refreshes += 1
+        self.d2h_bytes += cols.nbytes
 
 
 # ---------------------------------------------------------------------------
@@ -426,17 +502,42 @@ def _issuer_core(tab_stack, rep_stack, params, use_kernel, block_rows,
     return jnp.stack(new_t), jnp.stack(act)
 
 
+def _expand_compact(kv_stack, entries, patches):
+    """A compact wave's operands made dense on the device: the host-written
+    lanes of ``patches`` scattered into the stack, and the staged
+    ``entries`` scattered plane by plane into an all-NOOP ``(12, M, K)``
+    message operand.  Row 0 of both arrays is the flat lane index
+    ``m·K + l``; padding aims at row ``M``, past the stack, and is
+    dropped."""
+    k = kv_stack.shape[2]
+    kv = kv_stack.at[:, patches[0] // k, patches[0] % k].set(
+        patches[1:], mode="drop")
+    rows, lanes = entries[0] // k, entries[0] % k
+    msgreg = jnp.stack([
+        jnp.full(kv_stack.shape[1:], noop, jnp.int32).at[rows, lanes].set(
+            entries[1 + i], mode="drop")
+        for i, noop in enumerate(_NOOP_COL.tolist())])
+    return kv, msgreg
+
+
 @functools.partial(jax.jit, donate_argnums=(0,),
                    static_argnames=("use_kernel", "interpret", "block_rows",
                                     "shard_lanes", "out_sharding"))
-def _fused_receiver_step(kv_stack, msgreg_stack, *, use_kernel,
-                         block_rows, shard_lanes=None, out_sharding=None,
-                         interpret=None):
+def _fused_receiver_step(kv_stack, msgreg_stack, patches=None, *,
+                         use_kernel, block_rows, shard_lanes=None,
+                         out_sharding=None, interpret=None):
     """One receiver step for every machine: (18,M,K),(12,M,K) ->
     (18,M,K),(11,M,K),(M,K).  Flattens the machine axis into the lane axis
     — apply_batch is elementwise, so rows stay isolated by construction.
     The 12th input plane is the host-gathered is_registered bit, packed
     with the message planes so one transfer stages the whole wave.
+
+    With ``patches`` the wave came on the compact wire: ``msgreg_stack``
+    is then a ``(13, W)`` batch of staged entries and ``patches`` a
+    ``(19, W)`` array of host-written lanes, each with the flat lane index
+    as row 0 (:func:`_expand_compact`).  The patches land in the donated
+    stack and the entries become the dense message operand on the device,
+    so the step is the same elementwise pass over the whole stack.
 
     ``shard_lanes`` (static) declares the lane axis as shard-aligned
     segments of that length: each machine row is n_shards contiguous
@@ -451,9 +552,13 @@ def _fused_receiver_step(kv_stack, msgreg_stack, *, use_kernel,
     outputs keep the stack's placement across waves.  ``check_vma`` is off
     because ``pallas_call`` outputs carry no varying-axes annotation; a
     stack too narrow to split is replicated, and every device then
-    computes the same step.  ``interpret``
-    (static) is left to the kernel, which derives it from the platform;
-    only a compile for a described chip passes False."""
+    computes the same step.  A sharded stack takes the dense wire only.
+    ``interpret`` (static) is left to the kernel, which derives it from
+    the platform; only a compile for a described chip passes False."""
+    if patches is not None:
+        assert out_sharding is None, "a sharded stack takes the dense wire"
+        kv_stack, msgreg_stack = _expand_compact(kv_stack, msgreg_stack,
+                                                 patches)
     if out_sharding is None:
         return _receiver_core(kv_stack, msgreg_stack, use_kernel, block_rows,
                               shard_lanes, interpret)
@@ -465,6 +570,20 @@ def _fused_receiver_step(kv_stack, msgreg_stack, *, use_kernel,
                          in_specs=(spec, spec),
                          out_specs=(spec, spec, P(*tuple(spec)[1:])),
                          check_vma=False)(kv_stack, msgreg_stack)
+
+
+@jax.jit
+def _touched_lanes(kv_stack, replies, mask, entries):
+    """A receiver step's outputs at a compact wave's staged entries,
+    packed ``(18 + 11 + 1, W)``: the new KV columns, the reply columns
+    and the registration mask, read from the outputs as the step returned
+    them.  Padding entries read a clipped lane, which the host drops."""
+    k = mask.shape[1]
+    rows, lanes = entries[0] // k, entries[0] % k
+    return jnp.concatenate([
+        kv_stack.at[:, rows, lanes].get(mode="clip"),
+        replies.at[:, rows, lanes].get(mode="clip"),
+        mask.at[rows, lanes].get(mode="clip")[None].astype(jnp.int32)])
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),
@@ -576,12 +695,15 @@ class ClusterEngine:
         self._replaced_write_backs = 0    # of incarnations adopt() replaced
         self._bridges: Dict[int, object] = {}     # mi -> its KVBridge
         self._msg_host: Optional[np.ndarray] = None
+        self._entries_host: Optional[np.ndarray] = None
+        self._replies_host: Optional[np.ndarray] = None
         self._rep_host: Optional[np.ndarray] = None
         self._params_key = None
         self._params_dev: Optional[jnp.ndarray] = None
         self.stats = {"ticks": 0, "shards": self.shards,
                       "staging_h2d_bytes": 0, "staging_d2h_bytes": 0,
                       "fused_receiver_calls": 0, "fused_receiver_lanes": 0,
+                      "compact_receiver_waves": 0,
                       "fused_issuer_calls": 0, "fused_issuer_lanes": 0,
                       "receiver_shard_lanes": [0] * self.shards,
                       "issuer_shard_lanes": [0] * self.tab_shards,
@@ -613,6 +735,7 @@ class ClusterEngine:
         recorder pulls this at snapshot time."""
         t = dict(self.stats)
         t["plane_wave_ships"] = self.kv.wave_ships + self.tab.wave_ships
+        t["patched_lanes"] = self.kv.patched_lanes
         t["plane_wave_refreshes"] = (self.kv.wave_refreshes
                                      + self.tab.wave_refreshes)
         t["kv_plane_syncs"] = self.kv.syncs
@@ -701,6 +824,19 @@ class ClusterEngine:
             self._msg_host[:] = _NOOP_COL[:, None, None]
         return self._msg_host
 
+    def _entry_buffers(self, width: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The compact wire's staging buffer, ``(1 + 12, width)``, every
+        column padding (a NOOP aimed past the stack), and the persistent
+        ``(11, M, K)`` host plane its replies are scattered into."""
+        shape = (N_REP, self.kv.n_machines, self.kv.n_lanes)
+        if (self._replies_host is None or self._replies_host.shape != shape
+                or self._entries_host.shape[1] != width):
+            self._entries_host = np.empty((1 + N_MSGREG, width), I32)
+            self._entries_host[0] = shape[1] * shape[2]
+            self._entries_host[1:] = _NOOP_COL[:, None]
+            self._replies_host = np.zeros(shape, I32)
+        return self._entries_host, self._replies_host
+
     def _rep_buffers(self) -> np.ndarray:
         shape = (N_IREP, self.tab.n_machines, self.tab.n_lanes)
         if self._rep_host is None or self._rep_host.shape != shape:
@@ -708,10 +844,35 @@ class ClusterEngine:
             self._rep_host[:] = _IDLE_COL[:, None, None]
         return self._rep_host
 
+    def _wave_width(self) -> Optional[int]:
+        """Entries one compact receiver wave stages: machines × the
+        machines' batch target (one batch per machine per wave, each
+        capped by its ``IngestScheduler``).  ``None`` picks the dense
+        wire: a machine has no batch target, the stack lies on a device
+        mesh, or the plane is no wider than a wave, so a compact batch
+        would save nothing."""
+        if self.kv.device_sharding() is not None:
+            return None
+        targets = [m.batch_target for m in self._machines.values()]
+        if not targets or None in targets:
+            return None
+        width = self.kv.n_machines * max(targets)
+        if self.kv.n_machines * self.kv.n_lanes <= width:
+            return None
+        return width
+
     # -- fused wave execution ------------------------------------------------
 
     def _run_receiver(self, requests) -> Dict[int, Dict[str, np.ndarray]]:
         """requests: [(machine, [Msg,...]), ...] — one fused call.
+
+        The wave takes the compact wire when :meth:`_wave_width` gives a
+        width, else the dense one (:class:`PlaneStack` describes both).
+        Dense, the whole ``(12, M, K)`` staging buffer goes up and the
+        whole new stack, replies and mask come down.  Compact, only the
+        staged entries (and host-written lanes, as patches) go up, and
+        after the step one small gather (:func:`_touched_lanes`) brings
+        down the outputs at those entries alone.
 
         With a clock attached the call is six sibling spans: ``stage``,
         ``upload``, ``launch``, ``wait`` (for the device, so the copies
@@ -720,10 +881,9 @@ class ClusterEngine:
         if clock is not None:
             clock.begin("engine.stage")
         # every bridge sharing the stack scatters its checked-out views
-        # first: the fused call replaces the *whole* stack
+        # first: the fused call steps the *whole* stack
         for br in self._bridges.values():
             br.flush()
-        msg_host = self._msg_buffers()
         fields = vector.MsgBatch._fields
         lps = self.kv.n_lanes // self.shards    # lanes per shard block
         shard_lanes_stat = self.stats["receiver_shard_lanes"]
@@ -748,30 +908,23 @@ class ClusterEngine:
                 shard_lanes_stat[msg.key // lps] += 1
         # one vectorized scatter for the whole wave (per-item fancy writes
         # were the staging hotspot)
-        msg_host[:, s_mi, s_key] = np.array(cols, I32).T
-        if clock is not None:
-            clock.switch("engine.upload")
-        kv_dev, msg_dev = self.kv.upload_with(msg_host)
-        if clock is not None:
-            clock.switch("engine.launch")
-        outs = _fused_receiver_step(
-            kv_dev, msg_dev,
+        staged = np.array(cols, I32).T
+        width = self._wave_width()
+        step = functools.partial(
+            _fused_receiver_step,
             use_kernel=self.use_kernel, block_rows=self.block_rows,
-            shard_lanes=lps if self.shards > 1 else None,
-            out_sharding=self.kv.device_sharding())
-        if clock is not None:
-            clock.switch("engine.wait")
-        kv_np, rep_np, mask_np = _download(outs, clock)
-        if clock is not None:
-            clock.switch("engine.unstage")
-        self.kv.absorb(outs[0], kv_np)
+            shard_lanes=lps if self.shards > 1 else None)
+        if width is None:
+            rep_np, mask_e = self._receiver_dense(step, staged, s_mi, s_key)
+        else:
+            rep_np, mask_e = self._receiver_compact(step, width, staged,
+                                                    s_mi, s_key)
         for br in self._bridges.values():
             br.drop_views()              # stale against the new stack
-        self.stats["staging_h2d_bytes"] += msg_host.nbytes
-        self.stats["staging_d2h_bytes"] += rep_np.nbytes + mask_np.nbytes
         results: Dict[int, Dict[str, np.ndarray]] = {}
         self.stats["fused_receiver_calls"] += 1
         reg_stat = self.stats["shard_registrations"]
+        j = 0
         for mach, batch in requests:
             mi = mach._mi
             committed = mach.registry.committed
@@ -782,7 +935,7 @@ class ClusterEngine:
                 # machine-global registry that every shard's gather reads
                 # next wave, with the owning shard journaled in the
                 # bridge's per-shard mirror.
-                if mask_np[mi, msg.key]:
+                if mask_e[j]:
                     gs = msg.rmw_id.gsess
                     cnt = msg.rmw_id.counter
                     if 0 <= gs < len(committed) and cnt > committed[gs]:
@@ -790,14 +943,73 @@ class ClusterEngine:
                     shard = msg.key // lps
                     mach.kvs.note_registration(shard, gs, cnt)
                     reg_stat[shard] += 1
+                j += 1
             self.stats["fused_receiver_lanes"] += len(batch)
             results[id(mach)] = {f: rep_np[i, mi] for i, f
                                  in enumerate(vector.ReplyBatch._fields)}
-        # reset to NOOP for the next wave
-        msg_host[:, s_mi, s_key] = _NOOP_COL[:, None]
         if clock is not None:
             clock.end()
         return results
+
+    def _receiver_dense(self, step, staged, s_mi, s_key
+                        ) -> Tuple[np.ndarray, np.ndarray]:
+        """The dense wire from the ``upload`` span to ``unstage``: returns
+        the ``(11, M, K)`` replies and the mask at each staged entry."""
+        clock = self.clock
+        msg_host = self._msg_buffers()
+        msg_host[:, s_mi, s_key] = staged
+        if clock is not None:
+            clock.switch("engine.upload")
+        kv_dev, msg_dev = self.kv.upload_with(msg_host)
+        if clock is not None:
+            clock.switch("engine.launch")
+        outs = step(kv_dev, msg_dev, out_sharding=self.kv.device_sharding())
+        if clock is not None:
+            clock.switch("engine.wait")
+        kv_np, rep_np, mask_np = _download(outs, clock)
+        if clock is not None:
+            clock.switch("engine.unstage")
+        self.kv.absorb(outs[0], kv_np)
+        self.stats["staging_h2d_bytes"] += msg_host.nbytes
+        self.stats["staging_d2h_bytes"] += rep_np.nbytes + mask_np.nbytes
+        # reset to NOOP for the next wave
+        msg_host[:, s_mi, s_key] = _NOOP_COL[:, None]
+        return rep_np, mask_np[s_mi, s_key]
+
+    def _receiver_compact(self, step, width, staged, s_mi, s_key
+                          ) -> Tuple[np.ndarray, np.ndarray]:
+        """The compact wire, spanned as :meth:`_receiver_dense`: returns
+        the persistent reply plane, fresh at the staged lanes, and the
+        mask at each staged entry."""
+        clock = self.clock
+        n = len(s_mi)
+        assert n <= width, f"{n} staged messages overflow a {width}-wide wave"
+        entries, replies = self._entry_buffers(width)
+        mi = np.asarray(s_mi, np.int64)
+        key = np.asarray(s_key, np.int64)
+        entries[0, :n] = mi * self.kv.n_lanes + key
+        entries[1:, :n] = staged
+        if clock is not None:
+            clock.switch("engine.upload")
+        kv_dev, ent_dev, patch_dev = self.kv.upload_compact(entries, width)
+        if clock is not None:
+            clock.switch("engine.launch")
+        outs = step(kv_dev, ent_dev, patch_dev)
+        packed_dev = _touched_lanes(*outs, ent_dev)
+        if clock is not None:
+            clock.switch("engine.wait")
+        (packed,) = _download([packed_dev], clock)
+        if clock is not None:
+            clock.switch("engine.unstage")
+        self.kv.absorb_lanes(outs[0], mi, key, packed[:N_KV])
+        replies[:, mi, key] = packed[N_KV:N_KV + N_REP, :n]
+        self.stats["compact_receiver_waves"] += 1
+        self.stats["staging_h2d_bytes"] += entries.nbytes
+        self.stats["staging_d2h_bytes"] += packed[N_KV:].nbytes
+        # reset to padding for the next wave
+        entries[0, :n] = self.kv.n_machines * self.kv.n_lanes
+        entries[1:, :n] = _NOOP_COL[:, None]
+        return replies, packed[-1, :n]
 
     def _run_issuer(self, requests) -> Dict[int, Dict[str, np.ndarray]]:
         """requests: [(machine, [(lane, Reply),...]), ...] — one call,

@@ -304,22 +304,38 @@ def _checked_after_every_call(engine, log):
         setattr(engine, name, call)
 
 
-@pytest.mark.parametrize("shards", (1, 4))
-@pytest.mark.parametrize("replicas", (3, 5))
-def test_mirror_coherent_after_every_fused_call(replicas, shards):
-    """All-aboard on, one crash and restart: after every fused call the
-    host mirrors equal the device stacks, and the completions are the
-    scalar cluster's."""
+MIRROR_CASES = [
+    pytest.param(replicas, shards, None, False, id=f"{replicas}-{shards}")
+    for replicas in (3, 5) for shards in (1, 4)] + [
+    # a plane wider than a wave: the compact receiver wire
+    pytest.param(replicas, 1, 1024, use_kernel,
+                 id=f"{replicas}-1-k1024-{'kernel' if use_kernel else 'jnp'}")
+    for replicas in (3, 5) for use_kernel in (False, True)]
+
+
+@pytest.mark.parametrize("replicas,shards,lanes,use_kernel", MIRROR_CASES)
+def test_mirror_coherent_after_every_fused_call(replicas, shards, lanes,
+                                                use_kernel):
+    """All-aboard on, ABD reads and writes and RMWs, one crash and
+    restart: after every fused call the host mirrors equal the device
+    stacks, and the completions are the scalar cluster's.  With ``lanes``
+    per machine (keys at the top of the plane) every receiver wave takes
+    the compact wire."""
     from repro.core.node import Machine
 
     cfg = ProtocolConfig(n_machines=replicas, sessions_per_machine=2,
                          all_aboard=True)
-    mcls = functools.partial(BatchedMachine, shards=shards)
+    kw = dict(use_kernel=True, block_rows=8) if use_kernel else {}
+    mcls = functools.partial(BatchedMachine, shards=shards, **kw)
     pair = [Cluster(cfg, NetConfig(seed=13), machine_cls=cls)
             for cls in (Machine, mcls)]
+    key_base = 0
+    if lanes:
+        pair[1].machines[0].kvs.ensure(lanes - 1)
+        key_base = lanes - 24
     for cl in pair:
         workload(cl, n_ops=40, keys=4, seed=13, rmw_frac=0.6,
-                 write_frac=0.2)
+                 write_frac=0.2, key_base=key_base)
     log = []
     _checked_after_every_call(pair[1].engine, log)
     victim = replicas - 1
@@ -333,6 +349,8 @@ def test_mirror_coherent_after_every_fused_call(replicas, shards):
     tel = pair[1].engine.telemetry()
     assert len(log) == tel["plane_wave_refreshes"] > 0
     assert tel["plane_wave_ships"] > 0 and tel["row_reloads"] > 0
+    assert tel["compact_receiver_waves"] == (
+        tel["fused_receiver_calls"] if lanes else 0)
 
 
 def test_one_transfer_each_way_per_fused_call(monkeypatch):
@@ -429,3 +447,130 @@ def test_bytes_split_into_staging_and_stacks():
                                         + eng.kv.host.nbytes)
     for key in ("staging_h2d_bytes", "staging_d2h_bytes", "stack_d2h_bytes"):
         assert after[key] == before[key]
+
+
+# ---------------------------------------------------------------------------
+# the compact receiver wire: bytes per wave independent of the plane
+# ---------------------------------------------------------------------------
+
+def _served(lanes, seed=19):
+    """Three replicas, All-aboard, a mixed load on keys 100-105, the KV
+    plane sized to ``lanes`` per machine before the first op."""
+    cfg = ProtocolConfig(n_machines=3, sessions_per_machine=2,
+                         all_aboard=True)
+    cl = Cluster(cfg, NetConfig(seed=seed), machine_cls=BatchedMachine)
+    cl.machines[0].kvs.ensure(lanes - 1)
+    workload(cl, n_ops=48, keys=6, seed=seed, rmw_frac=0.4, write_frac=0.3,
+             key_base=100)
+    return cl
+
+
+def test_receiver_wave_bytes_do_not_grow_with_lanes():
+    """The same traffic on planes of 1,024 and 16,384 lanes per machine:
+    once the stack is resident, every receiver wave takes the compact
+    wire and moves the same staging and KV-stack bytes on both."""
+    per_wave = []
+    runs = []
+    for lanes in (1024, 16384):
+        cl = _served(lanes)
+        cl.step(4)                     # the first wave made the stack resident
+        eng = cl.engine
+        before = dict(eng.telemetry(), kv_h2d=eng.kv.h2d_bytes,
+                      kv_d2h=eng.kv.d2h_bytes, kv_ships=eng.kv.wave_ships)
+        assert cl.run_until_quiet(max_ticks=50_000)
+        after = dict(eng.telemetry(), kv_h2d=eng.kv.h2d_bytes,
+                     kv_d2h=eng.kv.d2h_bytes, kv_ships=eng.kv.wave_ships)
+        delta = {k: after[k] - before[k] for k in (
+            "fused_receiver_calls", "compact_receiver_waves",
+            "staging_h2d_bytes", "staging_d2h_bytes", "kv_h2d", "kv_d2h",
+            "kv_ships", "patched_lanes")}
+        calls = delta["fused_receiver_calls"]
+        assert calls > 10 and delta["compact_receiver_waves"] == calls
+        assert delta["kv_ships"] == 0        # no whole stack rode a wave
+        _bytes_split_holds(after)
+        per_wave.append({k: v / calls for k, v in delta.items()})
+        runs.append(completion_tuples(cl))
+    assert runs[0] == runs[1]
+    assert per_wave[0] == per_wave[1]
+    # down, a wave's KV bytes are the 18 columns of its 3 x 128 entries
+    assert per_wave[0]["kv_d2h"] == 18 * 3 * 128 * 4
+
+
+def test_host_writes_ride_as_lane_patches():
+    """On a compact wire, a bridge checkout and flush reaches the device
+    as a lane patch, not as the whole stack; a row reload, or more patch
+    lanes than one patch array holds, still ships the whole stack."""
+    cl = _served(1024)
+    assert cl.run_until_quiet(max_ticks=50_000)
+    eng = cl.engine
+    bridge = cl.machines[1].kvs
+    width = eng._wave_width()
+    assert width == 3 * 128
+
+    def wave():
+        before = eng.telemetry()
+        eng._run_receiver([])                # a wave with nothing staged
+        after = eng.telemetry()
+        assert after["compact_receiver_waves"] == (
+            before["compact_receiver_waves"] + 1)
+        np.testing.assert_array_equal(np.asarray(eng.kv.dev), eng.kv.host)
+        return {k: after[k] - before[k]
+                for k in ("plane_wave_ships", "patched_lanes")}
+
+    value = eng.kv.fields.index("value")
+    bridge[700].value = 4242                 # a checkout, written
+    _ = bridge[701]                          # a checkout, read only
+    assert wave() == {"plane_wave_ships": 0, "patched_lanes": 2}
+    assert np.asarray(eng.kv.dev)[value, 1, 700] == 4242
+
+    for key in range(width + 1):             # one lane too many to patch
+        bridge[key]
+    assert wave() == {"plane_wave_ships": 1, "patched_lanes": 0}
+
+    eng.kv.load_row(2, eng.kv, 1)            # a row reload: whole rows
+    assert wave() == {"plane_wave_ships": 1, "patched_lanes": 0}
+    np.testing.assert_array_equal(np.asarray(eng.kv.dev)[:, 2],
+                                  np.asarray(eng.kv.dev)[:, 1])
+
+
+@pytest.mark.parametrize("use_kernel", (False, True))
+def test_compact_step_equals_dense_step(use_kernel):
+    """On random state, the compact operands (staged entries, lane patches,
+    some on the same lanes) step to exactly the outputs of the dense
+    operands they stand for, and the gather reads those outputs at the
+    entries."""
+    from repro.serve.paxos import cluster_engine as ce
+
+    m, k = 3, 512
+    n, w, ne = m * k, m * 128, 300
+    rng = np.random.default_rng(5)
+    kv = rng.integers(0, 4, (ce.N_KV, m, k)).astype(np.int32)
+    idx = rng.choice(n, ne, replace=False)
+    vals = rng.integers(0, 4, (ce.N_MSGREG, ne)).astype(np.int32)
+    vals[ce._MSG_IDX["kind"]] = rng.integers(0, 9, ne)
+    pidx = np.concatenate([idx[:20], rng.choice(
+        np.setdiff1d(np.arange(n), idx), 20, replace=False)])
+    pvals = rng.integers(0, 4, (ce.N_KV, 40)).astype(np.int32)
+    kv_dense = kv.copy()
+    kv_dense.reshape(ce.N_KV, n)[:, pidx] = pvals
+    msg = np.empty((ce.N_MSGREG, m, k), np.int32)
+    msg[:] = ce._NOOP_COL[:, None, None]
+    msg.reshape(ce.N_MSGREG, n)[:, idx] = vals
+    entries = np.empty((1 + ce.N_MSGREG, w), np.int32)
+    entries[0] = n
+    entries[1:] = ce._NOOP_COL[:, None]
+    entries[0, :ne], entries[1:, :ne] = idx, vals
+    patches = np.zeros((1 + ce.N_KV, w), np.int32)
+    patches[0] = n
+    patches[0, :40], patches[1:, :40] = pidx, pvals
+    step = functools.partial(ce._fused_receiver_step, use_kernel=use_kernel,
+                             block_rows=8)
+    want = [np.asarray(a) for a in step(kv_dense, msg)]
+    got = step(kv, entries, patches)
+    packed = np.asarray(ce._touched_lanes(*got, entries))
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(b), a)
+    rows, lanes = idx // k, idx % k
+    np.testing.assert_array_equal(packed[:, :ne], np.concatenate([
+        want[0][:, rows, lanes], want[1][:, rows, lanes],
+        want[2][rows, lanes][None]]))

@@ -110,6 +110,28 @@ def test_fused_receiver_step_compiles_and_fits_one_chip(one_chip):
     assert total < CHIP_HBM, total
 
 
+def test_compact_receiver_wave_compiles_and_fits_one_chip(one_chip):
+    """The compact wire at deployment size: the step takes a 640-entry
+    staged batch and a patch array, expands them on the chip and keeps the
+    kernel; the gather of the touched lanes compiles apart."""
+    m, k, w = N_MACHINES, N_KEYS, N_MACHINES * 128
+    kv = _i32((cluster_engine.N_KV, m, k), one_chip)
+    entries = _i32((1 + cluster_engine.N_MSGREG, w), one_chip)
+    compiled = cluster_engine._fused_receiver_step.lower(
+        kv, entries, _i32((1 + cluster_engine.N_KV, w), one_chip),
+        use_kernel=True, block_rows=32, interpret=False).compile()
+    assert _has_kernel(compiled)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < CHIP_HBM
+    gather = cluster_engine._touched_lanes.lower(
+        kv, _i32((cluster_engine.N_REP, m, k), one_chip),
+        jax.ShapeDtypeStruct((m, k), jnp.bool_, sharding=one_chip),
+        entries).compile()
+    assert gather.out_info.shape == (
+        cluster_engine.N_KV + cluster_engine.N_REP + 1, w)
+
+
 def test_sharded_fused_receiver_step_compiles_on_four_chips(topo):
     """The KV plane split over a 2x2 mesh: each chip steps its own lane
     block with no collective, holding a quarter of the state."""
