@@ -6,10 +6,16 @@ machine can tap BOTH halves of what it processed:
 
 * the **receiver** message stream (``Machine.msg_trace``, enabled by
   ``Cluster.enable_msg_trace``), replayed here through the scalar handlers
-  (:func:`repro.core.handlers.apply_msg`) AND the SIMD engine
-  (:func:`repro.kernels.paxos_apply.ops.replica_step`, Pallas kernel by
-  default or the pure-jnp oracle), asserting reply- and
-  plane-for-plane state equality after every conflict-free batch;
+  (:func:`repro.core.handlers.apply_msg`) AND the SIMD engine, asserting
+  reply- and plane-for-plane state equality after every conflict-free
+  batch.  Two drivers share the staging and the checks:
+  :func:`replay_cluster_fused` runs every machine's batches through
+  :func:`repro.kernels.paxos_apply.ops.stacked_replica_step`, the step the
+  serve engine runs, over one to many shard blocks; :func:`replay_cluster`
+  runs one machine at a time through
+  :func:`repro.kernels.paxos_apply.ops.replica_step`, whose registry
+  gather and scatter run on the device.  Either takes the Pallas kernel
+  (the default) or the pure-jnp oracle;
 * the **issuer** event stream (``Machine.issuer_trace``, enabled by
   ``Cluster.enable_issuer_trace``): round starts, steered replies,
   decisions and pauses (see :mod:`repro.core.proposer`), replayed through
@@ -19,7 +25,8 @@ machine can tap BOTH halves of what it processed:
   emission payloads and every :class:`ProposerTable` plane.
 
 Any schedule the simulator can produce is thereby a correctness test of
-both engines.
+both engines.  The ``run_and_replay*`` harnesses share one seeded faulty
+schedule.
 
 **Receiver bucketing contract** (see ``core/vector.py``): per batch, at
 most one message per key (lane ``i`` == key ``i``); per-key message order
@@ -39,8 +46,7 @@ the receiver replay).
 from __future__ import annotations
 
 import dataclasses
-import functools
-from collections import deque
+from collections import Counter, deque
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -76,8 +82,7 @@ from repro.kernels.paxos_apply import ops
 __all__ = [
     "ReplayMismatch", "bucket_conflict_free", "kv_to_lanes", "msg_to_lanes",
     "reply_to_lanes", "replay_trace", "replay_cluster",
-    "replay_cluster_fused", "replay_sharded", "run_and_replay",
-    "run_and_replay_fused", "run_and_replay_sharded",
+    "replay_cluster_fused", "run_and_replay", "run_and_replay_fused",
     "replay_issuer_trace", "replay_issuer_cluster", "run_and_replay_issuer",
 ]
 
@@ -86,25 +91,65 @@ class ReplayMismatch(AssertionError):
     """The SIMD engine diverged from the scalar handlers on a trace."""
 
 
-def batch_to_msgbatch(batch: Sequence[Msg], n_keys: int) -> vector.MsgBatch:
-    """Conflict-free batch -> struct-of-arrays MsgBatch (NOOP elsewhere)."""
-    planes = {f: [0] * n_keys for f in vector.MsgBatch._fields}
-    planes["has_value"] = [1] * n_keys          # matches MsgBatch.noop
-    for msg in batch:
-        lane = msg_to_lanes(msg)
-        for f, v in lane.items():
-            planes[f][msg.key] = v
-    return vector.MsgBatch(*[jnp.asarray(planes[f], jnp.int32)
-                             for f in vector.MsgBatch._fields])
+# ---------------------------------------------------------------------------
+# shared pieces of the replays
+# ---------------------------------------------------------------------------
+
+_MSG_FIELDS = vector.MsgBatch._fields
+_REP_FIELDS = vector.ReplyBatch._fields
+_KV_FIELDS = vector.KVTable._fields
+_N_MSG = len(_MSG_FIELDS)
 
 
-# ---------------------------------------------------------------------------
-# reply comparison (fields meaningful per opcode, mirroring the wire format;
-# opcode groups shared with repro.serve.paxos.bridge.reply_from_lanes)
-# ---------------------------------------------------------------------------
+def _traces(cluster: Cluster, machines: Optional[Sequence[int]],
+            tap: str = "msg_trace") -> List[tuple]:
+    """``(mid, trace)`` of every (or each selected) machine's ``tap``
+    (``msg_trace`` or ``issuer_trace``)."""
+    mids = machines if machines is not None else range(len(cluster.machines))
+    traces = []
+    for mid in mids:
+        trace = getattr(cluster.machines[mid], tap)
+        if trace is None:
+            raise ValueError(
+                f"machine {mid} has no {tap} — call "
+                f"cluster.enable_{tap}() before running the workload")
+        traces.append((mid, trace))
+    return traces
+
+
+def _bucketed(trace: Sequence[Msg], n_keys: int) -> List[List[Msg]]:
+    """A trace's conflict-free batches; every key must have a lane."""
+    for msg in trace:
+        if msg.key >= n_keys:
+            raise ValueError(f"trace touches key {msg.key} >= n_keys "
+                             f"{n_keys}")
+    return bucket_conflict_free(trace)
+
+
+def _stage(rows: Sequence[Sequence[Msg]], n_lanes: int,
+           registries: Sequence[List[int]]) -> np.ndarray:
+    """The packed ``(12, M, K)`` operand of one wave: row ``r`` holds the
+    conflict-free batch ``rows[r]`` at its keys and NOOP lanes elsewhere.
+    The 12th plane is ``is_registered``, gathered against row ``r``'s
+    committed counters (the host mirror of ``ops.gather_is_registered``:
+    clip, then compare)."""
+    out = np.empty((ops.N_MSGREG, len(rows), n_lanes), np.int32)
+    out[:] = ops.NOOP_COLUMN[:, None, None]
+    for r, batch in enumerate(rows):
+        reg = registries[r]
+        for msg in batch:
+            lane = msg_to_lanes(msg)
+            rid = msg.rmw_id
+            out[:, r, msg.key] = [lane[f] for f in _MSG_FIELDS] + [
+                rid.gsess >= 0
+                and reg[min(rid.gsess, len(reg) - 1)] >= rid.counter]
+    return out
+
 
 def _expected_reply_lanes(rep) -> Dict[str, int]:
-    """The ReplyBatch lanes a scalar Reply pins down (others are free)."""
+    """The ReplyBatch lanes a scalar Reply pins down (others are free);
+    fields meaningful per opcode, mirroring the wire format (opcode groups
+    shared with repro.serve.paxos.bridge.reply_from_lanes)."""
     want = {"kind": int(rep.kind), "opcode": int(rep.opcode)}
     if rep.opcode in _TS_OPS:
         want["ts_v"], want["ts_m"] = rep.ts.version, rep.ts.mid
@@ -122,8 +167,66 @@ def _expected_reply_lanes(rep) -> Dict[str, int]:
     return want
 
 
+def _check_reply(rep: Reply, col: np.ndarray, where: str) -> None:
+    """The scalar reply against ``col``, the engine's ``(11,)`` reply
+    column at the message's lane."""
+    want = _expected_reply_lanes(rep)
+    got = {f: int(col[_REP_FIELDS.index(f)]) for f in want}
+    if got != want:
+        raise ReplayMismatch(f"reply diverged at {where}:\n scalar: {want}\n"
+                             f" vector: {got}")
+
+
+def _check_kv(kvs: Dict[int, KVPair], planes: np.ndarray, keys,
+              where: str) -> None:
+    """Every lane in ``keys`` of one row's ``(18, K)`` KV planes against
+    the scalar store, plane for plane."""
+    for key in keys:
+        want = kv_to_lanes(kvs.get(key) or KVPair(key=key))
+        got = {f: int(planes[i, key]) for i, f in enumerate(_KV_FIELDS)}
+        if got != want:
+            diff = {f: (want[f], got[f]) for f in want if want[f] != got[f]}
+            raise ReplayMismatch(
+                f"final KV state diverged at {where}, key {key} "
+                f"(field: (scalar, vector)): {diff}")
+
+
+def _run_and_replay(replay, seed: int, trace, *, n_ops: int = 24,
+                    keys: int = 3, cfg: Optional[ProtocolConfig] = None,
+                    net: Optional[NetConfig] = None, rmw_frac: float = 0.45,
+                    write_frac: float = 0.3, all_aboard: bool = False
+                    ) -> Dict[str, int]:
+    """The harnesses' one schedule: a seeded faulty sim run with the
+    ``trace`` tap on (``Cluster.enable_msg_trace`` or
+    ``enable_issuer_trace``), run until quiet, then ``replay(cluster,
+    keys)``'s stats with the history length.
+
+    Defaults exercise the full vocabulary (mixed RMW/write/read) under an
+    adversarial network (drops, dups, heavy tails).  ``all_aboard=True``
+    deploys the §9 fast path, putting the all-aboard epoch-conflict lane
+    into the replayed schedules.
+    """
+    if cfg is None:
+        cfg = ProtocolConfig(n_machines=5, sessions_per_machine=2,
+                             all_aboard=all_aboard)
+    elif all_aboard and not cfg.all_aboard:
+        # don't silently drop the §9 deployment request on an explicit cfg
+        cfg = dataclasses.replace(cfg, all_aboard=True)
+    net = net or NetConfig(seed=seed, drop_prob=0.06, dup_prob=0.05,
+                           heavy_tail_prob=0.03, heavy_tail_extra=25.0)
+    cluster = Cluster(cfg, net)
+    trace(cluster)
+    workload(cluster, n_ops=n_ops, keys=keys, seed=seed,
+             rmw_frac=rmw_frac, write_frac=write_frac, op=RmwOp.FAA)
+    if not cluster.run_until_quiet(max_ticks=120_000):
+        raise RuntimeError(f"sim (seed {seed}) did not quiesce")
+    stats = replay(cluster, keys)
+    stats["history"] = len(cluster.history)
+    return stats
+
+
 # ---------------------------------------------------------------------------
-# the differential replay itself
+# one machine at a time, registry on the device (ops.replica_step)
 # ---------------------------------------------------------------------------
 
 def replay_trace(trace: Sequence[Msg], *, n_keys: int, num_gsess: int,
@@ -139,44 +242,25 @@ def replay_trace(trace: Sequence[Msg], *, n_keys: int, num_gsess: int,
     table = vector.KVTable.fresh(n_keys)
     registered = jnp.zeros((num_gsess,), jnp.int32)
 
-    batches = bucket_conflict_free(trace)
-    kind_counts: Dict[str, int] = {}
+    batches = _bucketed(trace, n_keys)
+    kinds: Counter = Counter()
     for step, batch in enumerate(batches):
-        scalar_reps = []
-        for msg in batch:
-            if msg.key >= n_keys:
-                raise ValueError(f"trace touches key {msg.key} >= n_keys "
-                                 f"{n_keys}")
-            rep = handlers.apply_msg(get_kv(kvs, msg.key), msg, registry)
-            scalar_reps.append(rep)
-            k = msg.kind.name.lower()
-            kind_counts[k] = kind_counts.get(k, 0) + 1
-        msgb = batch_to_msgbatch(batch, n_keys)
+        # replica_step gathers is_registered on the device: the staged
+        # 12th plane is dropped
+        staged = _stage([batch], n_keys, [registry.committed])
+        msgb = vector.MsgBatch(*map(jnp.asarray, staged[:_N_MSG, 0]))
         table, replies, registered = ops.replica_step(
             table, msgb, registered, block_rows=block_rows,
             use_kernel=use_kernel)
-        rep_np = {f: np.asarray(p) for f, p in
-                  zip(vector.ReplyBatch._fields, replies)}
-        for msg, rep in zip(batch, scalar_reps):
-            want = _expected_reply_lanes(rep)
-            got = {f: int(rep_np[f][msg.key]) for f in want}
-            if got != want:
-                raise ReplayMismatch(
-                    f"reply diverged at batch {step}, key {msg.key}, "
-                    f"msg {msg}:\n scalar: {want}\n vector: {got}")
+        rep_np = np.stack([np.asarray(p) for p in replies])
+        for msg in batch:
+            rep = handlers.apply_msg(get_kv(kvs, msg.key), msg, registry)
+            kinds[msg.kind.name.lower()] += 1
+            _check_reply(rep, rep_np[:, msg.key],
+                         f"batch {step}, key {msg.key}, msg {msg}")
 
-    # final state: every lane, plane for plane
-    table_np = {f: np.asarray(p) for f, p in
-                zip(vector.KVTable._fields, table)}
-    for key in range(n_keys):
-        scalar_kv = kvs.get(key) or KVPair(key=key)
-        want = kv_to_lanes(scalar_kv)
-        got = {f: int(table_np[f][key]) for f in vector.KVTable._fields}
-        if got != want:
-            diff = {f: (want[f], got[f]) for f in want if want[f] != got[f]}
-            raise ReplayMismatch(
-                f"final KV state diverged at key {key} "
-                f"(field: (scalar, vector)): {diff}")
+    _check_kv(kvs, np.stack([np.asarray(p) for p in table]), range(n_keys),
+              "the end of the trace")
     got_reg = [int(x) for x in np.asarray(registered)]
     if got_reg != registry.committed:
         raise ReplayMismatch(
@@ -184,7 +268,7 @@ def replay_trace(trace: Sequence[Msg], *, n_keys: int, num_gsess: int,
             f" vector: {got_reg}")
 
     stats = {"messages": len(trace), "batches": len(batches)}
-    stats.update(kind_counts)
+    stats.update(kinds)
     return stats
 
 
@@ -194,57 +278,26 @@ def replay_cluster(cluster: Cluster, *, n_keys: int,
                    machines: Optional[Sequence[int]] = None
                    ) -> Dict[str, int]:
     """Replay every (or selected) machine's trace; aggregate the stats."""
-    total: Dict[str, int] = {"machines": 0}
-    mids = machines if machines is not None else range(len(cluster.machines))
-    for mid in mids:
-        trace = cluster.machines[mid].msg_trace
-        if trace is None:
-            raise ValueError(
-                f"machine {mid} has no msg_trace — call "
-                f"cluster.enable_msg_trace() before running the workload")
-        stats = replay_trace(trace, n_keys=n_keys,
-                             num_gsess=cluster.cfg.num_gsess,
-                             use_kernel=use_kernel,
-                             block_rows=block_rows)
+    total: Counter = Counter()
+    for _, trace in _traces(cluster, machines):
         total["machines"] += 1
-        for k, v in stats.items():
-            total[k] = total.get(k, 0) + v
-    return total
+        total.update(replay_trace(trace, n_keys=n_keys,
+                                  num_gsess=cluster.cfg.num_gsess,
+                                  use_kernel=use_kernel,
+                                  block_rows=block_rows))
+    return dict(total)
 
 
-def run_and_replay(seed: int, *, n_ops: int = 24, keys: int = 3,
-                   cfg: Optional[ProtocolConfig] = None,
-                   net: Optional[NetConfig] = None,
-                   rmw_frac: float = 0.45, write_frac: float = 0.3,
-                   all_aboard: bool = False,
-                   use_kernel: bool = True,
-                   block_rows: int = 1) -> Dict[str, int]:
-    """End-to-end harness: seeded faulty sim run -> differential replay.
-
-    Defaults exercise the full vocabulary (mixed RMW/write/read) under an
-    adversarial network (drops, dups, heavy tails) and replay **every**
-    machine's trace through the Pallas kernel.
-    ``all_aboard=True`` deploys the §9 fast path, putting the all-aboard
-    epoch-conflict lane into the replayed schedules.
-    """
-    if cfg is None:
-        cfg = ProtocolConfig(n_machines=5, sessions_per_machine=2,
-                             all_aboard=all_aboard)
-    elif all_aboard and not cfg.all_aboard:
-        # don't silently drop the §9 deployment request on an explicit cfg
-        cfg = dataclasses.replace(cfg, all_aboard=True)
-    net = net or NetConfig(seed=seed, drop_prob=0.06, dup_prob=0.05,
-                           heavy_tail_prob=0.03, heavy_tail_extra=25.0)
-    cluster = Cluster(cfg, net)
-    cluster.enable_msg_trace()
-    workload(cluster, n_ops=n_ops, keys=keys, seed=seed,
-             rmw_frac=rmw_frac, write_frac=write_frac, op=RmwOp.FAA)
-    if not cluster.run_until_quiet(max_ticks=120_000):
-        raise RuntimeError(f"sim (seed {seed}) did not quiesce")
-    stats = replay_cluster(cluster, n_keys=keys, use_kernel=use_kernel,
-                           block_rows=block_rows)
-    stats["history"] = len(cluster.history)
-    return stats
+def run_and_replay(seed: int, *, use_kernel: bool = True,
+                   block_rows: int = 1, **sim) -> Dict[str, int]:
+    """End-to-end harness: seeded faulty sim run (``sim``: the schedule's
+    keywords, see :func:`_run_and_replay`) -> differential replay of
+    **every** machine's trace, through the Pallas kernel by default."""
+    return _run_and_replay(
+        lambda cluster, keys: replay_cluster(
+            cluster, n_keys=keys, use_kernel=use_kernel,
+            block_rows=block_rows),
+        seed, Cluster.enable_msg_trace, **sim)
 
 
 # ===========================================================================
@@ -253,260 +306,61 @@ def run_and_replay(seed: int, *, n_ops: int = 24, keys: int = 3,
 #
 # The device-resident ClusterEngine (repro.serve.paxos.cluster_engine)
 # stacks all N replicas' KV planes on a leading machine axis and runs ONE
-# fused receiver call per wave by flattening ``(M, K) -> (M*K,)`` lanes.
-# This replay drives the SAME flattening convention straight from recorded
-# message traces — machine ``i``'s batch ``w`` staged into row ``i`` of
-# wave ``w`` — and asserts, against N independent scalar-handler shadows,
-# that rows stay isolated: every reply, every KV plane of every row, and
-# every per-machine registry mirror are bit-identical after every fused
-# wave.  The registry gather stays host-side exactly as the engine does it
-# (the one cross-lane piece of the step): ``is_registered`` is computed
-# per staged lane against the machine's own mirror before the wave, and
-# commit-lane registrations max-merge back after it (out-of-range gsess
-# dropped, mirroring ops.scatter_register's dead-slot drop).
+# fused receiver call per wave: ops.stacked_replica_step, the step the
+# engine serves, on the packed (12, M, K) operand.  This replay stages that
+# operand straight from recorded message traces — machine ``i``'s batch
+# ``w`` in row ``i`` of wave ``w`` — runs the same step under its own jit,
+# and asserts, against N independent scalar-handler shadows, that rows stay
+# isolated: every reply, every KV plane of every row, and every
+# per-machine registry mirror are bit-identical after every wave.  The
+# registry gather stays host-side exactly as the engine does it (the one
+# cross-lane piece of the step): ``is_registered`` is computed per staged
+# lane against the machine's own mirror before the wave, and commit-lane
+# registrations max-merge back after it (out-of-range gsess dropped,
+# mirroring ops.scatter_register's dead-slot drop).  Only the engine's wire
+# (dense or compact, tests/test_cluster_engine.py) is not replayed here.
 #
 # Wave alignment across machines is arbitrary (machines with shorter
-# traces simply stop contributing rows) — apply_batch is elementwise, so
-# this checks precisely the row-isolation property the fused engine's
+# traces simply stop contributing rows) — the step is elementwise, so this
+# checks precisely the row-isolation property the fused engine's
 # correctness argument rests on, with no serve-layer code imported.
 
-_FUSED_NOOP = {f: 0 for f in vector.MsgBatch._fields}
-_FUSED_NOOP["has_value"] = 1                    # matches MsgBatch.noop
+_fused_step = jax.jit(ops.stacked_replica_step, static_argnames=(
+    "use_kernel", "block_rows", "shard_lanes", "interpret"))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("use_kernel", "block_rows",
-                                    "shard_lanes"))
-def _fused_wave_step(kv_stack, msg_stack, is_reg, *, use_kernel,
-                     block_rows, shard_lanes=None):
-    """One fused receiver wave: (18,M,K),(11,M,K),(M,K) ->
-    (18,M,K),(11,M,K),(M,K) — the ClusterEngine flattening convention
-    (machine axis folded into the lane axis, kernel path padded to the
-    block tile, padded lanes NOOP by construction).  ``shard_lanes``
-    switches the kernel padding to shard-local segments: each
-    ``shard_lanes``-wide lane block pads to its own tile boundary, so a
-    compiled block never spans a shard boundary (the sharded engine's
-    segment convention; ``None`` = one whole-axis segment, the classic
-    layout bit for bit)."""
-    n_kv = len(vector.KVTable._fields)
-    n_msg = len(vector.MsgBatch._fields)
-    m, k = is_reg.shape
-    n = m * k
-    kv = vector.KVTable(*[kv_stack[i].reshape(n) for i in range(n_kv)])
-    msg = vector.MsgBatch(*[msg_stack[i].reshape(n) for i in range(n_msg)])
-    reg = is_reg.reshape(n) != 0
-    if use_kernel:
-        tile = block_rows * ops.LANE
-        seg = shard_lanes if shard_lanes else n
-        seg_pad = ((seg + tile - 1) // tile) * tile
-        kv_p = vector.KVTable(
-            *[ops.pad_segments(a, seg, seg_pad) for a in kv])
-        msg_p = vector.MsgBatch(
-            *[ops.pad_segments(a, seg, seg_pad) for a in msg])
-        new_kv, replies, mask = ops.paxos_apply(
-            kv_p, msg_p,
-            ops.pad_segments(reg.astype(jnp.int32), seg, seg_pad),
-            block_rows=block_rows)
-        new_kv = vector.KVTable(
-            *[ops.unpad_segments(a, seg, seg_pad) for a in new_kv])
-        replies = type(replies)(
-            *[ops.unpad_segments(a, seg, seg_pad) for a in replies])
-        mask = ops.unpad_segments(mask, seg, seg_pad) != 0
-    else:
-        new_kv, replies, mask = vector.apply_batch(kv, msg, reg)
-    return (jnp.stack([a.reshape(m, k) for a in new_kv]),
-            jnp.stack([a.reshape(m, k) for a in replies]),
-            mask.reshape(m, k))
-
-
-def replay_cluster_fused(cluster: Cluster, *, n_keys: int,
+def replay_cluster_fused(cluster: Cluster, *, n_keys: int, shards: int = 1,
                          use_kernel: bool = True,
                          block_rows: int = 1,
                          machines: Optional[Sequence[int]] = None
                          ) -> Dict[str, int]:
-    """Replay every (or selected) machine's trace through fused ticks.
+    """Replay every (or selected) machine's trace through fused waves,
+    checked shard for shard.
 
     Unlike :func:`replay_cluster` (N independent single-machine replays),
     all machines share each fused step: one ``(M*K,)`` engine call per
-    wave, exactly like the serve-path ClusterEngine.  Raises
-    :class:`ReplayMismatch` on the first reply, plane or registry
-    divergence of any row.
-    """
-    mids = list(machines if machines is not None
-                else range(len(cluster.machines)))
-    num_gsess = cluster.cfg.num_gsess
-    batches: List[List[List[Msg]]] = []
-    total_msgs = 0
-    for mid in mids:
-        trace = cluster.machines[mid].msg_trace
-        if trace is None:
-            raise ValueError(
-                f"machine {mid} has no msg_trace — call "
-                f"cluster.enable_msg_trace() before running the workload")
-        for msg in trace:
-            if msg.key >= n_keys:
-                raise ValueError(f"trace touches key {msg.key} >= n_keys "
-                                 f"{n_keys}")
-        total_msgs += len(trace)
-        batches.append(bucket_conflict_free(trace))
-
-    m = len(mids)
-    fields = vector.MsgBatch._fields
-    rep_fields = vector.ReplyBatch._fields
-    # scalar shadows (one per row) + the fused side's host registry mirror
-    kvs: List[Dict[int, KVPair]] = [{} for _ in mids]
-    regs = [Registry(num_gsess) for _ in mids]
-    freg = [[0] * num_gsess for _ in mids]
-    fresh = vector.KVTable.fresh(n_keys)
-    kv_stack = jnp.stack([jnp.broadcast_to(p, (m, n_keys)) for p in fresh])
-
-    n_waves = max((len(b) for b in batches), default=0)
-    kind_counts: Dict[str, int] = {}
-    for wave in range(n_waves):
-        msg_host = np.zeros((len(fields), m, n_keys), np.int32)
-        for i, f in enumerate(fields):
-            if _FUSED_NOOP[f]:
-                msg_host[i] = _FUSED_NOOP[f]
-        reg_host = np.zeros((m, n_keys), np.int32)
-        staged: List[tuple] = []
-        for row in range(m):
-            if wave >= len(batches[row]):
-                continue
-            for msg in batches[row][wave]:
-                lane = msg_to_lanes(msg)
-                for i, f in enumerate(fields):
-                    msg_host[i, row, msg.key] = lane[f]
-                gs, cnt = msg.rmw_id.gsess, msg.rmw_id.counter
-                # host mirror of ops.gather_is_registered (clip + compare)
-                reg_host[row, msg.key] = int(
-                    gs >= 0 and freg[row][min(gs, num_gsess - 1)] >= cnt)
-                staged.append((row, msg))
-        kv_stack, rep_stack, reg_mask = _fused_wave_step(
-            kv_stack, jnp.asarray(msg_host), jnp.asarray(reg_host),
-            use_kernel=use_kernel,
-            block_rows=block_rows)
-        rep_np = np.asarray(rep_stack)
-        mask_np = np.asarray(reg_mask)
-        for row, msg in staged:
-            rep = handlers.apply_msg(get_kv(kvs[row], msg.key), msg,
-                                     regs[row])
-            k = msg.kind.name.lower()
-            kind_counts[k] = kind_counts.get(k, 0) + 1
-            want = _expected_reply_lanes(rep)
-            got = {f: int(rep_np[rep_fields.index(f), row, msg.key])
-                   for f in want}
-            if got != want:
-                raise ReplayMismatch(
-                    f"fused reply diverged at wave {wave}, machine "
-                    f"{mids[row]}, key {msg.key}, msg {msg}:\n"
-                    f" scalar: {want}\n fused:  {got}")
-        # commit-lane registrations scatter back after the wave (max-merge,
-        # out-of-range dropped — ops.scatter_register's dead-slot contract)
-        for row, msg in staged:
-            if mask_np[row, msg.key]:
-                gs, cnt = msg.rmw_id.gsess, msg.rmw_id.counter
-                if 0 <= gs < num_gsess and cnt > freg[row][gs]:
-                    freg[row][gs] = cnt
-        for row in range(m):
-            if freg[row] != regs[row].committed:
-                raise ReplayMismatch(
-                    f"fused registry diverged at wave {wave}, machine "
-                    f"{mids[row]}:\n scalar: {regs[row].committed}\n"
-                    f" fused:  {freg[row]}")
-
-    # final state: every row, every lane, plane for plane
-    kv_np = np.asarray(kv_stack)
-    kv_fields = vector.KVTable._fields
-    for row in range(m):
-        for key in range(n_keys):
-            scalar_kv = kvs[row].get(key) or KVPair(key=key)
-            want = kv_to_lanes(scalar_kv)
-            got = {f: int(kv_np[i, row, key])
-                   for i, f in enumerate(kv_fields)}
-            if got != want:
-                diff = {f: (want[f], got[f])
-                        for f in want if want[f] != got[f]}
-                raise ReplayMismatch(
-                    f"fused final KV state diverged at machine {mids[row]},"
-                    f" key {key} (field: (scalar, fused)): {diff}")
-
-    stats = {"machines": m, "messages": total_msgs, "fused_waves": n_waves}
-    stats.update(kind_counts)
-    return stats
-
-
-def run_and_replay_fused(seed: int, *, n_ops: int = 24, keys: int = 3,
-                         cfg: Optional[ProtocolConfig] = None,
-                         net: Optional[NetConfig] = None,
-                         rmw_frac: float = 0.45, write_frac: float = 0.3,
-                         use_kernel: bool = True,
-                         block_rows: int = 1) -> Dict[str, int]:
-    """End-to-end fused harness: seeded faulty sim -> stacked replay."""
-    cfg = cfg or ProtocolConfig(n_machines=5, sessions_per_machine=2)
-    net = net or NetConfig(seed=seed, drop_prob=0.06, dup_prob=0.05,
-                           heavy_tail_prob=0.03, heavy_tail_extra=25.0)
-    cluster = Cluster(cfg, net)
-    cluster.enable_msg_trace()
-    workload(cluster, n_ops=n_ops, keys=keys, seed=seed,
-             rmw_frac=rmw_frac, write_frac=write_frac, op=RmwOp.FAA)
-    if not cluster.run_until_quiet(max_ticks=120_000):
-        raise RuntimeError(f"sim (seed {seed}) did not quiesce")
-    stats = replay_cluster_fused(cluster, n_keys=keys,
-                                 use_kernel=use_kernel,
-                                 block_rows=block_rows)
-    stats["history"] = len(cluster.history)
-    return stats
-
-
-def replay_sharded(cluster: Cluster, *, n_keys: int, shards: int = 2,
-                   use_kernel: bool = True,
-                   block_rows: int = 1,
-                   machines: Optional[Sequence[int]] = None
-                   ) -> Dict[str, int]:
-    """:func:`replay_cluster_fused` with a sharded lane axis, checked
-    shard for shard.
-
-    The lane axis is aligned up to ``shards`` contiguous blocks (the
+    wave, exactly like the serve-path ClusterEngine.  The lane axis is
+    aligned up to ``shards`` contiguous blocks (the
     :class:`~repro.core.lanes.ShardMap` block partition — lane == key, no
-    permutation) and the fused wave runs with shard-local kernel
-    segments, exactly like the sharded ClusterEngine.  Against the same
-    N scalar-handler shadows this asserts, per wave, every staged reply;
-    per wave, that each machine's registry (gathered pre-wave, commit
-    registrations scattered post-wave) matches the scalar one AND that
-    re-merging the per-shard registration journals — the cross-shard
-    scatter bookkeeping the serve bridge mirrors — reproduces it; and,
-    finally, every KV plane of every shard block of every row.  Raises
-    :class:`ReplayMismatch` naming the shard on the first divergence.
+    permutation); with ``shards > 1`` the wave runs with shard-local
+    kernel segments, as the sharded ClusterEngine does.  Per wave this
+    asserts every staged reply, and that each machine's registry
+    (gathered pre-wave, commit registrations scattered post-wave) matches
+    the scalar one AND that re-merging the per-shard registration journals
+    — the cross-shard scatter bookkeeping the serve bridge mirrors —
+    reproduces it; finally, every KV plane of every shard block of every
+    row.  Raises :class:`ReplayMismatch` on the first divergence.
     """
-    if shards < 1:
-        raise ValueError(f"shards must be >= 1, got {shards}")
-    mids = list(machines if machines is not None
-                else range(len(cluster.machines)))
+    traces = _traces(cluster, machines)
+    mids = [mid for mid, _ in traces]
+    batches = [_bucketed(trace, n_keys) for _, trace in traces]
     num_gsess = cluster.cfg.num_gsess
-    batches: List[List[List[Msg]]] = []
-    total_msgs = 0
-    for mid in mids:
-        trace = cluster.machines[mid].msg_trace
-        if trace is None:
-            raise ValueError(
-                f"machine {mid} has no msg_trace — call "
-                f"cluster.enable_msg_trace() before running the workload")
-        for msg in trace:
-            if msg.key >= n_keys:
-                raise ValueError(f"trace touches key {msg.key} >= n_keys "
-                                 f"{n_keys}")
-        total_msgs += len(trace)
-        batches.append(bucket_conflict_free(trace))
-
     m = len(mids)
     k_al = ShardMap(shards, shards).aligned(n_keys)
     sm = ShardMap(shards, k_al)
-    lps = sm.lanes_per_shard
-    fields = vector.MsgBatch._fields
-    rep_fields = vector.ReplyBatch._fields
-    # scalar shadows (one per row); fused side: the machine-global
-    # registry every shard gathers from, plus one registration journal
-    # per shard row (the bridge's reg_mirror analogue)
+    # scalar shadows (one per row); fused side: the machine-global registry
+    # every shard gathers from, plus one registration journal per shard
+    # (the bridge's reg_mirror analogue)
     kvs: List[Dict[int, KVPair]] = [{} for _ in mids]
     regs = [Registry(num_gsess) for _ in mids]
     freg = [[0] * num_gsess for _ in mids]
@@ -514,126 +368,78 @@ def replay_sharded(cluster: Cluster, *, n_keys: int, shards: int = 2,
     fresh = vector.KVTable.fresh(k_al)
     kv_stack = jnp.stack([jnp.broadcast_to(p, (m, k_al)) for p in fresh])
 
-    n_waves = max((len(b) for b in batches), default=0)
-    shard_lane_counts = [0] * shards
-    kind_counts: Dict[str, int] = {}
+    n_waves = max(map(len, batches), default=0)
+    shard_lanes = [0] * shards
+    kinds: Counter = Counter()
     for wave in range(n_waves):
-        msg_host = np.zeros((len(fields), m, k_al), np.int32)
-        for i, f in enumerate(fields):
-            if _FUSED_NOOP[f]:
-                msg_host[i] = _FUSED_NOOP[f]
-        reg_host = np.zeros((m, k_al), np.int32)
-        staged: List[tuple] = []
-        for row in range(m):
-            if wave >= len(batches[row]):
-                continue
-            for msg in batches[row][wave]:
-                lane = msg_to_lanes(msg)
-                for i, f in enumerate(fields):
-                    msg_host[i, row, msg.key] = lane[f]
-                gs, cnt = msg.rmw_id.gsess, msg.rmw_id.counter
-                reg_host[row, msg.key] = int(
-                    gs >= 0 and freg[row][min(gs, num_gsess - 1)] >= cnt)
-                shard_lane_counts[sm.shard_of(msg.key)] += 1
-                staged.append((row, msg))
-        kv_stack, rep_stack, reg_mask = _fused_wave_step(
-            kv_stack, jnp.asarray(msg_host), jnp.asarray(reg_host),
-            use_kernel=use_kernel,
-            block_rows=block_rows,
-            shard_lanes=lps if shards > 1 else None)
+        rows = [b[wave] if wave < len(b) else [] for b in batches]
+        kv_stack, rep_stack, reg_mask = _fused_step(
+            kv_stack, jnp.asarray(_stage(rows, k_al, freg)),
+            use_kernel=use_kernel, block_rows=block_rows,
+            shard_lanes=sm.lanes_per_shard if shards > 1 else None)
         rep_np = np.asarray(rep_stack)
         mask_np = np.asarray(reg_mask)
-        for row, msg in staged:
-            rep = handlers.apply_msg(get_kv(kvs[row], msg.key), msg,
-                                     regs[row])
-            k = msg.kind.name.lower()
-            kind_counts[k] = kind_counts.get(k, 0) + 1
-            want = _expected_reply_lanes(rep)
-            got = {f: int(rep_np[rep_fields.index(f), row, msg.key])
-                   for f in want}
-            if got != want:
-                raise ReplayMismatch(
-                    f"sharded reply diverged at wave {wave}, machine "
-                    f"{mids[row]}, shard {sm.shard_of(msg.key)}, key "
-                    f"{msg.key}, msg {msg}:\n scalar: {want}\n"
-                    f" fused:  {got}")
+        for row, batch in enumerate(rows):
+            for msg in batch:
+                shard = sm.shard_of(msg.key)
+                shard_lanes[shard] += 1
+                kinds[msg.kind.name.lower()] += 1
+                rep = handlers.apply_msg(get_kv(kvs[row], msg.key), msg,
+                                         regs[row])
+                _check_reply(rep, rep_np[:, row, msg.key],
+                             f"wave {wave}, machine {mids[row]}, shard "
+                             f"{shard}, key {msg.key}, msg {msg}")
         # cross-shard registry scatter: a commit lane's registration
         # max-merges into the machine-global registry AND journals under
         # its owning shard
-        for row, msg in staged:
-            if mask_np[row, msg.key]:
+        for row, batch in enumerate(rows):
+            for msg in batch:
                 gs, cnt = msg.rmw_id.gsess, msg.rmw_id.counter
-                if 0 <= gs < num_gsess and cnt > freg[row][gs]:
-                    freg[row][gs] = cnt
-                if 0 <= gs < num_gsess:
+                if mask_np[row, msg.key] and 0 <= gs < num_gsess:
+                    freg[row][gs] = max(freg[row][gs], cnt)
                     j = journals[row][sm.shard_of(msg.key)]
-                    if cnt > j.get(gs, 0):
-                        j[gs] = cnt
+                    j[gs] = max(j.get(gs, 0), cnt)
         for row in range(m):
             if freg[row] != regs[row].committed:
                 raise ReplayMismatch(
-                    f"sharded registry diverged at wave {wave}, machine "
+                    f"fused registry diverged at wave {wave}, machine "
                     f"{mids[row]}:\n scalar: {regs[row].committed}\n"
                     f" fused:  {freg[row]}")
             merged = [0] * num_gsess
             for j in journals[row]:
                 for gs, cnt in j.items():
-                    if cnt > merged[gs]:
-                        merged[gs] = cnt
+                    merged[gs] = max(merged[gs], cnt)
             if merged != freg[row]:
                 raise ReplayMismatch(
                     f"per-shard registration journals diverged from the "
                     f"global registry at wave {wave}, machine {mids[row]}:"
                     f"\n merged journals: {merged}\n global: {freg[row]}")
 
-    # final state: every row, shard block by shard block, plane for plane
     kv_np = np.asarray(kv_stack)
-    kv_fields = vector.KVTable._fields
     for row in range(m):
         for shard in range(shards):
-            for key in range(*sm.slice_of(shard).indices(k_al)):
-                scalar_kv = kvs[row].get(key) or KVPair(key=key)
-                want = kv_to_lanes(scalar_kv)
-                got = {f: int(kv_np[i, row, key])
-                       for i, f in enumerate(kv_fields)}
-                if got != want:
-                    diff = {f: (want[f], got[f])
-                            for f in want if want[f] != got[f]}
-                    raise ReplayMismatch(
-                        f"sharded final KV state diverged at machine "
-                        f"{mids[row]}, shard {shard}, key {key} "
-                        f"(field: (scalar, fused)): {diff}")
+            _check_kv(kvs[row], kv_np[:, row],
+                      range(*sm.slice_of(shard).indices(k_al)),
+                      f"machine {mids[row]}, shard {shard}")
 
-    stats = {"machines": m, "messages": total_msgs, "fused_waves": n_waves,
-             "shards": shards, "lane_axis": k_al}
-    for s, c in enumerate(shard_lane_counts):
+    stats = {"machines": m, "messages": sum(len(t) for _, t in traces),
+             "fused_waves": n_waves, "shards": shards, "lane_axis": k_al}
+    for s, c in enumerate(shard_lanes):
         stats[f"shard{s}_lanes"] = c
-    stats.update(kind_counts)
+    stats.update(kinds)
     return stats
 
 
-def run_and_replay_sharded(seed: int, *, shards: int = 2, n_ops: int = 24,
-                           keys: int = 3,
-                           cfg: Optional[ProtocolConfig] = None,
-                           net: Optional[NetConfig] = None,
-                           rmw_frac: float = 0.45, write_frac: float = 0.3,
-                           use_kernel: bool = True,
-                           block_rows: int = 1) -> Dict[str, int]:
-    """End-to-end sharded harness: seeded faulty sim -> sharded replay."""
-    cfg = cfg or ProtocolConfig(n_machines=5, sessions_per_machine=2)
-    net = net or NetConfig(seed=seed, drop_prob=0.06, dup_prob=0.05,
-                          heavy_tail_prob=0.03, heavy_tail_extra=25.0)
-    cluster = Cluster(cfg, net)
-    cluster.enable_msg_trace()
-    workload(cluster, n_ops=n_ops, keys=keys, seed=seed,
-             rmw_frac=rmw_frac, write_frac=write_frac, op=RmwOp.FAA)
-    if not cluster.run_until_quiet(max_ticks=120_000):
-        raise RuntimeError(f"sim (seed {seed}) did not quiesce")
-    stats = replay_sharded(cluster, n_keys=keys, shards=shards,
-                           use_kernel=use_kernel,
-                           block_rows=block_rows)
-    stats["history"] = len(cluster.history)
-    return stats
+def run_and_replay_fused(seed: int, *, shards: int = 1,
+                         use_kernel: bool = True, block_rows: int = 1,
+                         **sim) -> Dict[str, int]:
+    """End-to-end fused harness: seeded faulty sim (``sim`` as in
+    :func:`run_and_replay`) -> stacked replay over ``shards`` blocks."""
+    return _run_and_replay(
+        lambda cluster, keys: replay_cluster_fused(
+            cluster, n_keys=keys, shards=shards, use_kernel=use_kernel,
+            block_rows=block_rows),
+        seed, Cluster.enable_msg_trace, **sim)
 
 
 # ===========================================================================
@@ -1011,47 +817,21 @@ def replay_issuer_cluster(cluster: Cluster,
                           machines: Optional[Sequence[int]] = None
                           ) -> Dict[str, int]:
     """Replay every (or selected) machine's issuer trace; aggregate stats."""
-    total: Dict[str, int] = {"machines": 0}
-    mids = machines if machines is not None else range(len(cluster.machines))
-    for mid in mids:
-        events = cluster.machines[mid].issuer_trace
-        if events is None:
-            raise ValueError(
-                f"machine {mid} has no issuer_trace — call "
-                f"cluster.enable_issuer_trace() before running the workload")
-        stats = replay_issuer_trace(events, cfg=cluster.cfg)
+    total: Counter = Counter()
+    for _, events in _traces(cluster, machines, "issuer_trace"):
         total["machines"] += 1
-        for k, v in stats.items():
-            total[k] = total.get(k, 0) + v
-    return total
+        total.update(replay_issuer_trace(events, cfg=cluster.cfg))
+    return dict(total)
 
 
-def run_and_replay_issuer(seed: int, *, n_ops: int = 24, keys: int = 3,
-                          cfg: Optional[ProtocolConfig] = None,
-                          net: Optional[NetConfig] = None,
-                          rmw_frac: float = 0.45, write_frac: float = 0.3,
-                          all_aboard: bool = False) -> Dict[str, int]:
+def run_and_replay_issuer(seed: int, **sim) -> Dict[str, int]:
     """End-to-end proposer harness: seeded faulty sim -> issuer replay.
 
-    The mirror image of :func:`run_and_replay`: same adversarial network
-    and mixed workload, but the differential surface is the *issuer* side —
-    every machine's recorded reply stream is replayed through the scalar
-    shadow and :func:`repro.core.proposer_vector.proposer_step`.
+    The mirror image of :func:`run_and_replay`: the same schedule
+    (``sim``), but the differential surface is the *issuer* side — every
+    machine's recorded reply stream is replayed through the scalar shadow
+    and :func:`repro.core.proposer_vector.proposer_step`.
     """
-    if cfg is None:
-        cfg = ProtocolConfig(n_machines=5, sessions_per_machine=2,
-                             all_aboard=all_aboard)
-    elif all_aboard and not cfg.all_aboard:
-        # don't silently drop the §9 deployment request on an explicit cfg
-        cfg = dataclasses.replace(cfg, all_aboard=True)
-    net = net or NetConfig(seed=seed, drop_prob=0.06, dup_prob=0.05,
-                           heavy_tail_prob=0.03, heavy_tail_extra=25.0)
-    cluster = Cluster(cfg, net)
-    cluster.enable_issuer_trace()
-    workload(cluster, n_ops=n_ops, keys=keys, seed=seed,
-             rmw_frac=rmw_frac, write_frac=write_frac, op=RmwOp.FAA)
-    if not cluster.run_until_quiet(max_ticks=120_000):
-        raise RuntimeError(f"sim (seed {seed}) did not quiesce")
-    stats = replay_issuer_cluster(cluster)
-    stats["history"] = len(cluster.history)
-    return stats
+    return _run_and_replay(
+        lambda cluster, keys: replay_issuer_cluster(cluster),
+        seed, Cluster.enable_issuer_trace, **sim)

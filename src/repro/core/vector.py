@@ -65,10 +65,10 @@ done by the jitted wrapper (see ``repro.kernels.paxos_apply.ops``).
 elementwise (no cross-lane reads or writes), the lane axis composes
 freely: stacking N machines' tables as ``(M, K)`` planes and flattening
 to ``(M*K,)`` lanes runs N replica steps in ONE call, with rows isolated
-by construction.  The device-resident serve engine
-(``repro.serve.paxos.cluster_engine``) and the fused differential replay
-(:func:`repro.core.replay.replay_cluster_fused`) both rely on exactly
-this property; keep new transitions elementwise or they break it.
+by construction.  That stacked step
+(:func:`repro.kernels.paxos_apply.ops.stacked_replica_step`) is what the
+serve engine runs and the fused differential replay checks; keep new
+transitions elementwise or they break it.
 """
 
 from __future__ import annotations
@@ -170,8 +170,12 @@ class MsgBatch(NamedTuple):
 
     @staticmethod
     def noop(n_keys: int) -> "MsgBatch":
-        z = jnp.zeros((n_keys,), I32)
-        return MsgBatch(z, z, z, z, z, z, z, z, z, z, jnp.ones((n_keys,), I32))
+        return MsgBatch(*[jnp.full((n_keys,), v, I32) for v in NOOP_LANE])
+
+
+# A NOOP message lane, field by field: kind 0 and has_value 1 (a NOOP is
+# no §8.6 thin commit).  Every staging buffer fills unstaged lanes from it.
+NOOP_LANE = tuple(int(f == "has_value") for f in MsgBatch._fields)
 
 
 class ReplyBatch(NamedTuple):

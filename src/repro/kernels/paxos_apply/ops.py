@@ -1,8 +1,16 @@
-"""Jitted public wrapper for the paxos_apply kernel.
+"""The receiver step over lanes: the paxos_apply kernel or its jnp oracle.
 
-Handles lane padding, the per-session registered-rmw-id gather/scatter (the
-only non-lane-parallel piece of the receiver step), and exposes a full
-"replica step": table' , replies, registry' = step(table, batch, registry).
+:func:`apply_lanes` is the one flat-lane receiver step: segment padding,
+then :func:`~.kernel.paxos_apply` or :func:`repro.core.vector.apply_batch`
+(``use_kernel``), then unpadding.  Two entries call it:
+
+* :func:`replica_step` — one replica, with the per-session registered-rmw-id
+  gather/scatter (the only non-lane-parallel piece of the receiver step)
+  on the device: table', replies, registry' = step(table, batch, registry);
+* :func:`stacked_replica_step` — every replica of a cluster at once on
+  ``(F, M, K)`` stacks, the registry gathered on the host and packed with
+  the message planes.  The serve engine's fused receiver step and the
+  fused differential replay both trace it.
 
 Padding contract (validated here, *before* trace, and enforced again with a
 ``ValueError`` inside :func:`repro.kernels.paxos_apply.kernel.paxos_apply`):
@@ -10,7 +18,7 @@ Padding contract (validated here, *before* trace, and enforced again with a
 * every ``KVTable`` and ``MsgBatch`` plane is 1-D with one shared lane
   count ``n`` (slot ``i`` targets key ``i`` — conflict-free batches, see
   :mod:`repro.core.vector`);
-* ``replica_step`` pads all planes with zeros up to a multiple of
+* the kernel path pads all planes with zeros up to a multiple of
   ``block_rows * 128``; padded message lanes are ``kind = NOOP`` by
   construction, so they neither mutate state nor emit replies, and are
   sliced off again before returning;
@@ -19,8 +27,8 @@ Padding contract (validated here, *before* trace, and enforced again with a
   block tile — compiled blocks then never straddle a shard boundary, so
   a shard-partitioned plane stack keeps every block device-local.  The
   step stays elementwise either way, so segmented padding is
-  bit-identical to whole-axis padding (pinned by the sharded replay
-  gates);
+  bit-identical to whole-axis padding (pinned by the fused replay's
+  sharded cases);
 * ``registered`` is the 1-D per-global-session committed-counter table;
   commit-lane registrations scatter into it *after* the batch.
 """
@@ -32,13 +40,27 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.vector import KVTable, MsgBatch, apply_batch
-from .kernel import LANE, paxos_apply
+from repro.core.vector import NOOP_LANE, KVTable, MsgBatch, apply_batch
+from .kernel import LANE, N_KV, N_MSG, paxos_apply
+
+# The stacked step's packed message operand: the 11 message planes and the
+# host-gathered is_registered bit as a 12th, so one transfer stages a wave.
+# An unstaged lane holds this column: a NOOP, not registered.
+N_MSGREG = N_MSG + 1
+NOOP_COLUMN = np.array(NOOP_LANE + (0,), np.int32)
 
 
-def _pad(a: jnp.ndarray, n_to: int) -> jnp.ndarray:
-    return jnp.pad(a, (0, n_to - a.shape[0]))
+def segment_layout(n: int, block_rows: int,
+                   shard_lanes: Optional[int]) -> tuple:
+    """``(seg, seg_pad)``: a length-``n`` lane axis is segments of ``seg``
+    lanes (``shard_lanes``, or one whole-axis segment), each padded to
+    ``seg_pad``, the next multiple of the kernel tile.  Shared with
+    ``paxos_propose.ops``."""
+    tile = block_rows * LANE
+    seg = shard_lanes if shard_lanes else n
+    return seg, ((seg + tile - 1) // tile) * tile
 
 
 def pad_segments(a: jnp.ndarray, seg: int, seg_pad: int,
@@ -110,36 +132,38 @@ def validate_batch(kv: KVTable, msg: MsgBatch, registered: jnp.ndarray,
             f"{jnp.shape(registered)}")
 
 
+def apply_lanes(kv: KVTable, msg: MsgBatch, is_reg: jnp.ndarray, *,
+                use_kernel: bool, block_rows: int,
+                shard_lanes: Optional[int], interpret: Optional[bool]):
+    """The receiver step over 1-D lanes: ``(new_kv, replies, reg_mask)``,
+    through the Pallas kernel or the jnp oracle.  The kernel path pads each
+    lane segment to the block tile (padded message lanes are NOOPs, kind
+    0) and drops the padding again; both paths give the same planes."""
+    if use_kernel:
+        seg, seg_pad = segment_layout(kv.state.shape[0], block_rows,
+                                      shard_lanes)
+        pad = functools.partial(pad_segments, seg=seg, seg_pad=seg_pad)
+        unpad = functools.partial(unpad_segments, seg=seg, seg_pad=seg_pad)
+        new_kv, replies, reg_mask = paxos_apply(
+            KVTable(*map(pad, kv)), MsgBatch(*map(pad, msg)),
+            pad(is_reg.astype(jnp.int32)),
+            block_rows=block_rows, interpret=interpret)
+        return (KVTable(*map(unpad, new_kv)),
+                type(replies)(*map(unpad, replies)), unpad(reg_mask) != 0)
+    return apply_batch(kv, msg, is_reg)
+
+
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret",
                                              "use_kernel", "shard_lanes"))
 def _replica_step(kv: KVTable, msg: MsgBatch, registered: jnp.ndarray,
                   *, block_rows: int, interpret: Optional[bool],
                   use_kernel: bool,
                   shard_lanes: Optional[int] = None):
-    n = kv.state.shape[0]
-    tile = block_rows * LANE
-    # shard-aligned segment padding: with shard_lanes unset there is one
-    # segment and this is exactly the old whole-axis padding
-    seg = shard_lanes if shard_lanes else n
-    seg_pad = ((seg + tile - 1) // tile) * tile
-
-    is_reg = gather_is_registered(registered, msg)
-    if use_kernel:
-        kv_p = KVTable(*[pad_segments(a, seg, seg_pad) for a in kv])
-        # padded lanes become NOOP automatically (kind=0)
-        msg_p = MsgBatch(*[pad_segments(a, seg, seg_pad) for a in msg])
-        new_kv, replies, reg_mask = paxos_apply(
-            kv_p, msg_p, pad_segments(is_reg.astype(jnp.int32), seg, seg_pad),
-            block_rows=block_rows, interpret=interpret)
-        new_kv = KVTable(*[unpad_segments(a, seg, seg_pad) for a in new_kv])
-        replies = type(replies)(
-            *[unpad_segments(a, seg, seg_pad) for a in replies])
-        reg_mask = unpad_segments(reg_mask, seg, seg_pad) != 0
-    else:
-        new_kv, replies, reg_mask = apply_batch(kv, msg, is_reg)
-
-    new_registered = scatter_register(registered, msg, reg_mask)
-    return new_kv, replies, new_registered
+    new_kv, replies, reg_mask = apply_lanes(
+        kv, msg, gather_is_registered(registered, msg),
+        use_kernel=use_kernel, block_rows=block_rows,
+        shard_lanes=shard_lanes, interpret=interpret)
+    return new_kv, replies, scatter_register(registered, msg, reg_mask)
 
 
 def replica_step(kv: KVTable, msg: MsgBatch, registered: jnp.ndarray,
@@ -157,3 +181,30 @@ def replica_step(kv: KVTable, msg: MsgBatch, registered: jnp.ndarray,
     return _replica_step(kv, msg, registered, block_rows=block_rows,
                          interpret=interpret, use_kernel=use_kernel,
                          shard_lanes=shard_lanes)
+
+
+def stacked_replica_step(kv_stack, msgreg_stack, *, use_kernel, block_rows,
+                         shard_lanes=None, interpret=None):
+    """One receiver step for every replica: ``(18, M, K)`` KV stack and
+    ``(12, M, K)`` packed message operand (:data:`N_MSGREG`) ->
+    ``(18, M, K)``, ``(11, M, K)`` replies and the ``(M, K)`` registration
+    mask.  The machine axis folds into the lane axis: the step is
+    elementwise, so rows stay isolated by construction.  ``shard_lanes``
+    declares each row as blocks of that many lanes, so the flat axis is
+    ``M·n_shards`` segments, each padded on its own (kernel blocks never
+    straddle a shard boundary).
+
+    Not jitted: its callers trace it inline, so it lowers into their own
+    program."""
+    msg_stack = msgreg_stack[:N_MSG]
+    is_reg = msgreg_stack[N_MSG]
+    m, k = is_reg.shape
+    n = m * k
+    kv = KVTable(*[kv_stack[i].reshape(n) for i in range(N_KV)])
+    msg = MsgBatch(*[msg_stack[i].reshape(n) for i in range(N_MSG)])
+    new_kv, replies, mask = apply_lanes(
+        kv, msg, is_reg.reshape(n) != 0, use_kernel=use_kernel,
+        block_rows=block_rows, shard_lanes=shard_lanes, interpret=interpret)
+    return (jnp.stack([a.reshape(m, k) for a in new_kv]),
+            jnp.stack([a.reshape(m, k) for a in replies]),
+            mask.reshape(m, k))

@@ -1,18 +1,20 @@
-"""Jitted public wrapper for the paxos_propose kernel.
+"""The issuer step over session lanes: the paxos_propose kernel or its oracle.
 
-Handles lane padding and parameter-plane broadcasting, and exposes the
-issuer step with the same ``use_kernel`` switch the receiver step has
-(:func:`repro.kernels.paxos_apply.ops.replica_step`): ``use_kernel=False``
-runs the pure-jnp oracle (:func:`repro.core.proposer_vector.proposer_core`)
-on the same planes, bit-identically.
+:func:`propose_lanes` is the one issuer step and the only place that pads
+for the kernel; ``use_kernel=False`` runs the pure-jnp oracle
+(:func:`repro.core.proposer_vector.proposer_core`) on the same planes,
+bit-identically.  Two entries call it: :func:`issuer_step` (one replica,
+1-D session lanes) and :func:`stacked_issuer_step` (every replica of a
+cluster on ``(F, M, S)`` stacks, which the serve engine's fused issuer step
+traces).
 
 Padding contract (enforced with a ``ValueError`` inside
 :func:`repro.kernels.paxos_propose.kernel.paxos_propose`):
 
-* every ``ProposerTable`` and ``IssuerReplyBatch`` plane is 1-D with one
-  shared lane count ``n`` (one session per lane, at most one steered reply
-  per lane per step — the serve path's fixed layout);
-* ``issuer_step`` pads all planes with zeros up to a multiple of
+* every ``ProposerTable`` and ``IssuerReplyBatch`` plane has one shared
+  shape, ``n`` lanes once flattened (one session per lane, at most one
+  steered reply per lane per step — the serve path's fixed layout);
+* the kernel path pads all planes with zeros up to a multiple of
   ``block_rows * 128``, except ``rep.kind``, which pads with ``-1``:
   padded lanes are *idle*, so they neither fold tallies nor decide, and
   are sliced off again before returning;
@@ -27,21 +29,19 @@ Padding contract (enforced with a ``ValueError`` inside
 
 from __future__ import annotations
 
-import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.proposer_vector import (
-    IssuerReplyBatch, ProposerTable, proposer_core,
+    ActionBatch, IssuerReplyBatch, ProposerTable, proposer_core,
 )
-from repro.kernels.paxos_apply.ops import pad_segments, unpad_segments
-from .kernel import LANE, N_PAR, paxos_propose
-
-
-def _pad(a: jnp.ndarray, n_to: int, fill: int = 0) -> jnp.ndarray:
-    return jnp.pad(a, (0, n_to - a.shape[0]), constant_values=fill)
+from repro.kernels.paxos_apply.ops import (
+    pad_segments, segment_layout, unpad_segments,
+)
+from .kernel import N_PAR, N_REP, N_TAB, paxos_propose
 
 
 def validate_lanes(t: ProposerTable, rep: IssuerReplyBatch,
@@ -66,36 +66,40 @@ def validate_lanes(t: ProposerTable, rep: IssuerReplyBatch,
                 f"reply per lane.")
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret",
-                                             "use_kernel", "shard_lanes"))
-def _issuer_step(t: ProposerTable, rep: IssuerReplyBatch,
-                 params: jnp.ndarray, *, block_rows: int,
-                 interpret: Optional[bool], use_kernel: bool,
-                 shard_lanes: Optional[int] = None):
-    n = t.phase.shape[0]
+def propose_lanes(t: ProposerTable, rep: IssuerReplyBatch,
+                  params: jnp.ndarray, *, use_kernel: bool, block_rows: int,
+                  shard_lanes: Optional[int], interpret: Optional[bool]):
+    """The issuer step over session planes of one shape, 1-D ``(n,)`` or
+    stacked ``(M, S)``: ``(new_table, actions)``, through the Pallas kernel
+    or the jnp oracle.  ``params`` stacks the four quorum parameters on a
+    leading axis, broadcastable to the planes.  The kernel path flattens
+    the planes, pads each lane segment to the block tile (padded lanes are
+    idle, ``rep.kind = -1``, with quorum parameters 1) and restores the
+    shape; the oracle runs on the planes as they are."""
     if use_kernel:
-        tile = block_rows * LANE
-        # one segment without shard_lanes == the old whole-axis padding
-        seg = shard_lanes if shard_lanes else n
-        seg_pad = ((seg + tile - 1) // tile) * tile
-        t_p = ProposerTable(*[pad_segments(a, seg, seg_pad) for a in t])
-        # padded lanes are idle (kind = -1): no fold, no decision
-        rep_p = IssuerReplyBatch(
-            pad_segments(rep.kind, seg, seg_pad, fill=-1),
-            *[pad_segments(a, seg, seg_pad) for a in rep[1:]])
-        par_p = jnp.stack([pad_segments(params[i], seg, seg_pad, fill=1)
-                           for i in range(N_PAR)])
-        new_t, actions = paxos_propose(t_p, rep_p, par_p,
-                                       block_rows=block_rows,
-                                       interpret=interpret)
-        new_t = ProposerTable(
-            *[unpad_segments(a, seg, seg_pad) for a in new_t])
-        actions = type(actions)(
-            *[unpad_segments(a, seg, seg_pad) for a in actions])
-    else:
-        new_t, actions = proposer_core(t, rep, params[0], params[1],
-                                       params[2], params[3])
-    return new_t, actions
+        shape = t.phase.shape
+        n = math.prod(shape)
+        seg, seg_pad = segment_layout(n, block_rows, shard_lanes)
+
+        def pad(a, fill=0):
+            return pad_segments(a.reshape(n), seg, seg_pad, fill=fill)
+
+        def unpad(a):
+            return unpad_segments(a, seg, seg_pad).reshape(shape)
+
+        par = jnp.broadcast_to(params, (N_PAR, *shape)).reshape(N_PAR, n)
+        new_t, actions = paxos_propose(
+            ProposerTable(*map(pad, t)),
+            IssuerReplyBatch(pad(rep.kind, fill=-1), *map(pad, rep[1:])),
+            jnp.stack([pad(par[i], fill=1) for i in range(N_PAR)]),
+            block_rows=block_rows, interpret=interpret)
+        return (ProposerTable(*map(unpad, new_t)),
+                ActionBatch(*map(unpad, actions)))
+    return proposer_core(t, rep, params[0], params[1], params[2], params[3])
+
+
+_issuer_step = jax.jit(propose_lanes, static_argnames=(
+    "block_rows", "interpret", "use_kernel", "shard_lanes"))
 
 
 def issuer_step(t: ProposerTable, rep: IssuerReplyBatch, *,
@@ -119,3 +123,22 @@ def issuer_step(t: ProposerTable, rep: IssuerReplyBatch, *,
     return _issuer_step(t, rep, params, block_rows=block_rows,
                         interpret=interpret, use_kernel=use_kernel,
                         shard_lanes=shard_lanes)
+
+
+def stacked_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
+                        block_rows, shard_lanes=None, interpret=None):
+    """One issuer step for every replica: ``(65, M, S)`` proposer stack,
+    ``(13, M, S)`` steered replies and ``(4, M, 1)`` quorum parameters,
+    one column per machine (each machine's active view pins its own
+    quorum sizes, §8.7) -> ``(65, M, S)``, ``(14, M, S)`` actions.
+    ``shard_lanes`` as in
+    :func:`repro.kernels.paxos_apply.ops.stacked_replica_step`.
+
+    Not jitted: its callers trace it inline, so it lowers into their own
+    program."""
+    t = ProposerTable(*[tab_stack[i] for i in range(N_TAB)])
+    rep = IssuerReplyBatch(*[rep_stack[i] for i in range(N_REP)])
+    new_t, act = propose_lanes(t, rep, params, use_kernel=use_kernel,
+                               block_rows=block_rows,
+                               shard_lanes=shard_lanes, interpret=interpret)
+    return jnp.stack(new_t), jnp.stack(act)

@@ -65,9 +65,10 @@ gather/scatter is a *host* responsibility, mediated by :mod:`.bridge`:
   abandonment parks the lane (``PAUSED``) exactly where the scalar machine
   stops gathering replies.  Decision *payloads* come back as ActionBatch
   lanes — the same planes the differential replay asserts against the
-  scalar oracle (including the fused stacking itself:
-  :func:`repro.core.replay.replay_cluster_fused`), so live dispatch and
-  replay can never drift apart.
+  scalar oracle.  The fused receiver replay
+  (:func:`repro.core.replay.replay_cluster_fused`) runs the very step the
+  engine serves (:func:`repro.kernels.paxos_apply.ops.stacked_replica_step`),
+  so live dispatch and replay can never drift apart.
 * **Residency + donation** — each stack keeps a single device array
   across ticks (``donate_argnums`` updates it in place); crash/restart
   and view installs evict or reload ONE row via

@@ -83,10 +83,8 @@ from repro.core.lanes import (
     ShardMap, kv_to_lanes, msg_to_lanes, reply_to_lanes,
 )
 from repro.core.types import KVPair
-from repro.kernels.paxos_apply import kernel as apply_kernel
-from repro.kernels.paxos_apply.ops import pad_segments, unpad_segments
+from repro.kernels.paxos_apply import ops as apply_ops
 from repro.kernels.paxos_propose import ops as propose_ops
-from repro.kernels.paxos_propose.kernel import N_PAR
 from repro.parallel import sharding as plane_sharding
 
 # CPU backends may decline a donation (the buffer is still consumed
@@ -97,28 +95,24 @@ warnings.filterwarnings("ignore",
 I32 = np.int32
 
 N_KV = len(vector.KVTable._fields)                  # 18
-N_MSG = len(vector.MsgBatch._fields)                # 11
 N_REP = len(vector.ReplyBatch._fields)              # 11
-N_TAB = len(proposer_vector.ProposerTable._fields)  # 65
 N_IREP = len(proposer_vector.IssuerReplyBatch._fields)  # 13
-N_ACT = len(proposer_vector.ActionBatch._fields)    # 14
 
 KV_DEFAULTS = kv_to_lanes(KVPair(key=0))
 
-_MSG_IDX = {f: i for i, f in enumerate(vector.MsgBatch._fields)}
 _IREP_IDX = {f: i for i, f in enumerate(
     proposer_vector.IssuerReplyBatch._fields)}
 
-# an unstaged message lane is a NOOP (matches vector.MsgBatch noop: kind=0,
-# has_value=1); an unstaged reply lane is idle (kind=-1: no fold/decision).
-# The message staging buffer carries the is_registered gather result as a
-# 12th plane so one device transfer ships both (N_MSGREG below).
-_NOOP_COL = np.zeros((N_MSG + 1,), I32)
-_NOOP_COL[_MSG_IDX["has_value"]] = 1
+# an unstaged message lane is a NOOP, not registered (the message staging
+# buffer carries the is_registered gather result as a 12th plane so one
+# device transfer ships both); an unstaged reply lane is idle (kind=-1: no
+# fold/decision).
+_NOOP_COL = apply_ops.NOOP_COLUMN
 _IDLE_COL = np.zeros((N_IREP,), I32)
 _IDLE_COL[_IREP_IDX["kind"]] = -1
 
-N_MSGREG = N_MSG + 1                    # 11 message planes + is_registered
+N_MSGREG = apply_ops.N_MSGREG           # 11 message planes + is_registered
+N_PAR = propose_ops.N_PAR               # quorum parameters per machine
 
 
 # ---------------------------------------------------------------------------
@@ -445,63 +439,6 @@ class PlaneStack:
 # fused step functions (module-level: one jit cache across engines)
 # ---------------------------------------------------------------------------
 
-def _receiver_core(kv_stack, msgreg_stack, use_kernel, block_rows,
-                   shard_lanes, interpret):
-    msg_stack = msgreg_stack[:N_MSG]
-    is_reg = msgreg_stack[N_MSG]
-    m, k = is_reg.shape
-    n = m * k
-    kv = vector.KVTable(*[kv_stack[i].reshape(n) for i in range(N_KV)])
-    msg = vector.MsgBatch(*[msg_stack[i].reshape(n) for i in range(N_MSG)])
-    reg = is_reg.reshape(n) != 0
-    if use_kernel:
-        tile = block_rows * apply_kernel.LANE
-        seg = shard_lanes if shard_lanes else n
-        seg_pad = ((seg + tile - 1) // tile) * tile
-        kv_p = vector.KVTable(
-            *[pad_segments(a, seg, seg_pad) for a in kv])
-        # padded lanes become NOOP automatically (kind=0)
-        msg_p = vector.MsgBatch(
-            *[pad_segments(a, seg, seg_pad) for a in msg])
-        new_kv, replies, mask = apply_kernel.paxos_apply(
-            kv_p, msg_p,
-            pad_segments(reg.astype(jnp.int32), seg, seg_pad),
-            block_rows=block_rows, interpret=interpret)
-        new_kv = vector.KVTable(
-            *[unpad_segments(a, seg, seg_pad) for a in new_kv])
-        replies = type(replies)(
-            *[unpad_segments(a, seg, seg_pad) for a in replies])
-        mask = unpad_segments(mask, seg, seg_pad) != 0
-    else:
-        new_kv, replies, mask = vector.apply_batch(kv, msg, reg)
-    return (jnp.stack([a.reshape(m, k) for a in new_kv]),
-            jnp.stack([a.reshape(m, k) for a in replies]),
-            mask.reshape(m, k))
-
-
-def _issuer_core(tab_stack, rep_stack, params, use_kernel, block_rows,
-                 shard_lanes, interpret):
-    m, s = rep_stack.shape[1], rep_stack.shape[2]
-    if use_kernel:
-        n = m * s
-        t = proposer_vector.ProposerTable(
-            *[tab_stack[i].reshape(n) for i in range(N_TAB)])
-        rep = proposer_vector.IssuerReplyBatch(
-            *[rep_stack[i].reshape(n) for i in range(N_IREP)])
-        par = jnp.broadcast_to(params, (N_PAR, m, s)).reshape(N_PAR, n)
-        new_t, act = propose_ops._issuer_step(
-            t, rep, par, block_rows=block_rows, interpret=interpret,
-            use_kernel=True, shard_lanes=shard_lanes)
-        return (jnp.stack([a.reshape(m, s) for a in new_t]),
-                jnp.stack([a.reshape(m, s) for a in act]))
-    t = proposer_vector.ProposerTable(*[tab_stack[i] for i in range(N_TAB)])
-    rep = proposer_vector.IssuerReplyBatch(
-        *[rep_stack[i] for i in range(N_IREP)])
-    new_t, act = proposer_vector.proposer_core(
-        t, rep, params[0], params[1], params[2], params[3])
-    return jnp.stack(new_t), jnp.stack(act)
-
-
 def _expand_compact(kv_stack, entries, patches):
     """A compact wave's operands made dense on the device: the host-written
     lanes of ``patches`` scattered into the stack, and the staged
@@ -527,10 +464,9 @@ def _fused_receiver_step(kv_stack, msgreg_stack, patches=None, *,
                          use_kernel, block_rows, shard_lanes=None,
                          out_sharding=None, interpret=None):
     """One receiver step for every machine: (18,M,K),(12,M,K) ->
-    (18,M,K),(11,M,K),(M,K).  Flattens the machine axis into the lane axis
-    — apply_batch is elementwise, so rows stay isolated by construction.
-    The 12th input plane is the host-gathered is_registered bit, packed
-    with the message planes so one transfer stages the whole wave.
+    (18,M,K),(11,M,K),(M,K), the stacked step of
+    :func:`repro.kernels.paxos_apply.ops.stacked_replica_step` (which
+    holds the kernel choice and the shard-segment padding).
 
     With ``patches`` the wave came on the compact wire: ``msgreg_stack``
     is then a ``(13, W)`` batch of staged entries and ``patches`` a
@@ -538,13 +474,6 @@ def _fused_receiver_step(kv_stack, msgreg_stack, patches=None, *,
     as row 0 (:func:`_expand_compact`).  The patches land in the donated
     stack and the entries become the dense message operand on the device,
     so the step is the same elementwise pass over the whole stack.
-
-    ``shard_lanes`` (static) declares the lane axis as shard-aligned
-    segments of that length: each machine row is n_shards contiguous
-    blocks, so the flattened axis is M·n_shards segments, each padded
-    independently to the kernel tile — compiled blocks never straddle a
-    shard boundary.  One segment (``None``) is whole-axis padding; either
-    way the step is elementwise, so the outputs are bit-identical.
 
     ``out_sharding`` (static) is the stack's placement on a device mesh.
     The step then runs under ``shard_map``: each device steps its own lane
@@ -560,10 +489,13 @@ def _fused_receiver_step(kv_stack, msgreg_stack, patches=None, *,
         kv_stack, msgreg_stack = _expand_compact(kv_stack, msgreg_stack,
                                                  patches)
     if out_sharding is None:
-        return _receiver_core(kv_stack, msgreg_stack, use_kernel, block_rows,
-                              shard_lanes, interpret)
+        return apply_ops.stacked_replica_step(
+            kv_stack, msgreg_stack, use_kernel=use_kernel,
+            block_rows=block_rows, shard_lanes=shard_lanes,
+            interpret=interpret)
     spec = out_sharding.spec
-    local = functools.partial(_receiver_core, use_kernel=use_kernel,
+    local = functools.partial(apply_ops.stacked_replica_step,
+                              use_kernel=use_kernel,
                               block_rows=block_rows, shard_lanes=None,
                               interpret=interpret)
     return jax.shard_map(local, mesh=out_sharding.mesh,
@@ -593,15 +525,18 @@ def _fused_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
                        block_rows, shard_lanes=None, out_sharding=None,
                        interpret=None):
     """One issuer step for every machine: (65,M,S),(13,M,S),(4,M,1) ->
-    (65,M,S),(14,M,S).  Quorum parameters broadcast per machine row —
-    each machine's active view pins its own quorum sizes (§8.7).
-    ``shard_lanes``, ``out_sharding`` and ``interpret`` as in
-    :func:`_fused_receiver_step` (session-lane segments)."""
+    (65,M,S),(14,M,S), the stacked step of
+    :func:`repro.kernels.paxos_propose.ops.stacked_issuer_step`.
+    ``out_sharding`` and ``interpret`` as in
+    :func:`_fused_receiver_step`."""
     if out_sharding is None:
-        return _issuer_core(tab_stack, rep_stack, params, use_kernel,
-                            block_rows, shard_lanes, interpret)
+        return propose_ops.stacked_issuer_step(
+            tab_stack, rep_stack, params, use_kernel=use_kernel,
+            block_rows=block_rows, shard_lanes=shard_lanes,
+            interpret=interpret)
     spec = out_sharding.spec
-    local = functools.partial(_issuer_core, use_kernel=use_kernel,
+    local = functools.partial(propose_ops.stacked_issuer_step,
+                              use_kernel=use_kernel,
                               block_rows=block_rows, shard_lanes=None,
                               interpret=interpret)
     return jax.shard_map(local, mesh=out_sharding.mesh,

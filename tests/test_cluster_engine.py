@@ -539,6 +539,7 @@ def test_compact_step_equals_dense_step(use_kernel):
     some on the same lanes) step to exactly the outputs of the dense
     operands they stand for, and the gather reads those outputs at the
     entries."""
+    from repro.core import vector
     from repro.serve.paxos import cluster_engine as ce
 
     m, k = 3, 512
@@ -547,7 +548,7 @@ def test_compact_step_equals_dense_step(use_kernel):
     kv = rng.integers(0, 4, (ce.N_KV, m, k)).astype(np.int32)
     idx = rng.choice(n, ne, replace=False)
     vals = rng.integers(0, 4, (ce.N_MSGREG, ne)).astype(np.int32)
-    vals[ce._MSG_IDX["kind"]] = rng.integers(0, 9, ne)
+    vals[vector.MsgBatch._fields.index("kind")] = rng.integers(0, 9, ne)
     pidx = np.concatenate([idx[:20], rng.choice(
         np.setdiff1d(np.arange(n), idx), 20, replace=False)])
     pvals = rng.integers(0, 4, (ce.N_KV, 40)).astype(np.int32)
@@ -574,3 +575,47 @@ def test_compact_step_equals_dense_step(use_kernel):
     np.testing.assert_array_equal(packed[:, :ne], np.concatenate([
         want[0][:, rows, lanes], want[1][:, rows, lanes],
         want[2][rows, lanes][None]]))
+
+
+@pytest.mark.parametrize("shard_lanes", (None, 8))
+def test_stacked_issuer_step_kernel_matches_jnp(shard_lanes):
+    """The served issuer step, through the Pallas kernel and through the
+    jnp oracle, on random ``(M, S)`` planes with a quorum-parameter column
+    per machine (each machine's view pins its own quorums) gives the same
+    planes bit for bit, with whole-axis or per-shard kernel segments."""
+    from repro.core import proposer_vector as pv
+    from repro.core.proposer import Decision
+    from repro.serve.paxos import cluster_engine as ce
+
+    m, s = 3, 16
+    rng = np.random.default_rng(23)
+    tab = rng.integers(-1, 4, (len(pv.ProposerTable._fields), m, s))
+    rep = rng.integers(-1, 4, (len(pv.IssuerReplyBatch._fields), m, s))
+    # live propose and accept rounds with partial tallies, and mostly-ack
+    # replies to them, so that quorum sizes decide
+    for fields, planes, f, vals in (
+            (pv.ProposerTable, tab, "phase", (1, 2)),
+            (pv.ProposerTable, tab, "lid", (1,)),
+            (pv.ProposerTable, tab, "rep_bits", range(32)),
+            (pv.ProposerTable, tab, "ack_bits", range(32)),
+            (pv.IssuerReplyBatch, rep, "opcode", (0, 0, 0, 1, 2, 5, 8)),
+            (pv.IssuerReplyBatch, rep, "src", range(5)),
+            (pv.IssuerReplyBatch, rep, "lid", (1,))):
+        planes[fields._fields.index(f)] = rng.choice(vals, (m, s))
+    # PROP_REPLY to a propose round, ACC_REPLY to an accept round, or idle
+    rep[0] = np.where(rng.random((m, s)) < 0.2, -1, tab[0] + 2)
+    params = np.array([[5, 3, 2, 4], [5, 3, 1, 4], [3, 2, 1, 3]]).T[..., None]
+    outs = [ce._fused_issuer_step(
+        tab.astype(np.int32), rep.astype(np.int32), params.astype(np.int32),
+        use_kernel=use_kernel, block_rows=1, shard_lanes=shard_lanes)
+        for use_kernel in (False, True)]
+    for want, got in zip(*outs):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    decision = np.asarray(outs[0][1])[pv.ActionBatch._fields.index(
+        "decision")]
+    assert (decision != int(Decision.WAIT)).any()
+    # the machines' own quorum columns decide: swapped, they decide apart
+    swapped = ce._fused_issuer_step(
+        tab.astype(np.int32), rep.astype(np.int32),
+        params[:, ::-1].astype(np.int32), use_kernel=False, block_rows=1)
+    assert (np.asarray(swapped[1]) != np.asarray(outs[0][1])).any()

@@ -5,8 +5,10 @@ network (drops, duplicates, heavy-tail delays) and differentially replays
 per-machine traces:
 
 * receiver side — the message stream through the Pallas kernel (interpret
-  mode) AND the scalar handlers, asserting reply- and plane-for-plane state
-  equality (repro.core.replay.run_and_replay);
+  mode) or the jnp oracle AND the scalar handlers, asserting reply- and
+  plane-for-plane state equality, one machine at a time
+  (repro.core.replay.run_and_replay) and through the stacked step the
+  serve engine runs, over one to four shard blocks (run_and_replay_fused);
 * issuer side — the reply/round/decision stream through the batched
   proposer engine (repro.core.proposer_vector) AND the scalar shadow built
   from the same pure transitions the Machine runs, asserting decisions,
@@ -86,14 +88,17 @@ def test_replay_with_crash_and_restart():
 # fused (stacked-machine) replay: cluster ticks, plane-for-plane
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("seed", (1, 4, 8, 13))
+# seeds apart from test_sharded_replay's, whose shards=1 cases run the
+# same driver
+@pytest.mark.parametrize("seed", (2, 6, 10, 17))
 def test_fused_replay_jnp(seed):
-    """All machines share each fused (M*K,) step — the ClusterEngine
-    flattening convention — yet every row stays bit-identical to its own
+    """All machines share each fused (M*K,) step — the step the
+    ClusterEngine serves — yet every row stays bit-identical to its own
     scalar shadow, wave for wave."""
     stats = replay.run_and_replay_fused(seed, n_ops=24, keys=3,
                                         use_kernel=False)
     assert stats["machines"] == 5
+    assert stats["shards"] == 1
     assert stats["messages"] > 0
     assert stats["fused_waves"] > 0
     assert stats["history"] == 24
@@ -133,10 +138,9 @@ def test_fused_replay_with_crash_and_restart():
 def test_sharded_replay(seed, shards):
     """Shard-for-shard replay against the N scalar shadows: replies,
     per-shard registration journals, and every shard block of every KV
-    plane bit-identical at every shard count (shards=1 pins that the
-    sharded path degenerates to the classic fused replay)."""
-    stats = replay.run_and_replay_sharded(seed, shards=shards,
-                                          use_kernel=False)
+    plane bit-identical at every shard count."""
+    stats = replay.run_and_replay_fused(seed, shards=shards,
+                                        use_kernel=False)
     assert stats["machines"] == 5
     assert stats["shards"] == shards
     assert stats["fused_waves"] > 0
@@ -149,8 +153,8 @@ def test_sharded_replay_kernel():
     """Same through the Pallas kernel (interpret mode): each shard's lane
     block pads to its own tile segment, so no compiled block spans a
     shard boundary — and the planes still match the scalar shadows."""
-    stats = replay.run_and_replay_sharded(3, shards=4, use_kernel=True,
-                                          block_rows=1)
+    stats = replay.run_and_replay_fused(3, shards=4, use_kernel=True,
+                                        block_rows=1)
     assert stats["machines"] == 5
     assert stats["shards"] == 4
     assert stats["fused_waves"] > 0
@@ -168,7 +172,8 @@ def test_sharded_replay_with_crash_and_restart():
     cl.step(6)
     cl.restart(4)
     assert cl.run_until_quiet(max_ticks=120_000)
-    stats = replay.replay_sharded(cl, n_keys=2, shards=2, use_kernel=False)
+    stats = replay.replay_cluster_fused(cl, n_keys=2, shards=2,
+                                        use_kernel=False)
     assert stats["machines"] == 5
     assert stats["shards"] == 2
 
