@@ -104,7 +104,7 @@ class KVBridge:
         # registered by commits that landed in that shard's lane block
         # (gsess -> counter).  The machine-global scalar registry is the
         # cross-shard max-merge of these journals plus snapshot state —
-        # see ClusterEngine._run_receiver's scatter.
+        # see ClusterEngine._receiver_half's scatter.
         self.reg_mirror: List[Dict[int, int]] = [
             {} for _ in range(self._stack.n_shards)]
 

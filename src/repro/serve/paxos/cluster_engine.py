@@ -24,10 +24,10 @@ restructures the serve stack around *residency*:
 * **One fused tick** — :meth:`ClusterEngine.step_all` advances every
   machine's tick *generator* in waves: each wave executes one fused
   receiver call and/or one fused issuer call for every machine with a
-  pending batch, then resumes the generators (in mid order) with views of
-  their row of the output planes.  Host code — KV-coupled decisions,
-  registry scatter, wire I/O — runs between waves through the unchanged
-  scalar paths.
+  pending batch, both in one host round trip each way, then resumes the
+  generators (in mid order) with views of their row of the output
+  planes.  Host code — KV-coupled decisions, registry scatter, wire I/O
+  — runs between waves through the unchanged scalar paths.
 
 This module is the code behind ``docs/serve_architecture.md`` — *wave*,
 *plane stack*, *residency* and the *donation contract* are used there
@@ -126,12 +126,13 @@ class PlaneStack:
     at lane ``l``.  The device array is the authoritative state; the host
     mirror (:attr:`host`) is what scalar code reads and writes, and every
     fused wave keeps the two coherent in its own transfers.  A wave takes
-    one of two wires (:meth:`ClusterEngine._run_receiver` picks by shape):
+    one of two wires (:meth:`ClusterEngine._receiver_half` picks by shape):
 
     * **dense** — the wave downloads the whole new stack with its other
       outputs and :meth:`absorb` copies it into the mirror; host writes
       mark the stack ``host_dirty`` and ride up whole with the wave's
-      staging buffer (:meth:`upload_with`, one batched transfer).
+      staging buffer (:meth:`upload_with`, in the wave's one batched
+      transfer).
     * **compact** — the wave downloads only the lanes its step touched
       and :meth:`absorb_lanes` scatters them into the mirror (every other
       lane was a NOOP, bit-identical).  Host writes recorded lane by lane
@@ -353,48 +354,47 @@ class PlaneStack:
                 clock.end()
         return self.dev
 
-    def upload_with(self, staging: np.ndarray
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """A fused wave's one host→device transfer: ``staging``, and the
-        mirror with it when host code wrote to it (or nothing is resident
-        yet), in one batched ``device_put``.  Returns the device stack and
-        staging buffer.  The stack is about to be *donated*: the caller
+    def upload_with(self, staging: np.ndarray):
+        """A fused wave's host→device transfer for this stack:
+        ``staging``, and the mirror with it when host code wrote to it (or
+        nothing is resident yet).  A generator: it yields the host arrays
+        and their placements, which the wave ships with everything else it
+        uploads in one batched ``device_put``
+        (:meth:`ClusterEngine._run_wave`), takes their device copies back,
+        and returns the device stack and staging buffer.  The stack is about to be *donated*: the caller
         must :meth:`absorb` the step's output before any further host
-        access.  ``may_alias=False``: on the CPU a ``device_put`` of a
-        numpy array would otherwise share its memory, and the donated
-        stack, and with it the step's output, could live in the mirror."""
+        access."""
         if not self.host_dirty and self.dev is not None:
-            return self.dev, jax.device_put(staging, may_alias=False)
-        self.dev, staging_dev = jax.device_put(
-            (self.host, staging), (self.device_sharding(), None),
-            may_alias=False)
+            (staging_dev,) = yield (staging,), (None,)
+            return self.dev, staging_dev
+        self.dev, staging_dev = yield ((self.host, staging),
+                                       (self.device_sharding(), None))
         self.host_dirty = False
         self.wave_ships += 1
         self.h2d_bytes += self.host.nbytes
         return self.dev, staging_dev
 
-    def upload_compact(self, staging: np.ndarray, width: int
-                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """A compact wave's one host→device transfer: ``staging`` and the
-        host writes, in one batched ``device_put``.  Recorded lane patches
-        ride as a ``(1 + F, width)`` array: row 0 the flat lane index
-        (padding aims past the stack and is dropped), rows 1.. the lane's
-        mirror values.  With no patch lanes, or a whole-row write, more
-        patch lanes than ``width`` or nothing resident yet,
+    def upload_compact(self, staging: np.ndarray, width: int):
+        """A compact wave's host→device transfer, a generator as
+        :meth:`upload_with`: ``staging`` and the host writes.  Recorded
+        lane patches ride as a ``(1 + F, width)`` array: row 0 the flat
+        lane index (padding aims past the stack and is dropped), rows 1..
+        the lane's mirror values.  With no patch lanes, or a whole-row
+        write, more patch lanes than ``width`` or nothing resident yet,
         :meth:`upload_with` ships instead, with an all-padding patch
         array that stays on the device.  Returns the device stack,
         staging and patches; the same donation rule holds."""
         if (self.dev is None or self.shard_dirty.any()
                 or not self._patches or len(self._patches) > width):
-            return (*self.upload_with(staging), self._empty_patches(width))
+            stack, staging_dev = yield from self.upload_with(staging)
+            return stack, staging_dev, self._empty_patches(width)
         idx = np.fromiter(self._patches, np.int64, len(self._patches))
         patches = np.zeros((1 + len(self.fields), width), I32)
         patches[0] = self.n_machines * self.n_lanes
         patches[0, :len(idx)] = idx
         patches[1:, :len(idx)] = self.host.reshape(len(self.fields), -1)[
             :, idx]
-        staging_dev, patches_dev = jax.device_put((staging, patches),
-                                                  may_alias=False)
+        staging_dev, patches_dev = yield (staging, patches), (None, None)
         self.patched_lanes += len(idx)
         self.h2d_bytes += patches.nbytes
         self._patches.clear()
@@ -545,19 +545,30 @@ def _fused_issuer_step(tab_stack, rep_stack, params, *, use_kernel,
                          check_vma=False)(tab_stack, rep_stack, params)
 
 
-def _download(outs, clock) -> List[np.ndarray]:
-    """Host copies of a fused step's outputs in one device→host round
-    trip: every copy is started before any is read, so they overlap.
-    With a clock the caller has opened ``engine.wait``: the step's
-    outputs are awaited there (the copies already queued behind the
+def _download(outs, clock) -> List[List[np.ndarray]]:
+    """Host copies of a wave's outputs, a list per half, in one
+    device→host round trip: every copy is started before any is read, so
+    they overlap.  With a clock the caller has opened ``engine.wait``: the
+    steps' outputs are awaited there (the copies already queued behind the
     computation, so the wait adds no round trip), and ``engine.download``
     times what the copies add."""
-    for a in outs:
-        a.copy_to_host_async()
+    for half in outs:
+        for a in half:
+            a.copy_to_host_async()
     if clock is not None:
         jax.block_until_ready(outs)
         clock.switch("engine.download")
-    return [np.asarray(a) for a in outs]
+    return [[np.asarray(a) for a in half] for half in outs]
+
+
+def _finish(half, host_outs):
+    """Resume a wave half with the host copies of its outputs; what it
+    returns, the machines' result views."""
+    try:
+        half.send(host_outs)
+    except StopIteration as done:
+        return done.value
+    raise RuntimeError("a wave half yields twice: its upload, its outputs")
 
 
 # ---------------------------------------------------------------------------
@@ -640,6 +651,7 @@ class ClusterEngine:
                       "fused_receiver_calls": 0, "fused_receiver_lanes": 0,
                       "compact_receiver_waves": 0,
                       "fused_issuer_calls": 0, "fused_issuer_lanes": 0,
+                      "paired_waves": 0,
                       "receiver_shard_lanes": [0] * self.shards,
                       "issuer_shard_lanes": [0] * self.tab_shards,
                       "shard_registrations": [0] * self.shards}
@@ -798,8 +810,9 @@ class ClusterEngine:
 
     # -- fused wave execution ------------------------------------------------
 
-    def _run_receiver(self, requests) -> Dict[int, Dict[str, np.ndarray]]:
-        """requests: [(machine, [Msg,...]), ...] — one fused call.
+    def _receiver_half(self, requests):
+        """requests: [(machine, [Msg,...]), ...] — one fused receiver
+        call, as a wave half (:meth:`_run_wave`).
 
         The wave takes the compact wire when :meth:`_wave_width` gives a
         width, else the dense one (:class:`PlaneStack` describes both).
@@ -807,14 +820,7 @@ class ClusterEngine:
         whole new stack, replies and mask come down.  Compact, only the
         staged entries (and host-written lanes, as patches) go up, and
         after the step one small gather (:func:`_touched_lanes`) brings
-        down the outputs at those entries alone.
-
-        With a clock attached the call is six sibling spans: ``stage``,
-        ``upload``, ``launch``, ``wait`` (for the device, so the copies
-        after it time the copy alone), ``download`` and ``unstage``."""
-        clock = self.clock
-        if clock is not None:
-            clock.begin("engine.stage")
+        down the outputs at those entries alone."""
         # every bridge sharing the stack scatters its checked-out views
         # first: the fused call steps the *whole* stack
         for br in self._bridges.values():
@@ -850,10 +856,11 @@ class ClusterEngine:
             use_kernel=self.use_kernel, block_rows=self.block_rows,
             shard_lanes=lps if self.shards > 1 else None)
         if width is None:
-            rep_np, mask_e = self._receiver_dense(step, staged, s_mi, s_key)
+            rep_np, mask_e = yield from self._receiver_dense(
+                step, staged, s_mi, s_key)
         else:
-            rep_np, mask_e = self._receiver_compact(step, width, staged,
-                                                    s_mi, s_key)
+            rep_np, mask_e = yield from self._receiver_compact(
+                step, width, staged, s_mi, s_key)
         for br in self._bridges.values():
             br.drop_views()              # stale against the new stack
         results: Dict[int, Dict[str, np.ndarray]] = {}
@@ -882,28 +889,17 @@ class ClusterEngine:
             self.stats["fused_receiver_lanes"] += len(batch)
             results[id(mach)] = {f: rep_np[i, mi] for i, f
                                  in enumerate(vector.ReplyBatch._fields)}
-        if clock is not None:
-            clock.end()
         return results
 
-    def _receiver_dense(self, step, staged, s_mi, s_key
-                        ) -> Tuple[np.ndarray, np.ndarray]:
-        """The dense wire from the ``upload`` span to ``unstage``: returns
-        the ``(11, M, K)`` replies and the mask at each staged entry."""
-        clock = self.clock
+    def _receiver_dense(self, step, staged, s_mi, s_key):
+        """The dense wire from the upload to the unstaging, in the wave
+        protocol: returns the ``(11, M, K)`` replies and the mask at each
+        staged entry."""
         msg_host = self._msg_buffers()
         msg_host[:, s_mi, s_key] = staged
-        if clock is not None:
-            clock.switch("engine.upload")
-        kv_dev, msg_dev = self.kv.upload_with(msg_host)
-        if clock is not None:
-            clock.switch("engine.launch")
+        kv_dev, msg_dev = yield from self.kv.upload_with(msg_host)
         outs = step(kv_dev, msg_dev, out_sharding=self.kv.device_sharding())
-        if clock is not None:
-            clock.switch("engine.wait")
-        kv_np, rep_np, mask_np = _download(outs, clock)
-        if clock is not None:
-            clock.switch("engine.unstage")
+        kv_np, rep_np, mask_np = yield outs
         self.kv.absorb(outs[0], kv_np)
         self.stats["staging_h2d_bytes"] += msg_host.nbytes
         self.stats["staging_d2h_bytes"] += rep_np.nbytes + mask_np.nbytes
@@ -911,12 +907,10 @@ class ClusterEngine:
         msg_host[:, s_mi, s_key] = _NOOP_COL[:, None]
         return rep_np, mask_np[s_mi, s_key]
 
-    def _receiver_compact(self, step, width, staged, s_mi, s_key
-                          ) -> Tuple[np.ndarray, np.ndarray]:
-        """The compact wire, spanned as :meth:`_receiver_dense`: returns
-        the persistent reply plane, fresh at the staged lanes, and the
-        mask at each staged entry."""
-        clock = self.clock
+    def _receiver_compact(self, step, width, staged, s_mi, s_key):
+        """The compact wire, as :meth:`_receiver_dense`: returns the
+        persistent reply plane, fresh at the staged lanes, and the mask at
+        each staged entry."""
         n = len(s_mi)
         assert n <= width, f"{n} staged messages overflow a {width}-wide wave"
         entries, replies = self._entry_buffers(width)
@@ -924,18 +918,10 @@ class ClusterEngine:
         key = np.asarray(s_key, np.int64)
         entries[0, :n] = mi * self.kv.n_lanes + key
         entries[1:, :n] = staged
-        if clock is not None:
-            clock.switch("engine.upload")
-        kv_dev, ent_dev, patch_dev = self.kv.upload_compact(entries, width)
-        if clock is not None:
-            clock.switch("engine.launch")
+        kv_dev, ent_dev, patch_dev = yield from self.kv.upload_compact(
+            entries, width)
         outs = step(kv_dev, ent_dev, patch_dev)
-        packed_dev = _touched_lanes(*outs, ent_dev)
-        if clock is not None:
-            clock.switch("engine.wait")
-        (packed,) = _download([packed_dev], clock)
-        if clock is not None:
-            clock.switch("engine.unstage")
+        (packed,) = yield [_touched_lanes(*outs, ent_dev)]
         self.kv.absorb_lanes(outs[0], mi, key, packed[:N_KV])
         replies[:, mi, key] = packed[N_KV:N_KV + N_REP, :n]
         self.stats["compact_receiver_waves"] += 1
@@ -946,12 +932,9 @@ class ClusterEngine:
         entries[1:, :n] = _NOOP_COL[:, None]
         return replies, packed[-1, :n]
 
-    def _run_issuer(self, requests) -> Dict[int, Dict[str, np.ndarray]]:
-        """requests: [(machine, [(lane, Reply),...]), ...] — one call,
-        timed in the same six spans as :meth:`_run_receiver`."""
-        clock = self.clock
-        if clock is not None:
-            clock.begin("engine.stage")
+    def _issuer_half(self, requests):
+        """requests: [(machine, [(lane, Reply),...]), ...] — one fused
+        issuer call, as a wave half (:meth:`_run_wave`)."""
         rep_host = self._rep_buffers()
         fields = proposer_vector.IssuerReplyBatch._fields
         lps = self.tab.n_lanes // self.tab_shards
@@ -968,22 +951,13 @@ class ClusterEngine:
                 s_lane.append(lane)
                 shard_lanes_stat[lane // lps] += 1
         rep_host[:, s_mi, s_lane] = np.array(cols, I32).T
-        if clock is not None:
-            clock.switch("engine.upload")
-        tab_dev, rep_dev = self.tab.upload_with(rep_host)
-        params = self._params()
-        if clock is not None:
-            clock.switch("engine.launch")
+        tab_dev, rep_dev = yield from self.tab.upload_with(rep_host)
         outs = _fused_issuer_step(
-            tab_dev, rep_dev, params,
+            tab_dev, rep_dev, self._params(),
             use_kernel=self.use_kernel, block_rows=self.block_rows,
             shard_lanes=lps if self.tab_shards > 1 else None,
             out_sharding=self.tab.device_sharding())
-        if clock is not None:
-            clock.switch("engine.wait")
-        tab_np, act_np = _download(outs, clock)
-        if clock is not None:
-            clock.switch("engine.unstage")
+        tab_np, act_np = yield outs
         self.tab.absorb(outs[0], tab_np)
         self.stats["staging_h2d_bytes"] += rep_host.nbytes
         self.stats["staging_d2h_bytes"] += act_np.nbytes
@@ -996,6 +970,52 @@ class ClusterEngine:
                 in enumerate(proposer_vector.ActionBatch._fields)}
         # reset to idle for the next wave
         rep_host[:, s_mi, s_lane] = _IDLE_COL[:, None]
+        return results
+
+    def _run_wave(self, halves) -> Dict[int, Dict[str, np.ndarray]]:
+        """Run a wave's halves, the receiver's and then the issuer's (one
+        or both), with one host round trip each way for all of them.
+
+        Each half is a generator of the wave protocol.  It stages its
+        batch and yields its upload: a tuple of host arrays and one of
+        their placements.  Resumed with their device copies, it launches
+        its step and yields the outputs the host reads.  Resumed with
+        their host copies, it unstages and returns the machines' result
+        views.  The halves step disjoint stacks for different machines
+        (each machine yields one request a wave), so nothing one stages
+        depends on the other's outputs: every upload goes in one batched
+        ``device_put``, both steps are launched, and one wait and one
+        download serve both.
+
+        With a clock attached the wave is six sibling spans, each opened
+        once: ``stage``, ``upload``, ``launch``, ``wait`` (for the device,
+        so the copies after it time the copy alone), ``download`` and
+        ``unstage``."""
+        clock = self.clock
+        if clock is not None:
+            clock.begin("engine.stage")
+        ups = [next(half) for half in halves]
+        if clock is not None:
+            clock.switch("engine.upload")
+        # may_alias=False: on the CPU a device_put of a numpy array would
+        # otherwise share its memory, and a donated stack, and with it the
+        # step's output, could live in the mirror
+        devs = jax.device_put(tuple(arrays for arrays, _ in ups),
+                              tuple(places for _, places in ups),
+                              may_alias=False)
+        if clock is not None:
+            clock.switch("engine.launch")
+        outs = [half.send(d) for half, d in zip(halves, devs)]
+        if clock is not None:
+            clock.switch("engine.wait")
+        host_outs = _download(outs, clock)
+        if clock is not None:
+            clock.switch("engine.unstage")
+        results: Dict[int, Dict[str, np.ndarray]] = {}
+        for half, host in zip(halves, host_outs):
+            results.update(_finish(half, host))
+        if len(halves) > 1:
+            self.stats["paired_waves"] += 1
         if clock is not None:
             clock.end()
         return results
@@ -1004,9 +1024,10 @@ class ClusterEngine:
         """Advance (machine, tick-generator) pairs to completion in waves.
 
         Each wave collects every pending request, executes at most one
-        fused receiver call and one fused issuer call, and resumes the
-        generators in the order given (mid order — matching the sequential
-        loop's per-machine ordering of host actions).  Each resumption is
+        fused receiver call and one fused issuer call, both in one round
+        trip each way (:meth:`_run_wave`), and resumes the generators in
+        the order given (mid order — matching the sequential loop's
+        per-machine ordering of host actions).  Each resumption is
         a ``machine`` span: the machine's scalar host decisions."""
         clock = self.clock
         pending = []
@@ -1024,11 +1045,12 @@ class ClusterEngine:
         while pending:
             recv = [(m, r[1]) for m, _g, r in pending if r[0] == "recv"]
             iss = [(m, r[1]) for m, _g, r in pending if r[0] == "issuer"]
-            results: Dict[int, object] = {}
+            halves = []
             if recv:
-                results.update(self._run_receiver(recv))
+                halves.append(self._receiver_half(recv))
             if iss:
-                results.update(self._run_issuer(iss))
+                halves.append(self._issuer_half(iss))
+            results = self._run_wave(halves)
             nxt = []
             for mach, gen, _req in pending:
                 if clock is not None:

@@ -286,22 +286,25 @@ def test_shard_mesh_needs_devices_on_tpu(monkeypatch, platform):
 # ---------------------------------------------------------------------------
 
 def _checked_after_every_call(engine, log):
-    """Wrap the engine's fused calls: after each, the stack the call
-    stepped must equal its device array, and so must the other stack
-    unless host code has written to it since its last wave."""
-    for name, stepped in (("_run_receiver", engine.kv),
-                          ("_run_issuer", engine.tab)):
-        def call(requests, _run=getattr(engine, name), _stepped=stepped):
-            out = _run(requests)
-            assert not _stepped.host_dirty
-            for stack in (engine.kv, engine.tab):
-                if not stack.host_dirty:
-                    np.testing.assert_array_equal(
-                        np.asarray(stack.dev), stack.host,
-                        err_msg=f"call {len(log)} {stack.fields[0]}")
-            log.append(name)
-            return out
-        setattr(engine, name, call)
+    """Wrap the engine's waves: after each, every stack a fused call of
+    the wave stepped must equal its device array, and so must the other
+    stack unless host code has written to it since its last wave.  Logs
+    the halves of each wave."""
+    stepped_by = {"_receiver_half": engine.kv, "_issuer_half": engine.tab}
+
+    def wave(halves, _run=engine._run_wave):
+        names = [half.__name__ for half in halves]
+        out = _run(halves)
+        for name in names:
+            assert not stepped_by[name].host_dirty
+        for stack in (engine.kv, engine.tab):
+            if not stack.host_dirty:
+                np.testing.assert_array_equal(
+                    np.asarray(stack.dev), stack.host,
+                    err_msg=f"wave {len(log)} {stack.fields[0]}")
+        log.append(names)
+        return out
+    engine._run_wave = wave
 
 
 MIRROR_CASES = [
@@ -347,7 +350,9 @@ def test_mirror_coherent_after_every_fused_call(replicas, shards, lanes,
         assert cl.run_until_quiet(max_ticks=50_000)
     assert completion_tuples(pair[1]) == completion_tuples(pair[0])
     tel = pair[1].engine.telemetry()
-    assert len(log) == tel["plane_wave_refreshes"] > 0
+    assert sum(map(len, log)) == tel["plane_wave_refreshes"] > 0
+    assert len(log) == (tel["fused_receiver_calls"]
+                        + tel["fused_issuer_calls"] - tel["paired_waves"])
     assert tel["plane_wave_ships"] > 0 and tel["row_reloads"] > 0
     assert tel["compact_receiver_waves"] == (
         tel["fused_receiver_calls"] if lanes else 0)
@@ -356,9 +361,9 @@ def test_mirror_coherent_after_every_fused_call(replicas, shards, lanes,
 def test_one_transfer_each_way_per_fused_call(monkeypatch):
     """The 3-replica TPC-C cell's shape (3 replicas, 40 sessions each,
     All-aboard, FAA-heavy on 120 counters), clock on: after warm-up each
-    fused call makes one upload and one download, no whole-stack push runs
-    on its own, and the stacks shipped are exactly the waves
-    that found host writes to ship."""
+    wave, with one fused call or two, makes one upload and one download,
+    no whole-stack push runs on its own, and the stacks shipped are
+    exactly the calls that found host writes to ship."""
     from repro.obs import FlightRecorder
     from repro.serve.paxos import cluster_engine
 
@@ -376,18 +381,21 @@ def test_one_transfer_each_way_per_fused_call(monkeypatch):
     real_put = cluster_engine.jax.device_put
     monkeypatch.setattr(cluster_engine.jax, "device_put",
                         lambda *a, **kw: puts.append(1) or real_put(*a, **kw))
-    # a wave carries its stack when the step is handed another array
+    # a call carries its stack when the step is handed another array
     # than the one resident when the wave began
     resident, carried = [], []
-    for name, stack in (("_run_receiver", eng.kv), ("_run_issuer", eng.tab)):
-        def run(requests, _run=getattr(eng, name), _stack=stack):
-            resident.append(_stack.dev)
-            return _run(requests)
-        setattr(eng, name, run)
-    for name in ("_fused_receiver_step", "_fused_issuer_step"):
-        def step(stack, *a, _step=getattr(cluster_engine, name), **kw):
-            carried.append(stack.nbytes if stack is not resident[-1] else 0)
-            return _step(stack, *a, **kw)
+
+    def wave(halves, _run=eng._run_wave):
+        resident.append({"kv": eng.kv.dev, "tab": eng.tab.dev})
+        return _run(halves)
+    eng._run_wave = wave
+    for name, stack in (("_fused_receiver_step", "kv"),
+                        ("_fused_issuer_step", "tab")):
+        def step(dev, *a, _step=getattr(cluster_engine, name), _stack=stack,
+                 **kw):
+            carried.append(dev.nbytes if dev is not resident[-1][_stack]
+                           else 0)
+            return _step(dev, *a, **kw)
         monkeypatch.setattr(cluster_engine, name, step)
     cl.step(60)
     after = eng.telemetry()
@@ -395,9 +403,11 @@ def test_one_transfer_each_way_per_fused_call(monkeypatch):
     def delta(key):
         return after.get(key, 0) - before.get(key, 0)
     calls = delta("fused_receiver_calls") + delta("fused_issuer_calls")
+    waves = calls - delta("paired_waves")
     assert calls == len(carried) > 100
-    assert delta("span.engine.upload.n") == calls == len(puts)
-    assert delta("span.engine.download.n") == calls
+    assert 0 < delta("paired_waves") and waves == len(resident)
+    assert delta("span.engine.upload.n") == waves == len(puts)
+    assert delta("span.engine.download.n") == waves
     assert delta("span.plane.push.n") == 0
     assert delta("plane_syncs") == 0
     assert delta("plane_wave_refreshes") == calls
@@ -408,6 +418,68 @@ def test_one_transfer_each_way_per_fused_call(monkeypatch):
         delta("staging_d2h_bytes")
         + delta("fused_receiver_calls") * eng.kv.host.nbytes
         + delta("fused_issuer_calls") * eng.tab.host.nbytes)
+
+
+@pytest.mark.parametrize("lanes", (128, 1024), ids=("dense", "compact"))
+def test_paired_waves_match_scalar_and_each_call_alone(monkeypatch, lanes):
+    """A wave with receiver and issuer work makes one round trip each way
+    for both halves.  On both receiver wires (3 replicas, 128 lanes a
+    machine: dense; 1024, wider than a wave: compact) the run pairs
+    waves and completes the scalar cluster's op stream, and every fused
+    call of a paired wave, replayed alone on copies of its operands,
+    gives the outputs it gave in the wave."""
+    from repro.core.node import Machine
+    from repro.serve.paxos import cluster_engine
+
+    cfg = ProtocolConfig(n_machines=3, sessions_per_machine=4,
+                         all_aboard=True)
+    pair = [Cluster(cfg, NetConfig(seed=29), machine_cls=cls)
+            for cls in (Machine, BatchedMachine)]
+    eng = pair[1].engine
+    pair[1].machines[0].kvs.ensure(lanes - 1)
+    for cl in pair:
+        workload(cl, n_ops=60, keys=8, seed=29, rmw_frac=0.6,
+                 write_frac=0.2, key_base=lanes - 8)
+
+    wave = {"paired": False, "calls": []}
+    checked = []
+    for name in ("_fused_receiver_step", "_fused_issuer_step"):
+        def step(*args, _step=getattr(cluster_engine, name), _name=name,
+                 **kw):
+            if wave["paired"]:
+                alone = _step(*[np.array(a) for a in args], **kw)
+                wave["calls"].append(
+                    (_name, [np.asarray(a) for a in alone]))
+            out = _step(*args, **kw)
+            if wave["paired"]:
+                wave["calls"][-1] += (out,)
+            return out
+        monkeypatch.setattr(cluster_engine, name, step)
+
+    def run_wave(halves, _run=eng._run_wave):
+        wave.update(paired=len(halves) > 1, calls=[])
+        results = _run(halves)
+        if wave["paired"]:
+            assert [c[0] for c in wave["calls"]] == [
+                "_fused_receiver_step", "_fused_issuer_step"]
+        for name, alone, out in wave["calls"]:
+            for want, got in zip(alone, out):
+                np.testing.assert_array_equal(np.asarray(got), want,
+                                              err_msg=name)
+        checked.append(len(wave["calls"]))
+        return results
+    eng._run_wave = run_wave
+
+    for cl in pair:
+        assert cl.run_until_quiet(max_ticks=50_000)
+    assert completion_tuples(pair[1]) == completion_tuples(pair[0])
+    tel = eng.telemetry()
+    assert tel["paired_waves"] > 0
+    assert sum(checked) == 2 * tel["paired_waves"]
+    assert len(checked) == (tel["fused_receiver_calls"]
+                            + tel["fused_issuer_calls"] - tel["paired_waves"])
+    assert tel["compact_receiver_waves"] == (
+        tel["fused_receiver_calls"] if lanes > 128 else 0)
 
 
 # ---------------------------------------------------------------------------
@@ -509,7 +581,7 @@ def test_host_writes_ride_as_lane_patches():
 
     def wave():
         before = eng.telemetry()
-        eng._run_receiver([])                # a wave with nothing staged
+        eng._run_wave([eng._receiver_half([])])  # nothing staged
         after = eng.telemetry()
         assert after["compact_receiver_waves"] == (
             before["compact_receiver_waves"] + 1)
