@@ -114,6 +114,24 @@ _IDLE_COL[_IREP_IDX["kind"]] = -1
 N_MSGREG = apply_ops.N_MSGREG           # 11 message planes + is_registered
 N_PAR = propose_ops.N_PAR               # quorum parameters per machine
 
+# telemetry key -> the ``Machine.stats`` counters it sums over the adopted
+# machines, incarnations replaced by a restart included
+MACHINE_COUNTERS = {
+    "abd_read_write_backs": ("read_write_backs",),
+    "round_timeouts": ("round_timeouts",),
+    "abd_retransmits": ("abd_retransmits",),
+    "all_aboard_attempts": ("all_aboard_attempts",),
+    "all_aboard_timeouts": ("all_aboard_timeouts",),
+    "helps": ("helps", "help_after_wait"),
+    "rmw_completed": ("rmw_completed",),
+}
+
+
+def _machine_counts(m) -> Dict[str, int]:
+    """``MACHINE_COUNTERS`` of one machine's ``stats``."""
+    return {key: sum(m.stats.get(s, 0) for s in stats)
+            for key, stats in MACHINE_COUNTERS.items()}
+
 
 # ---------------------------------------------------------------------------
 # PlaneStack: a device-resident (fields, machines, lanes) int32 block
@@ -638,7 +656,8 @@ class ClusterEngine:
             self.kv.set_mesh(self.mesh)
             self.tab.set_mesh(self.mesh)
         self._machines: Dict[int, object] = {}    # mi -> BatchedMachine
-        self._replaced_write_backs = 0    # of incarnations adopt() replaced
+        # MACHINE_COUNTERS of the incarnations adopt() replaced
+        self._replaced = dict.fromkeys(MACHINE_COUNTERS, 0)
         self._bridges: Dict[int, object] = {}     # mi -> its KVBridge
         self._msg_host: Optional[np.ndarray] = None
         self._entries_host: Optional[np.ndarray] = None
@@ -674,9 +693,12 @@ class ClusterEngine:
         (``h2d_bytes``/``d2h_bytes``: the whole stacks, in a wave or out
         of one, as ``stack_h2d_bytes``/``stack_d2h_bytes``, plus the
         per-wave staging and reply transfers, ``staging_*``).  It also
-        counts the ABD reads that took the §11 write-back round
-        (``abd_read_write_backs``: the adopted machines'
-        ``read_write_backs``, replaced incarnations included).  While a
+        sums the adopted machines' protocol counters, replaced
+        incarnations included (``MACHINE_COUNTERS``): the ABD reads that
+        took the §11 write-back round (``abd_read_write_backs``), stalled
+        rounds retried or retransmitted (``round_timeouts``,
+        ``abd_retransmits``), All-aboard attempts and §9.2 time-outs,
+        helps (§6, after a wait too) and completed RMWs.  While a
         clock is attached it also carries the clock's span totals and
         counters (:meth:`repro.obs.HostClock.totals`).  The flight
         recorder pulls this at snapshot time."""
@@ -695,9 +717,10 @@ class ClusterEngine:
                           + t["stack_h2d_bytes"])
         t["d2h_bytes"] = (self.stats["staging_d2h_bytes"]
                           + t["stack_d2h_bytes"])
-        t["abd_read_write_backs"] = self._replaced_write_backs + sum(
-            m.stats.get("read_write_backs", 0)
-            for m in self._machines.values())
+        t.update(self._replaced)
+        for m in self._machines.values():
+            for key, n in _machine_counts(m).items():
+                t[key] += n
         if self.clock is not None:
             t.update(self.clock.totals())
         return t
@@ -737,7 +760,8 @@ class ClusterEngine:
             m._mi = mi
         old = self._machines.get(mi)
         if old is not None and old is not m:
-            self._replaced_write_backs += old.stats.get("read_write_backs", 0)
+            for key, n in _machine_counts(old).items():
+                self._replaced[key] += n
         self._machines[mi] = m
         self._bridges[mi] = m.kvs
         self._params_key = None

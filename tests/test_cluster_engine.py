@@ -521,6 +521,57 @@ def test_bytes_split_into_staging_and_stacks():
         assert after[key] == before[key]
 
 
+def _machine_sums(machines):
+    """The protocol counters of ``telemetry()``, summed over
+    ``machines``' stats by hand."""
+    def total(*stats):
+        return sum(m.stats.get(s, 0) for m in machines for s in stats)
+    return {"abd_read_write_backs": total("read_write_backs"),
+            "round_timeouts": total("round_timeouts"),
+            "abd_retransmits": total("abd_retransmits"),
+            "all_aboard_attempts": total("all_aboard_attempts"),
+            "all_aboard_timeouts": total("all_aboard_timeouts"),
+            "helps": total("helps", "help_after_wait"),
+            "rmw_completed": total("rmw_completed")}
+
+
+def test_recovery_counters_sum_the_machines_across_faults():
+    """After a partition and its heal, and after a crash and a restart,
+    the protocol counters in ``telemetry()`` are the machines' stats
+    summed, the replaced incarnation included, and none falls."""
+    cfg = ProtocolConfig(n_machines=5, sessions_per_machine=2,
+                         all_aboard=True)
+    cl = Cluster(cfg, NetConfig(seed=23), machine_cls=BatchedMachine)
+    workload(cl, n_ops=80, keys=4, seed=23, rmw_frac=0.8, write_frac=0.1)
+    eng = cl.engine
+
+    def counters():
+        t = eng.telemetry()
+        return {k: t[k] for k in _machine_sums([])}
+
+    cl.network.partition([4], [0, 1, 2, 3])
+    cl.step(60)
+    cl.network.heal()
+    cl.step(20)
+    healed = counters()
+    assert healed == _machine_sums(cl.machines)
+    for key in ("round_timeouts", "all_aboard_attempts",
+                "all_aboard_timeouts", "helps", "rmw_completed"):
+        assert healed[key] > 0, key
+    old = cl.machines[3]
+    cl.crash(3)
+    cl.step(10)
+    crashed = counters()
+    assert _machine_sums([old])["rmw_completed"] > 0
+    cl.restart(3)
+    assert counters() == crashed            # the old incarnation stays
+    assert cl.run_until_quiet(max_ticks=50_000)
+    final = counters()
+    assert final == _machine_sums(cl.machines + [old])
+    assert all(final[k] >= crashed[k] >= healed[k] for k in final)
+    assert final["rmw_completed"] > crashed["rmw_completed"]
+
+
 # ---------------------------------------------------------------------------
 # the compact receiver wire: bytes per wave independent of the plane
 # ---------------------------------------------------------------------------
